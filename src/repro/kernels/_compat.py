@@ -1,21 +1,9 @@
-"""Version compat + runtime flags for the Pallas TPU API surface.
-
-jax renamed `pltpu.TPUCompilerParams` -> `pltpu.CompilerParams` across
-releases; resolve whichever this jax ships so the kernels import on both.
-"""
+"""Runtime flag for the Pallas kernels: compiled on TPU, interpreted
+elsewhere, with an environment override."""
 
 from __future__ import annotations
 
 import os
-
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-# pl.CostEstimate is absent on very old jax; None disables the annotation.
-CostEstimate = getattr(pl, "CostEstimate", None)
 
 #: env override for the interpret default: "1"/"true" forces interpret
 #: mode everywhere, "0"/"false" forces compiled kernels even off-TPU.

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams, CostEstimate, resolve_interpret
+from ._compat import resolve_interpret
 
 BM, BK, BN = 128, 128, 128
 
@@ -63,12 +63,12 @@ def _block_sparse_matmul(x, w_blocks, idx, *, interpret: bool):
 
     # work scales with the STORED blocks only (the value-sparsity saving)
     stored = NT * MAXB * BK * BN
-    cost_kw = {} if CostEstimate is None else {"cost_estimate": CostEstimate(
+    cost = pl.CostEstimate(
         flops=2 * M * stored,
         bytes_accessed=(M * K * x.dtype.itemsize
                         + stored * w_blocks.dtype.itemsize
                         + NT * MAXB * 4 + M * N * x.dtype.itemsize),
-        transcendentals=0)}
+        transcendentals=0)
 
     return pl.pallas_call(
         functools.partial(_kernel, maxb=MAXB),
@@ -85,8 +85,8 @@ def _block_sparse_matmul(x, w_blocks, idx, *, interpret: bool):
             scratch_shapes=[pltpu.VMEM((BM, BN), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **cost_kw,
+        cost_estimate=cost,
     )(idx, x, w_blocks)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams, CostEstimate, resolve_interpret
+from ._compat import resolve_interpret
 
 BM, BK, BN = 128, 512, 128
 
@@ -59,11 +59,11 @@ def _fta_int8_matmul(x, w_q, scales, *, out_dtype, interpret: bool):
     grid = (M // BM, N // BN, nk)
 
     # weight traffic is the INT8 bytes (the bit-level saving vs bf16)
-    cost_kw = {} if CostEstimate is None else {"cost_estimate": CostEstimate(
+    cost = pl.CostEstimate(
         flops=2 * M * K * N,
         bytes_accessed=(M * K * x.dtype.itemsize + K * N + N * 4
                         + M * N * jnp.dtype(out_dtype).itemsize),
-        transcendentals=0)}
+        transcendentals=0)
 
     return pl.pallas_call(
         functools.partial(_kernel, nk=nk),
@@ -76,8 +76,8 @@ def _fta_int8_matmul(x, w_q, scales, *, out_dtype, interpret: bool):
         out_specs=pl.BlockSpec((BM, BN), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((BM, BN), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **cost_kw,
+        cost_estimate=cost,
     )(x, w_q, scales)

@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams, CostEstimate, resolve_interpret
+from ._compat import resolve_interpret
 
 BM, BK, BN = 128, 128, 128
 
@@ -75,10 +75,8 @@ def _kernel(idx_ref, x_ref, w_ref, scale_ref, o_ref, acc_ref, *, maxb: int):
 
 def _cost(M, K, NT, MAXB, bk, bn, x_itemsize, out_itemsize, w_itemsize):
     """Static CostEstimate: work scales with the STORED blocks only."""
-    if CostEstimate is None:                      # very old jax
-        return None
     stored = NT * MAXB * bk * bn
-    return CostEstimate(
+    return pl.CostEstimate(
         flops=2 * M * stored,
         bytes_accessed=(M * K * x_itemsize        # activations
                         + stored * w_itemsize     # payload (int8/bf16)
@@ -120,12 +118,6 @@ def _joint_sparse_matmul(x, w_blocks, idx, scales, *, out_dtype,
     out_dtype = x.dtype if out_dtype is None else out_dtype
     grid = (M // bm, NT, MAXB)
 
-    cost = _cost(M, K, NT, MAXB, bk, bn, x.dtype.itemsize,
-                 jnp.dtype(out_dtype).itemsize, w_blocks.dtype.itemsize)
-    # only pass the kwarg where this jax knows it (CostEstimate is None
-    # on versions whose pallas_call has no cost_estimate parameter)
-    cost_kw = {} if cost is None else {"cost_estimate": cost}
-
     return pl.pallas_call(
         functools.partial(_kernel, maxb=MAXB),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -143,8 +135,10 @@ def _joint_sparse_matmul(x, w_blocks, idx, scales, *, out_dtype,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **cost_kw,
+        cost_estimate=_cost(M, K, NT, MAXB, bk, bn, x.dtype.itemsize,
+                            jnp.dtype(out_dtype).itemsize,
+                            w_blocks.dtype.itemsize),
     )(idx, x, w_blocks, scales)

@@ -163,16 +163,18 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
                                        enc_out=enc_abs))
                 token_abs = jax.ShapeDtypeStruct((spec.global_batch, 1),
                                                  jnp.int32)
-                pspec, cspec, tspec = shard_fn(params_abs, cache_abs,
-                                               token_abs)
+                pspec, _, cspec, tspec = shard_fn(params_abs, None,
+                                                  cache_abs, token_abs)
                 jitted = jax.jit(step,
                                  in_shardings=(shr.named(pspec, mesh),
+                                               None,
                                                shr.named(cspec, mesh),
                                                shr.named(tspec, mesh)),
-                                 donate_argnums=(1,))
-                lowered = jitted.lower(params_abs, cache_abs, token_abs)
+                                 donate_argnums=(2,))
+                lowered = jitted.lower(params_abs, None, cache_abs,
+                                       token_abs)
                 rec["jaxpr_cost"] = jaxpr_cost.analyze(
-                    step, params_abs, cache_abs, token_abs)
+                    step, params_abs, None, cache_abs, token_abs)
 
             rec["lower_s"] = round(time.time() - t0, 1)
             t1 = time.time()

@@ -4,20 +4,29 @@ Defined as FUNCTIONS so importing this module never touches jax device
 state. The dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512
 before any jax import; smoke tests and benchmarks see the real single
 device and use `make_test_mesh`.
+
+Every axis is ``AxisType.Auto``: the sharding rules in runtime.sharding
+hand specs to jit and let the compiler propagate the rest, which explicit
+axes (jax.make_mesh's default) refuse for the KV-cache scatters.
 """
 
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh() -> Mesh:
     """1-device mesh with the production axis names (CPU tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))

@@ -45,6 +45,11 @@ raised), and ``--queue-cap`` bounds the admission queue with explicit
 load-shedding. ``--strict-admission`` restores the hard ValueError on
 oversized requests instead of a recorded rejection.
 
+A request that is not served (rejected, shed, or lost to a fault) makes
+the CLI exit non-zero unless ``--fault-rate``, ``--deadline-slack`` or
+``--queue-cap`` was given: without them, a lost request is a failure,
+not a policy.
+
 Observability: ``--trace-out trace.jsonl`` records the full two-clock
 span/event stream (repro.obs.Tracer) plus the per-call-kind weight
 waterfall and dumps it as JSONL — render with ``python -m
@@ -66,12 +71,14 @@ them on, outputs and device-call count are identical to a bare run.
 from __future__ import annotations
 
 import argparse
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_params
 from repro.models.transformer import encode
 from repro.serving import ServeEngine, WorkloadSpec, make_trace
@@ -90,8 +97,14 @@ def _spec_from(args) -> WorkloadSpec:
 
 
 def build_engine_and_trace(args, cfg):
-    """Shared by the CLI and benchmarks: engine + trace from parsed args."""
-    params = init_params(cfg, jax.random.PRNGKey(args.seed))
+    """Shared by the CLI and benchmarks: engine + trace from parsed args.
+    Prints the set-up seconds: random weights on the device ("init") and
+    stacked-table packing with stripping ("pack")."""
+    t0 = time.perf_counter()
+    params = jax.block_until_ready(
+        init_params(cfg, jax.random.PRNGKey(args.seed)))
+    t_init = time.perf_counter() - t0
+    t0 = time.perf_counter()
 
     stacked_tables = None
     if cfg.dbpim and cfg.dbpim_mode != "dense":
@@ -113,6 +126,9 @@ def build_engine_and_trace(args, cfg):
                   f"{len(stacked_tables.arrays)} projection families "
                   f"packed, {nbytes/1e6:.2f} MB stacked tables "
                   f"(dense copies stripped)")
+    jax.block_until_ready((params, stacked_tables))
+    print(f"[serve] set-up: init {t_init:.2f} s, pack "
+          f"{time.perf_counter() - t0:.2f} s")
 
     enc_out = None
     if cfg.is_encdec:
@@ -286,6 +302,7 @@ def main(argv=None):
                          "resume every stream bitwise (skips submission "
                          "— the trace is already in the journal)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced,
                      dbpim_mode=args.dbpim_mode,
@@ -346,6 +363,15 @@ def main(argv=None):
               f"{args.trace_out})")
     for rid in sorted(outputs):
         print(f"  req{rid}: {outputs[rid][:8]}...")
+    lost = s["n_requests"] - s["n_completed"]
+    may_lose = (args.fault_rate > 0 or args.deadline_slack is not None
+                or args.queue_cap is not None)
+    if lost and not may_lose:
+        raise SystemExit(
+            f"[serve] {lost}/{s['n_requests']} requests not served "
+            f"(rejected {s['n_rejected']}, shed {s['n_shed']}, faults "
+            f"{s['faults']}) and no --fault-rate, --deadline-slack or "
+            f"--queue-cap allows losing any")
     return outputs
 
 

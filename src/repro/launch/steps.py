@@ -129,36 +129,45 @@ RESTORE_TAG = "+restore"
 
 
 def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
-               stacked_tables=None, int8_weights: bool = False,
-               paged: bool = False):
+               int8_weights: bool = False, paged: bool = False):
     """One entry point for every fixed-shape serving step. Returns
     (step_fn, shardings_fn); step_fn carries a ``call_kind`` tag that
     runtime.jaxpr_cost.analyze_call_kinds and the serving engine consume
     for per-kind cost attribution.
 
+    Every step takes the stacked joint-sparse tables
+    (sparsity.sparse_linear.SegmentedKernelTables, from
+    build_stacked_tables(params, cfg)) as its SECOND argument, right
+    after params — ``None`` serves the plain dense matmuls. The tables are
+    a pytree whose layout is static, so a step compiles once per layout
+    and the packed payload stays an argument buffer instead of a constant
+    baked into the executable. With tables, every projection of every
+    layer runs the DB-PIM Pallas kernel (weight traffic (1 - vs) * 0.5 of
+    dense bf16 for joint; (1 - vs) for the bf16-payload value tables).
+
     call_kind selects the step:
 
-      * "serve" — plain (B, 1) decode step, ``(params, cache, token)``.
-        Tag "decode". int8_weights=True keeps projections in HBM as
-        INT8 + per-filter scale (the FTA/DB-PIM serving format),
+      * "serve" — plain (B, 1) decode step, ``(params, tables, cache,
+        token)``. Tag "decode". int8_weights=True keeps projections in
+        HBM as INT8 + per-filter scale (the FTA/DB-PIM serving format),
         dequantized in-graph so the dequant fuses into the matmuls —
-        halving decode weight traffic. Mutually exclusive with
-        stacked_tables (the tables carry their own payload).
+        halving decode weight traffic. Mutually exclusive with tables
+        (the tables carry their own payload).
       * "decode" — the serving engine's slot decode step,
-        ``(params, cache, token, active)``: inactive slots (free,
+        ``(params, tables, cache, token, active)``: inactive slots (free,
         draining, or mid-prefill while their neighbors decode) compute
         alongside the batch but their cache writes and position advances
         are discarded (models.decode.merge_slots) — continuous batching
         with ZERO per-request recompilation. Positions come from
         cache["pos"], a (B,) vector of per-slot depths. Tag "decode".
       * "prefill_chunk" — chunked cache-filling prefill,
-        ``(params, cache, tokens, n_valid)``: C prompt tokens per slot
-        in ONE fixed-shape device call (models.decode.decode_chunk), so
-        time-to-first-token is ceil(P/C) steps instead of P. n_valid (B,)
-        carries each slot's real token count this chunk (0 = slot not
-        prefilling; its cache is untouched). Tag "prefill_parallel" when
-        SSM segments run the parallel SSD chunk form (one read of the
-        stacked in/out projections per chunk;
+        ``(params, tables, cache, tokens, n_valid)``: C prompt tokens per
+        slot in ONE fixed-shape device call (models.decode.decode_chunk),
+        so time-to-first-token is ceil(P/C) steps instead of P. n_valid
+        (B,) carries each slot's real token count this chunk (0 = slot
+        not prefilling; its cache is untouched). Tag "prefill_parallel"
+        when SSM segments run the parallel SSD chunk form (one read of
+        the stacked in/out projections per chunk;
         models.ssm.prefill_ssm_parallel), "prefill_chunk_exact" when
         every segment's chunk math is bit-identical to sequential decode
         (attention chunks always are; SSM with cfg.prefill_exact).
@@ -175,20 +184,10 @@ def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
     attention write mask (pooled leaves have no batch dim for
     merge_slots to select on — inactive slots' writes are dropped at the
     scatter). "serve" (lock-step, no allocator) stays contiguous.
-
-    stacked_tables (sparsity.sparse_linear.SegmentedKernelTables, from
-    build_stacked_tables(params, cfg)): per-segment uniform-MAXB
-    joint-sparse weight packs riding each segment's layer scan, so every
-    projection of every layer runs the DB-PIM Pallas kernel — the
-    compiled serving HLO changes (weight traffic (1 - vs) * 0.5 of dense
-    bf16 for joint; (1 - vs) for the bf16-payload value tables).
     """
     if call_kind not in SERVE_CALL_KINDS:
         raise ValueError(f"call_kind {call_kind!r} not in "
                          f"{SERVE_CALL_KINDS}")
-    if int8_weights and stacked_tables is not None:
-        raise ValueError("int8_weights and stacked_tables are mutually "
-                         "exclusive serving formats")
     if int8_weights and call_kind != "serve":
         raise ValueError("int8_weights is a 'serve' step format")
     if paged and call_kind == "serve":
@@ -196,86 +195,83 @@ def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
                          "lock-step 'serve' step stays contiguous")
 
     if call_kind == "serve":
-        def step_fn(params, cache, token):
+        def step_fn(params, tables, cache, token):
             if int8_weights:
+                if tables is not None:
+                    raise ValueError("int8_weights and stacked tables are "
+                                     "mutually exclusive serving formats")
                 from repro.sparsity.sparse_linear import \
                     dequant_params_for_serving
                 params = dequant_params_for_serving(params)
-            return decode_step(params, cache, token, cfg,
-                               tables=stacked_tables)
+            return decode_step(params, cache, token, cfg, tables=tables)
         step_fn.call_kind = "decode"
 
-        def shardings(params, cache, token):
+        def shardings(params, tables, cache, token):
             pspec = _serving_param_specs(params, mesh)
             cspec = shr.cache_specs(cache, cfg, mesh)
             tspec = shr.batch_specs({"token": token}, mesh)["token"]
-            return pspec, cspec, tspec
+            return pspec, _table_specs(tables), cspec, tspec
 
     elif call_kind == "decode" and paged:
-        def step_fn(params, cache, token, active, ptab):
+        def step_fn(params, tables, cache, token, active, ptab):
             logits, new_cache = decode_step(params, cache, token, cfg,
-                                            tables=stacked_tables,
+                                            tables=tables,
                                             ptab=ptab, write_mask=active)
             return logits, merge_slots(new_cache, cache, active, cfg)
         step_fn.call_kind = "decode"
 
-        def shardings(params, cache, token, active, ptab):
+        def shardings(params, tables, cache, token, active, ptab):
             pspec = _serving_param_specs(params, mesh)
             cspec = shr.cache_specs(cache, cfg, mesh)
             bspec = shr.batch_specs({"token": token, "active": active},
                                     mesh)
             # page table: tiny int32, replicated — sharding it would
             # only add a gather before every pool lookup
-            return pspec, cspec, bspec["token"], bspec["active"], P()
+            return (pspec, _table_specs(tables), cspec, bspec["token"],
+                    bspec["active"], P())
 
     elif call_kind == "decode":
-        def step_fn(params, cache, token, active):
+        def step_fn(params, tables, cache, token, active):
             logits, new_cache = decode_step(params, cache, token, cfg,
-                                            tables=stacked_tables)
+                                            tables=tables)
             return logits, merge_slots(new_cache, cache, active, cfg)
         step_fn.call_kind = "decode"
 
-        def shardings(params, cache, token, active):
+        def shardings(params, tables, cache, token, active):
             pspec = _serving_param_specs(params, mesh)
             cspec = shr.cache_specs(cache, cfg, mesh)
             bspec = shr.batch_specs({"token": token, "active": active},
                                     mesh)
-            return pspec, cspec, bspec["token"], bspec["active"]
+            return (pspec, _table_specs(tables), cspec, bspec["token"],
+                    bspec["active"])
 
     elif paged:                            # "prefill_chunk", paged
-        def step_fn(params, cache, tokens, n_valid, ptab):
+        def step_fn(params, tables, cache, tokens, n_valid, ptab):
             return decode_chunk(params, cache, tokens, n_valid, cfg,
-                                tables=stacked_tables, ptab=ptab)
-        caps = cfg.serving_capabilities()
-        step_fn.call_kind = (
-            "prefill_parallel"
-            if caps.parallel_prefill and not cfg.prefill_exact
-            else "prefill_chunk_exact")
+                                tables=tables, ptab=ptab)
+        step_fn.call_kind = _chunk_kind(cfg)
 
-        def shardings(params, cache, tokens, n_valid, ptab):
+        def shardings(params, tables, cache, tokens, n_valid, ptab):
             pspec = _serving_param_specs(params, mesh)
             cspec = shr.cache_specs(cache, cfg, mesh)
             bspec = shr.batch_specs({"tokens": tokens, "n_valid": n_valid},
                                     mesh)
-            return (pspec, cspec, bspec["tokens"], bspec["n_valid"],
-                    P())
+            return (pspec, _table_specs(tables), cspec, bspec["tokens"],
+                    bspec["n_valid"], P())
 
     else:                                  # "prefill_chunk"
-        def step_fn(params, cache, tokens, n_valid):
+        def step_fn(params, tables, cache, tokens, n_valid):
             return decode_chunk(params, cache, tokens, n_valid, cfg,
-                                tables=stacked_tables)
-        caps = cfg.serving_capabilities()
-        step_fn.call_kind = (
-            "prefill_parallel"
-            if caps.parallel_prefill and not cfg.prefill_exact
-            else "prefill_chunk_exact")
+                                tables=tables)
+        step_fn.call_kind = _chunk_kind(cfg)
 
-        def shardings(params, cache, tokens, n_valid):
+        def shardings(params, tables, cache, tokens, n_valid):
             pspec = _serving_param_specs(params, mesh)
             cspec = shr.cache_specs(cache, cfg, mesh)
             bspec = shr.batch_specs({"tokens": tokens, "n_valid": n_valid},
                                     mesh)
-            return pspec, cspec, bspec["tokens"], bspec["n_valid"]
+            return (pspec, _table_specs(tables), cspec, bspec["tokens"],
+                    bspec["n_valid"])
 
     # which model family compiled this step — paired with call_kind it
     # forms the recompile sentinel's registry key and the tracer's
@@ -284,24 +280,34 @@ def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
     return step_fn, shardings
 
 
+def _chunk_kind(cfg: ModelConfig) -> str:
+    caps = cfg.serving_capabilities()
+    return ("prefill_parallel"
+            if caps.parallel_prefill and not cfg.prefill_exact
+            else "prefill_chunk_exact")
+
+
 def build_serve_step(cfg: ModelConfig, mesh: Mesh,
-                     int8_weights: bool = False, stacked_tables=None):
+                     int8_weights: bool = False):
     """Thin wrapper over build_step(call_kind="serve")."""
-    return build_step(cfg, mesh, "serve", stacked_tables=stacked_tables,
-                      int8_weights=int8_weights)
+    return build_step(cfg, mesh, "serve", int8_weights=int8_weights)
 
 
-def build_slot_decode_step(cfg: ModelConfig, mesh: Mesh,
-                           stacked_tables=None):
+def build_slot_decode_step(cfg: ModelConfig, mesh: Mesh):
     """Thin wrapper over build_step(call_kind="decode")."""
-    return build_step(cfg, mesh, "decode", stacked_tables=stacked_tables)
+    return build_step(cfg, mesh, "decode")
 
 
-def build_prefill_chunk_step(cfg: ModelConfig, mesh: Mesh,
-                             stacked_tables=None):
+def build_prefill_chunk_step(cfg: ModelConfig, mesh: Mesh):
     """Thin wrapper over build_step(call_kind="prefill_chunk")."""
-    return build_step(cfg, mesh, "prefill_chunk",
-                      stacked_tables=stacked_tables)
+    return build_step(cfg, mesh, "prefill_chunk")
+
+
+def _table_specs(tables):
+    # packed tables stay whole on every device: a tensor-parallel split
+    # would have to shard each tile's compacted blocks and index table
+    # together, which no mesh here needs yet
+    return jax.tree_util.tree_map(lambda _: P(), tables)
 
 
 def _serving_param_specs(params, mesh: Mesh):

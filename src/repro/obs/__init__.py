@@ -12,5 +12,4 @@ from .histogram import LogHistogram  # noqa: F401
 from .sentinel import RecompileError, RecompileSentinel  # noqa: F401
 from .trace import (EVENT_NAMES, SPAN_NAMES, TRACE_VERSION,  # noqa: F401
                     TraceError, Tracer, load, validate)
-from .waterfall import (engine_waterfall, serving_cost_by_kind,  # noqa: F401
-                        table_const_weights)
+from .waterfall import engine_waterfall, serving_cost_by_kind  # noqa: F401

@@ -18,22 +18,10 @@ see which structure pays them.
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict
 
 from repro.launch.steps import build_step
 from repro.runtime.jaxpr_cost import analyze_call_kinds
-
-
-def table_const_weights(tables) -> Optional[Dict[str, object]]:
-    """{label: array} for a SegmentedKernelTables' packed arrays, keyed
-    "tables/<family>/<part>" — the const_weights mapping
-    runtime.jaxpr_cost.analyze uses to attribute closed-over pallas
-    operands. None when serving dense (no tables)."""
-    if tables is None:
-        return None
-    return {f"tables/{fam}/{part}": arr
-            for fam, parts in tables.arrays.items()
-            for part, arr in parts.items()}
 
 
 def serving_cost_by_kind(cfg, mesh, params, cache, *, n_slots: int,
@@ -54,29 +42,28 @@ def serving_cost_by_kind(cfg, mesh, params, cache, *, n_slots: int,
     extra = ()
     if paged:
         extra = (jnp.full((n_slots, max_pages), -1, jnp.int32),)
-    decode_fn, _ = build_step(cfg, mesh, "decode", stacked_tables=tables,
-                              paged=paged)
+    decode_fn, _ = build_step(cfg, mesh, "decode", paged=paged)
     tok1 = jnp.zeros((n_slots, 1), jnp.int32)
     act = jnp.ones((n_slots,), bool)
     calls = {decode_fn.call_kind:
-             (decode_fn, (params, cache, tok1, act) + extra)}
+             (decode_fn, (params, tables, cache, tok1, act) + extra)}
     caps = cfg.serving_capabilities()
     if caps.chunked_prefill:
         tokc = jnp.zeros((n_slots, prefill_chunk), jnp.int32)
         nv = jnp.full((n_slots,), prefill_chunk, jnp.int32)
-        chunk_fn, _ = build_step(cfg, mesh, "prefill_chunk",
-                                 stacked_tables=tables, paged=paged)
+        chunk_fn, _ = build_step(cfg, mesh, "prefill_chunk", paged=paged)
         calls[chunk_fn.call_kind] = (chunk_fn,
-                                     (params, cache, tokc, nv) + extra)
+                                     (params, tables, cache, tokc, nv) + extra)
         if include_exact_fallback and caps.parallel_prefill \
                 and not cfg.prefill_exact:
             exact_fn, _ = build_step(cfg.scaled(prefill_exact=True), mesh,
-                                     "prefill_chunk", stacked_tables=tables,
-                                     paged=paged)
+                                     "prefill_chunk", paged=paged)
             calls[exact_fn.call_kind] = (exact_fn,
-                                         (params, cache, tokc, nv) + extra)
-    return analyze_call_kinds(calls,
-                              const_weights=table_const_weights(tables))
+                                         (params, tables, cache, tokc, nv)
+                                         + extra)
+    # params and the packed tables (args 0 and 1) seed the provenance
+    # tags: table leaves label as "tables/<family>/<part>"
+    return analyze_call_kinds(calls, weight_argnums=(0, 1))
 
 
 def engine_waterfall(engine) -> Dict[str, Dict[str, object]]:

@@ -44,10 +44,9 @@ serving path attacks. Three rules, in precedence order per operand:
 PER-PATH WATERFALL (`weight_bytes_by_path`): every byte charged into
 `weight_bytes` is ALSO attributed to the parameter path it came from —
 the provenance tags carry the pytree key path of the seeding leaf
-("blocks/attn/wq", "seg00/blocks/ssm/w_in", "blocks/moe/w1", ...), and
-`const_weights` extends the same tagging to arrays CLOSED OVER by the
-step function (the stacked kernel tables, matched by object identity
-against the jaxpr's constvars and labeled "tables/<family>/<part>").
+("blocks/attn/wq", "seg00/blocks/ssm/w_in", "blocks/moe/w1", ...; the
+stacked kernel tables, a step argument of their own, label as
+"tables/<family>/<part>").
 Bytes charged by the shape fallbacks, whose provenance is unknown, land
 in explicit "(untagged ...)" rows. The rows are charged at exactly the
 same sites with exactly the same integer byte values as the scalar, so
@@ -61,7 +60,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 
 def _dtype_bytes(aval) -> int:
@@ -325,23 +324,18 @@ def _path_str(key_path) -> str:
     return "/".join(parts)
 
 
-def analyze(fn, *args, weight_argnums: Tuple[int, ...] = (0,),
-            const_weights: Dict[str, Any] = None) -> Dict[str, float]:
+def analyze(fn, *args, weight_argnums: Tuple[int, ...] = (0,)
+            ) -> Dict[str, float]:
     """Trip-aware cost of `fn(*args)` (args may be ShapeDtypeStructs).
 
     weight_argnums: which positional args hold stored parameters — their
     leaves seed the provenance tags behind the exact weight_bytes rule
     (module docstring). Every call site in this repo passes params first,
-    so the default (0,) is right; pass () to fall back to the pure shape
-    heuristics (e.g. when arg 0 is an activation).
-
-    const_weights: {label: array-or-pytree} of stored weights the step
-    CLOSES OVER instead of taking as arguments — the serving engines
-    close over their stacked kernel tables. Leaves are matched by object
-    identity against the traced jaxpr's constvars and seed provenance
-    tags exactly like argument leaves do, so packed-table traffic is
-    attributed to its table path in ``weight_bytes_by_path`` instead of
-    the untagged-pallas fallback row.
+    so the default (0,) is right; the serving steps also name their
+    packed-table argument (1), so table traffic is attributed to its
+    table path in ``weight_bytes_by_path`` instead of the untagged-pallas
+    fallback row. Pass () to fall back to the pure shape heuristics (e.g.
+    when arg 0 is an activation).
 
     The result's ``weight_bytes_by_path`` maps parameter paths to the
     weight bytes charged against them; its values sum to
@@ -360,17 +354,6 @@ def analyze(fn, *args, weight_argnums: Tuple[int, ...] = (0,),
                 invars = closed.jaxpr.invars[offsets[i]:offsets[i + 1]]
                 for (kp, _), v in zip(paths, invars):
                     tags[v] = _path_str(kp)
-    if const_weights:
-        by_id = {}
-        for label, tree in const_weights.items():
-            for kp, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-                suffix = _path_str(kp)
-                by_id[id(leaf)] = (label + "/" + suffix if suffix
-                                   else label)
-        for cv, cval in zip(closed.jaxpr.constvars, closed.consts):
-            label = by_id.get(id(cval))
-            if label is not None:
-                tags[cv] = label
     wf: Dict[str, float] = {}
     _walk(closed.jaxpr, 1, acc, weight_vars=tags, wf=wf)
     # argument + result residency: params/opt-state are read and written
@@ -382,7 +365,7 @@ def analyze(fn, *args, weight_argnums: Tuple[int, ...] = (0,),
 
 
 def analyze_call_kinds(calls: Dict[str, tuple],
-                       const_weights: Dict[str, Any] = None
+                       weight_argnums: Tuple[int, ...] = (0,)
                        ) -> Dict[str, Dict[str, float]]:
     """Per-engine-call-kind cost attribution.
 
@@ -394,7 +377,7 @@ def analyze_call_kinds(calls: Dict[str, tuple],
     call that pays it instead of collapsing into one blended number: the
     chunked-prefill traffic savings the benchmarks guard are per-KIND
     contracts (a parallel SSM chunk reads its projections once, an exact
-    chunk C times, a decode step once per token). ``const_weights`` is
+    chunk C times, a decode step once per token). ``weight_argnums`` is
     forwarded to every analyze call (see analyze)."""
-    return {kind: analyze(fn, *args, const_weights=const_weights)
+    return {kind: analyze(fn, *args, weight_argnums=weight_argnums)
             for kind, (fn, args) in calls.items()}

@@ -326,7 +326,6 @@ class ServeEngine:
         self.restore_stats: Optional[dict] = None
 
         self.params = params
-        self.stacked_tables = stacked_tables
         with self.mesh:
             cache = init_cache(
                 cfg, n_slots, max_len, enc_out=enc_out,
@@ -339,60 +338,57 @@ class ServeEngine:
                 cache["attn"]["pos"] = jnp.zeros((n_slots,), jnp.int32)
             self.cache = cache
 
-            decode_fn, shard_fn = build_step(
-                cfg, self.mesh, "decode", stacked_tables=stacked_tables,
-                paged=self.paged)
-            tok0 = jnp.zeros((n_slots, 1), jnp.int32)
-            act0 = jnp.zeros((n_slots,), bool)
+            decode_fn, shard_fn = build_step(cfg, self.mesh, "decode",
+                                             paged=self.paged)
+            dec_args = (params, stacked_tables, cache,
+                        jnp.zeros((n_slots, 1), jnp.int32),
+                        jnp.zeros((n_slots,), bool))
             if self.paged:
-                pt0 = jnp.full((n_slots, self.max_pages_per_slot), -1,
-                               jnp.int32)
-                pspec, cspec, tspec, aspec, ptspec = shard_fn(
-                    params, cache, tok0, act0, pt0)
-            else:
-                pspec, cspec, tspec, aspec = shard_fn(params, cache, tok0,
-                                                      act0)
+                dec_args = dec_args + (jnp.full(
+                    (n_slots, self.max_pages_per_slot), -1, jnp.int32),)
+            dec_in = tuple(shr.named(s, self.mesh)
+                           for s in shard_fn(*dec_args))
+            cache_sh = dec_in[2]
+            # the packed tables are step ARGUMENTS, placed once here: a
+            # table closed over by the step would be baked into each
+            # executable as a constant (and re-sent every call if left
+            # on the host)
+            self.stacked_tables = (None if stacked_tables is None else
+                                   jax.device_put(stacked_tables, dec_in[1]))
             # COMMIT the fresh cache to its serving sharding up front:
             # otherwise the first jitted call returns committed outputs
             # whose signature differs from the uncommitted init arrays,
             # and reset/prefill each compile a second, steady-state
             # variant at tick 1 (the recompile sentinel caught this)
-            self.cache = jax.device_put(self.cache,
-                                        shr.named(cspec, self.mesh))
+            self.cache = jax.device_put(self.cache, cache_sh)
             # kept for restore: a snapshot's host cache re-enters the
             # device under the exact serving sharding
-            self._cache_sharding = shr.named(cspec, self.mesh)
+            self._cache_sharding = cache_sh
             # out_shardings pin the returned cache to the SAME spec the
             # steps take it with: left to propagation, XLA hands attn
             # k/v back replicated, and every consumer (reset, prefill)
             # compiles a second steady-state variant at tick 1 — the
             # recompile sentinel caught this
-            dec_in = (shr.named(pspec, self.mesh),
-                      shr.named(cspec, self.mesh),
-                      shr.named(tspec, self.mesh),
-                      shr.named(aspec, self.mesh))
-            if self.paged:
-                dec_in = dec_in + (shr.named(ptspec, self.mesh),)
             self._decode = jax.jit(
                 decode_fn,
                 in_shardings=dec_in,
-                out_shardings=(None, shr.named(cspec, self.mesh)),
-                donate_argnums=(1,))
+                out_shardings=(None, cache_sh),
+                donate_argnums=(2,))
             self._prefill = None
             if prefill_mode == "chunked":
                 self._prefill = build_chunk_step(
-                    cfg, self.mesh, params, cache, n_slots, prefill_chunk,
-                    stacked_tables=stacked_tables, paged=self.paged,
+                    cfg, self.mesh, params, stacked_tables, cache, n_slots,
+                    prefill_chunk, paged=self.paged,
                     max_pages=self.max_pages_per_slot)
             if self.paged:
                 self._reset = jax.jit(
                     lambda c, m, pt: reset_slots(c, m, cfg, ptab=pt),
-                    out_shardings=shr.named(cspec, self.mesh),
+                    out_shardings=cache_sh,
                     donate_argnums=(0,))
             else:
                 self._reset = jax.jit(
                     lambda c, m: reset_slots(c, m, cfg),
-                    out_shardings=shr.named(cspec, self.mesh),
+                    out_shardings=cache_sh,
                     donate_argnums=(0,))
 
         # which chunk math this engine's prefill executable compiles to
@@ -946,8 +942,8 @@ class ServeEngine:
                     replay=replaying, restore=restoring)
                 if self.tracer is not None else None)
         c0 = time.monotonic()
-        args = (self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(n_valid))
+        args = (self.params, self.stacked_tables, self.cache,
+                jnp.asarray(tokens), jnp.asarray(n_valid))
         if self.paged:
             args = args + (self._ptab(),)
         res = self._device_call("prefill", self.prefill_kind,
@@ -1005,8 +1001,8 @@ class ServeEngine:
                     occupancy=float(active.mean()))
                 if self.tracer is not None else None)
         c0 = time.monotonic()
-        args = (self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(active))
+        args = (self.params, self.stacked_tables, self.cache,
+                jnp.asarray(tokens), jnp.asarray(active))
         if self.paged:
             args = args + (self._ptab(),)
         res = self._device_call("decode", "decode", self._decode, *args)
@@ -1106,8 +1102,11 @@ class ServeEngine:
                  if self.slots[s].state is not SlotState.FREE]
         if not slots:
             return
-        self.cache = corrupt_cache(self.cache, slots, self.n_slots,
-                                   self.cfg)
+        # eager corruption hands back arrays off the serving sharding;
+        # re-place them so the next step does not compile a new variant
+        self.cache = jax.device_put(
+            corrupt_cache(self.cache, slots, self.n_slots, self.cfg),
+            self._cache_sharding)
         for s in slots:
             self.metrics.on_fault("cache_corruption", self.slots[s].rid,
                                   tick)

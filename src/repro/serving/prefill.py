@@ -62,10 +62,12 @@ def assemble_chunk(prompts: Dict[int, np.ndarray], cursors: Dict[int, int],
     return tokens, n_valid
 
 
-def build_chunk_step(cfg, mesh, params, cache, n_slots: int, chunk: int,
-                     stacked_tables=None, paged: bool = False,
-                     max_pages: int = 0):
+def build_chunk_step(cfg, mesh, params, tables, cache, n_slots: int,
+                     chunk: int, paged: bool = False, max_pages: int = 0):
     """Jit the fixed-shape chunk prefill step with serving shardings.
+    The jitted step is called ``(params, tables, cache, tokens, n_valid
+    [, ptab])``; ``tables`` (None for dense serving) fixes the table
+    layout the step is sharded for.
 
     Compiles ONCE for (n_slots, chunk) — every request, whatever its
     prompt length, flows through this single executable (ragged tails via
@@ -77,29 +79,20 @@ def build_chunk_step(cfg, mesh, params, cache, n_slots: int, chunk: int,
     not cache state — page churn between calls never recompiles."""
     import jax.numpy as jnp
 
-    step_fn, shard_fn = build_step(cfg, mesh, "prefill_chunk",
-                                   stacked_tables=stacked_tables,
-                                   paged=paged)
-    tok0 = jnp.zeros((n_slots, chunk), jnp.int32)
-    nv0 = jnp.zeros((n_slots,), jnp.int32)
+    step_fn, shard_fn = build_step(cfg, mesh, "prefill_chunk", paged=paged)
+    args = (params, tables, cache, jnp.zeros((n_slots, chunk), jnp.int32),
+            jnp.zeros((n_slots,), jnp.int32))
     if paged:
-        pt0 = jnp.full((n_slots, max_pages), -1, jnp.int32)
-        pspec, cspec, tspec, nspec, ptspec = shard_fn(params, cache, tok0,
-                                                      nv0, pt0)
-        in_sh = (shr.named(pspec, mesh), shr.named(cspec, mesh),
-                 shr.named(tspec, mesh), shr.named(nspec, mesh),
-                 shr.named(ptspec, mesh))
-    else:
-        pspec, cspec, tspec, nspec = shard_fn(params, cache, tok0, nv0)
-        in_sh = (shr.named(pspec, mesh), shr.named(cspec, mesh),
-                 shr.named(tspec, mesh), shr.named(nspec, mesh))
+        args = args + (jnp.full((n_slots, max_pages), -1, jnp.int32),)
+    specs = shard_fn(*args)
+    cspec = specs[2]
     jitted = jax.jit(step_fn,
-                     in_shardings=in_sh,
+                     in_shardings=tuple(shr.named(s, mesh) for s in specs),
                      # pin the returned cache to the spec it arrives
                      # with; propagated (replicated) output shardings
                      # make downstream steps recompile at tick 1
                      out_shardings=(None, shr.named(cspec, mesh)),
-                     donate_argnums=(1,))
+                     donate_argnums=(2,))
     # per-kind cost attribution rides along (jaxpr_cost.analyze_call_kinds)
     jitted.call_kind = step_fn.call_kind
     jitted.arch = cfg.name

@@ -351,6 +351,36 @@ class SegmentedKernelTables:
                 for s, seg in self.segments.items()
                 for name, t in seg.static.items()}
 
+    # -- pytree: the packed arrays are leaves, the layout is static -------
+    # Serving steps take the tables as a jit ARGUMENT (not a closure
+    # constant, which would bake the int8 payload into the executable).
+    # The one child is the flat ``arrays`` view under the key "tables",
+    # so leaf paths read "tables/<family>/<part>" — the labels the weight
+    # waterfall (runtime.jaxpr_cost) attributes packed traffic to.
+
+    def _tree_flatten_with_keys(self):
+        layout = tuple((seg_name, tuple(seg.arrays),
+                        tuple(sorted(seg.static.items())), seg.interpret)
+                       for seg_name, seg in self.segments.items())
+        return [(jax.tree_util.GetAttrKey("tables"), self.arrays)], layout
+
+    @classmethod
+    def _tree_unflatten(cls, layout, children):
+        (flat,) = children
+        single = [s for s, *_ in layout] == ["blocks"]
+        segments = {}
+        for seg_name, names, static, interpret in layout:
+            segments[seg_name] = StackedKernelTables(
+                arrays={n: flat[n if single else f"{seg_name}/{n}"]
+                        for n in names},
+                static=dict(static), interpret=interpret)
+        return cls(segments=segments)
+
+
+jax.tree_util.register_pytree_with_keys(
+    SegmentedKernelTables, SegmentedKernelTables._tree_flatten_with_keys,
+    SegmentedKernelTables._tree_unflatten)
+
 
 def _stacked_projections(params, cfg: ModelConfig):
     """segment name -> {hook name -> stacked weight} for every decoder

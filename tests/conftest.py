@@ -4,10 +4,21 @@ cache through sequential decode steps, or through fixed-shape
 decode_chunk calls with ragged tails — the two prefill paths every
 equivalence test compares."""
 
-import jax.numpy as jnp
-import numpy as np
+import os
 
-from repro.models import decode_chunk, decode_step, init_cache
+# The chunk-vs-stepwise tests compare logits bitwise. XLA:CPU's
+# multi-threaded Eigen matmul picks its summation order from the operand
+# shapes and the host's thread pool, so a (B, C) chunk and a (B, 1) step
+# can round differently on one machine and identically on another; one
+# thread per matmul fixes the order everywhere. Set before any backend
+# starts.
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_cpu_multi_thread_eigen=false")))
+
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from repro.models import decode_chunk, decode_step, init_cache  # noqa: E402
 
 
 def stepwise_prefill(params, cfg, prompts, max_len, tables=None):

@@ -200,7 +200,7 @@ def test_engine_sentinel_one_compile_per_step(tiny):
 def test_waterfall_rows_sum_exactly_to_weight_bytes(arch):
     """Every modeled weight byte lands in exactly one parameter-path row:
     sum(rows) == weight_bytes with NO tolerance, stacked tables
-    included (closure-const attribution)."""
+    included (attributed through the step's table argument)."""
     cfg = get_config(arch, reduced=True, dbpim_mode="joint").scaled(
         n_layers=2, d_model=64, vocab_size=64)
     params = init_params(cfg, jax.random.PRNGKey(0))

@@ -334,8 +334,8 @@ def test_build_step_tags_and_validation():
 
     with pytest.raises(ValueError, match="call_kind"):
         build_step(llama, mesh, "train")
+    serve_i8, _ = build_step(llama, mesh, "serve", int8_weights=True)
     with pytest.raises(ValueError, match="mutually"):
-        build_step(llama, mesh, "serve", int8_weights=True,
-                   stacked_tables=object())
+        serve_i8(None, object(), None, None)
     with pytest.raises(ValueError, match="serve"):
         build_step(llama, mesh, "decode", int8_weights=True)

@@ -325,11 +325,32 @@ def test_assemble_chunk_ragged():
 
 # ------------------------------------------------------------- serve CLI --
 
-def test_serve_cli_drives_engine(capsys):
+def test_serve_cli_drives_engine(capsys, monkeypatch, tmp_path):
     from repro.launch.serve import main
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     out = main(["--arch", "tinyllama-1.1b", "--reduced", "--batch", "2",
                 "--max-len", "16", "--requests", "3", "--gen-len", "3",
                 "--prompt-len", "2", "6", "--prefill-chunk", "4",
                 "--dbpim-mode", "joint"])
     assert len(out) == 3 and all(len(v) == 3 for v in out.values())
     assert "tokens/step" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("slo_flags,lost_ok", [
+    ([], False),
+    (["--queue-cap", "8"], True)])
+def test_serve_cli_exits_nonzero_on_lost_requests(monkeypatch, tmp_path,
+                                                   slo_flags, lost_ok):
+    """Prompts longer than --max-len are rejected as oversized. With no
+    flag that allows losing requests, the CLI must fail instead of
+    exiting 0 with a partial result."""
+    from repro.launch.serve import main
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    argv = ["--arch", "tinyllama-1.1b", "--reduced", "--batch", "2",
+            "--max-len", "16", "--requests", "3", "--gen-len", "2",
+            "--prompt-len", "15", "20", "--prefill-chunk", "4"] + slo_flags
+    if lost_ok:
+        assert main(argv) == {}
+    else:
+        with pytest.raises(SystemExit, match="requests not served"):
+            main(argv)

@@ -279,9 +279,10 @@ def test_serve_step_rejects_conflicting_weight_formats():
     from repro.launch.mesh import make_test_mesh
     from repro.launch.steps import build_serve_step
     cfg, params, tables = _setup("tinyllama-1.1b")
+    step, _ = build_serve_step(cfg, make_test_mesh(), int8_weights=True)
     with pytest.raises(ValueError):
-        build_serve_step(cfg, make_test_mesh(), int8_weights=True,
-                         stacked_tables=tables)
+        step(params, tables, init_cache(cfg, 1, 8),
+             jnp.ones((1, 1), jnp.int32))
 
 
 # ------------------------------------------------ interpret default -------
