@@ -519,7 +519,7 @@ def bench_chaos(arch: str = "tinyllama-1.1b",
         "slot_busy_frac": s["slot_busy_frac"],
         "faults_detected": detected,
         "retries": s["retries"], "replays": s["replays"],
-        "n_shed": s["n_shed"], "straggler_ticks": s["straggler_ticks"],
+        "n_shed": s["n_shed"],
         "calls_by_kind": s["calls_by_kind"],
         "engine_ticks_fault_free": ref_s["engine_ticks"],
         "engine_ticks_chaos": s["engine_ticks"],

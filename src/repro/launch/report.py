@@ -7,7 +7,11 @@
 Sections (each reads one record type of the obs.trace taxonomy):
 
   * TIMELINE   — per-call-kind span latency (count, total, p50/p95 from
-    the recorded dur_us) plus engine-tick stats;
+    the recorded dur_us; a "call" runs from input assembly until its
+    logits are on the host) plus engine-tick stats, and each host
+    phase's self time per tick: schedule, call (input assembly and
+    dispatch), logits (the wait for the step and the copy), sample,
+    commit, and the rest of the tick;
   * SLOTS      — per-slot occupancy bars from the closed SlotIntervals
     (the engine's audit log), busy fraction per slot and overall;
   * QUEUE      — queue-depth-over-time sparkline from the tick spans'
@@ -31,7 +35,7 @@ import sys
 from collections import defaultdict
 from typing import Dict, List
 
-from repro.obs import to_chrome_trace, validate
+from repro.obs import SPAN_NAMES, to_chrome_trace, validate
 from repro.obs.trace import load
 
 #: sparkline glyphs, lowest to highest occupancy
@@ -61,6 +65,25 @@ def _fmt_bytes(b: float) -> str:
             return f"{b:.1f} {unit}" if unit != "B" else f"{b:.0f} B"
         b /= 1024
     return f"{b:.1f} GB"
+
+
+def self_times(spans: List[dict]) -> Dict[int, Dict[str, float]]:
+    """Self time in us of each span name per tick: a span's duration
+    less its children's. Spans are start-ordered and nest LIFO, so a
+    stack of the open spans' end times finds each span's parent."""
+    out: Dict[int, Dict[str, float]] = defaultdict(lambda: defaultdict(
+        float))
+    stack: List[tuple] = []               # (end_us, tick, name)
+    for r in spans:
+        start = r["ts_us"]
+        while stack and stack[-1][0] <= start:
+            stack.pop()
+        if stack:
+            _, tick, name = stack[-1]
+            out[tick][name] -= r["dur_us"]
+        out[r["tick"]][r["name"]] += r["dur_us"]
+        stack.append((start + r["dur_us"], r["tick"], r["name"]))
+    return out
 
 
 def render(records: List[dict], width: int = 64) -> str:
@@ -106,6 +129,21 @@ def render(records: List[dict], width: int = 64) -> str:
                      f"p50={_percentile(durs, 0.5):.2f} "
                      f"p95={_percentile(durs, 0.95):.2f} ms  "
                      f"total={sum(durs):.1f} ms{occ_s}")
+    if ticks:
+        per_tick = self_times(spans)
+        wall = max(sum(t["dur_us"] for t in ticks), 1e-9)
+        label = {"call": "call (dispatch)", "tick": "tick (rest)"}
+        lines.append("  host phase self time per tick (mean / p95 ms, "
+                     "share of tick wall):")
+        for name in SPAN_NAMES[1:] + SPAN_NAMES[:1]:
+            vals = sorted(per_tick[t["tick"]].get(name, 0.0) / 1e3
+                          for t in ticks)
+            if not any(vals):
+                continue
+            lines.append(f"    {label.get(name, name):<18} "
+                         f"{sum(vals) / len(vals):>8.3f} "
+                         f"{_percentile(vals, 0.95):>8.3f}  "
+                         f"{sum(vals) * 1e3 / wall:>6.1%}")
 
     # -- SLOTS -------------------------------------------------------------
     if intervals and ticks:

@@ -333,8 +333,7 @@ def main(argv=None):
     if s["n_faults"] or s["n_rejected"] or s["n_shed"]:
         print(f"[serve] goodput {s['goodput']:.2f}  faults {s['faults']}  "
               f"retries {s['retries']}  replays {s['replays']}  "
-              f"rejected {s['n_rejected']}  shed {s['n_shed']}  "
-              f"straggler_ticks {s['straggler_ticks']}")
+              f"rejected {s['n_rejected']}  shed {s['n_shed']}")
     if engine.paged:
         pu = (f"{s['pages_used_mean']:.2f}"
               if s["pages_used_mean"] is not None else "n/a")

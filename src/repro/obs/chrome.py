@@ -2,8 +2,9 @@
 
 ``to_chrome_trace`` reformats the obs.trace record stream into the
 Trace Event Format JSON that chrome://tracing and https://ui.perfetto.dev
-open directly: one process, thread 0 for the engine ("tick" and "call"
-spans as complete "X" events), one thread per cache slot carrying that
+open directly: one process, thread 0 for the engine (its spans, "tick"
+and the host phases nested in it, as complete "X" events), one thread
+per cache slot carrying that
 slot's occupancy intervals (rendered as "rid<N>" spans) and lifecycle
 instants. Wall microseconds map straight onto the trace clock; engine
 ticks ride along in every event's ``args`` so the two clocks stay

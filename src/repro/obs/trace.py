@@ -10,16 +10,23 @@ Every record carries BOTH clocks the serving stack reasons in:
     (``ts_us``/``dur_us``), for latency attribution and the Chrome-trace
     timeline. Wall times are reporting-only; no guard compares them.
 
+Every span is also entered as a ``jax.profiler.TraceAnnotation`` named
+``engine.<name>``, so under a running profiler it lands on the host
+plane of the ``.xplane.pb``, on the same clock as the device's events:
+a device idle gap can then be charged to the span the host was in.
+
 Record taxonomy (one JSON object per line in the JSONL dump):
 
   ==========  =========================================================
   type        fields
   ==========  =========================================================
   meta        version, arch, plus engine config (first record)
-  span        name ("tick" | "call"), tick, ts_us, dur_us, attrs
-  event       name (admit | prefill | first_token | quarantine |
-              replay | shed | reject | release | fault | retry |
-              crash | snapshot | restore), tick, ts_us, attrs
+  span        name (tick | schedule | call | logits | sample |
+              commit), tick, ts_us, dur_us, attrs
+  event       name (submit | admit | prefill | first_token |
+              quarantine | replay | preempt | shed | reject | release |
+              fault | retry | crash | snapshot | restore), tick, ts_us,
+              attrs
   interval    slot, rid, admit_tick, release_tick — one closed
               SlotInterval from the engine's slot audit log
   waterfall   kind, total, rows {param path -> weight bytes} — the
@@ -29,8 +36,28 @@ Record taxonomy (one JSON object per line in the JSONL dump):
 Span records are appended at BEGIN time (their ``dur_us`` is filled in
 at end), so the record list is start-ordered and ``validate`` can check
 wall-clock monotonicity by simple iteration. ``begin``/``end`` enforce
-LIFO nesting: a "call" span always closes before its enclosing "tick"
-span, which is what makes the Chrome conversion a pure reformat.
+LIFO nesting: every other span closes before its enclosing "tick" span
+(and "logits" before its "call"), which is what makes the Chrome
+conversion a pure reformat and the profiler annotations safe to enter
+and leave by hand.
+
+The engine's spans split one tick by host phase:
+
+  ========  ===========================================================
+  span      covers
+  ========  ===========================================================
+  tick      the whole tick, journal commit and snapshot included
+  schedule  cache-fault injection, deadline shedding, admission (with
+            its slot-reset dispatch) and, when paged, page growth
+  call      one device call, from input assembly to its logits on the
+            host; prefill calls carry ``rows`` (slots x chunk) and
+            ``rows_valid`` (prompt tokens in the chunk)
+  logits    inside "call": the wait for the step and the device-to-host
+            copy of the last position's logits
+  sample    after each call: argmax, the finite guard and the per-slot
+            state updates (a finished prefill's first-logits pull too)
+  commit    journal commit and snapshot, when either runs
+  ========  ===========================================================
 
 The tracer is PASSIVE: it never issues device calls and never touches
 engine decisions, so tracing on vs off is bitwise-output- and
@@ -44,17 +71,21 @@ import json
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 TRACE_VERSION = 1
 
 #: span names the engine emits; anything else fails validation
-SPAN_NAMES = ("tick", "call")
+SPAN_NAMES = ("tick", "schedule", "call", "logits", "sample", "commit")
 #: instant-event names the engine emits; crash/snapshot/restore are the
 #: durability lifecycle (serving.journal / serving.snapshot) — one
 #: tracer may span a kill + warm restart, and stays valid because the
 #: restored engine resumes at a strictly later tick
-EVENT_NAMES = ("admit", "prefill", "first_token", "quarantine", "replay",
-               "shed", "reject", "release", "fault", "retry",
-               "crash", "snapshot", "restore")
+EVENT_NAMES = ("submit", "admit", "prefill", "first_token", "quarantine",
+               "replay", "preempt", "shed", "reject", "release", "fault",
+               "retry", "crash", "snapshot", "restore")
+#: prefix of the spans' profiler annotations
+ANNOTATION_PREFIX = "engine."
 
 
 class TraceError(RuntimeError):
@@ -76,6 +107,7 @@ class Tracer:
             "type": "meta", "version": TRACE_VERSION, "arch": arch,
             **(meta or {})}]
         self._open: List[dict] = []
+        self._annotations: List[TraceAnnotation] = []
 
     # -- clocks ------------------------------------------------------------
     def _now_us(self) -> float:
@@ -84,11 +116,16 @@ class Tracer:
     # -- spans -------------------------------------------------------------
     def begin(self, name: str, tick: int, **attrs) -> dict:
         """Open a span; returns the handle ``end`` takes. The record is
-        appended NOW (start-ordered stream); dur_us lands at ``end``."""
+        appended NOW (start-ordered stream); dur_us lands at ``end``.
+        The span's profiler annotation is entered here and left at
+        ``end``."""
+        ann = TraceAnnotation(ANNOTATION_PREFIX + name)
+        ann.__enter__()
         span = {"type": "span", "name": name, "tick": int(tick),
                 "ts_us": self._now_us(), "dur_us": None, "attrs": attrs}
         self.records.append(span)
         self._open.append(span)
+        self._annotations.append(ann)
         return span
 
     def end(self, span: dict, **attrs):
@@ -101,6 +138,7 @@ class Tracer:
                 f"{[s['name'] for s in self._open]})")
         self._open.pop()
         span["dur_us"] = self._now_us() - span["ts_us"]
+        self._annotations.pop().__exit__(None, None, None)
         if attrs:
             span["attrs"].update(attrs)
 
@@ -157,8 +195,8 @@ def validate(records: List[dict]) -> Dict[str, int]:
         order (spans are start-ordered by construction);
       * tick numbers are monotone non-decreasing;
       * every span was closed (dur_us set, >= 0) and has a known name;
-      * every "call" span lies WITHIN its tick's "tick" span on the wall
-        clock, and "tick" spans never overlap each other;
+      * every other span lies WITHIN its tick's "tick" span on the
+        wall clock, and "tick" spans never overlap each other;
       * slot intervals on one slot never overlap, release > admit.
 
     Returns counting stats ({"spans": n, "events": n, "intervals": n,
@@ -217,19 +255,20 @@ def validate(records: List[dict]) -> Dict[str, int]:
             raise TraceError(f"record {i}: tick went backwards "
                              f"({r['tick']} < {last_tick})")
         last_tick = r["tick"]
-    # call-in-tick containment (wall clock)
+    # span-in-tick containment (wall clock)
     for r in records[1:]:
-        if r.get("type") == "span" and r["name"] == "call":
+        if r.get("type") == "span" and r["name"] != "tick":
+            name = r["name"]
             parent = tick_spans.get(r["tick"])
             if parent is None:
-                raise TraceError(f"call span at tick {r['tick']} has no "
+                raise TraceError(f"{name} span at tick {r['tick']} has no "
                                  f"tick span")
             if r["ts_us"] < parent["ts_us"] - 1e-6 or \
                     r["ts_us"] + r["dur_us"] > \
                     parent["ts_us"] + parent["dur_us"] + 1e-6:
                 raise TraceError(
-                    f"call span at tick {r['tick']} escapes its tick span "
-                    f"on the wall clock")
+                    f"{name} span at tick {r['tick']} escapes its tick "
+                    f"span on the wall clock")
     # per-slot interval exclusivity
     by_slot: Dict[int, List[dict]] = {}
     for r in records[1:]:

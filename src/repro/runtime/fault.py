@@ -20,9 +20,9 @@ the `failure_hook`; the control flow is identical on real fleets.
 
 The SERVING engine has its own request-granular fault layer
 (serving.faults + serving.engine: per-slot quarantine and
-recovery-by-replay instead of checkpoint restore) but reuses
-`StragglerMonitor` verbatim for per-tick wall timing — outlier ticks
-surface as `straggler_ticks` in serving.metrics.summary().
+recovery-by-replay instead of checkpoint restore). It keeps no
+straggler count: a tick that carries a prefill chunk is slower than a
+decode-only tick by design, so a median threshold would flag every one.
 """
 
 from __future__ import annotations

@@ -86,27 +86,33 @@ training loop at checkpoint granularity):
   * A zero-fault plan is free: no extra device calls, bitwise-identical
     outputs (the chaos bench's no-overhead guard).
 
-Per-tick wall time feeds a runtime.fault.StragglerMonitor; outlier
-ticks are counted in metrics ("straggler_ticks").
-
 Observability (repro.obs) — all of it PASSIVE; with ``tracer=None``
 (default) outputs and device-call count are bitwise identical to a
 traced run (the zero-overhead contract the chaos bench guards):
 
-  * ``tracer=Tracer()`` records two-clock spans ("tick" per engine
-    tick, "call" per device call with call_kind/arch/occupancy/replay
-    attrs), slot lifecycle events (admit / prefill / first_token /
-    quarantine / replay / shed / reject / release / fault / retry), and
-    the closed
-    SlotIntervals — JSONL via tracer.dump, Chrome trace via obs.chrome,
-    rendered by ``python -m repro.launch.report``.
+  * ``tracer=Tracer()`` records two-clock spans that split each tick
+    by host phase: "tick", and inside it "schedule" (fault injection,
+    shedding, admission, page growth), one "call" per device call
+    (call_kind/arch/occupancy/replay attrs, plus rows/rows_valid on
+    prefill chunks) running from input assembly until the logits are
+    on the host, its child "logits" (the wait for the step and the
+    device-to-host copy), one "sample" per call (argmax, finite guard,
+    slot updates), and "commit" (journal commit, snapshot). It also
+    records slot lifecycle events (submit / admit / prefill /
+    first_token / quarantine / replay / preempt / shed / reject /
+    release / fault / retry) and the closed SlotIntervals — JSONL via
+    tracer.dump, Chrome trace via obs.chrome, rendered by ``python -m
+    repro.launch.report``. Each span is also a profiler annotation
+    (``engine.<name>``), so a device trace shows what the host was
+    doing in each idle gap.
   * the RECOMPILE SENTINEL (on by default) registers every jitted step
     under its (call_kind, arch) key and raises obs.RecompileError the
     tick any of them compiles more than once — the fixed-shape
     no-recompile contract above, enforced instead of assumed.
-  * every device call's wall latency feeds a log-bucketed per-kind
-    histogram (metrics.summary()["call_latency_ms"]: p50/p95/p99
-    without storing raw samples).
+  * every device call's wall latency, from dispatch until its logits
+    are on the host, feeds a log-bucketed per-kind histogram
+    (metrics.summary()["call_latency_ms"]: p50/p95/p99 without storing
+    raw samples).
 
 Durability (serving.journal + serving.snapshot) — crash-safe serving,
 PASSIVE like the tracer (``journal=None`` is bitwise/count-identical):
@@ -150,7 +156,6 @@ from repro.launch.steps import build_step
 from repro.models import init_cache, reset_slots
 from repro.obs import RecompileSentinel, Tracer
 from repro.runtime import sharding as shr
-from repro.runtime.fault import StragglerMonitor
 from repro.serving.faults import EngineCrash, FaultPlan, corrupt_cache
 from repro.serving.journal import Journal
 from repro.serving.metrics import MetricsRecorder
@@ -425,7 +430,6 @@ class ServeEngine:
         self.slot_log: List[SlotInterval] = []
         self._open_interval: Dict[int, SlotInterval] = {}
         self._has_deadlines = False
-        self.straggler = StragglerMonitor()
         self.metrics = MetricsRecorder()
 
     # ------------------------------------------------------------------ API
@@ -478,6 +482,8 @@ class ServeEngine:
         self.metrics.on_submit(request.rid, request.prompt_len,
                                request.gen_len, request.arrival,
                                deadline=request.deadline)
+        if self.tracer is not None:
+            self.tracer.event("submit", self.tick_count, rid=request.rid)
         if self.journal is not None:
             self.journal.append(
                 "submit", self.tick_count, rid=int(request.rid),
@@ -648,10 +654,10 @@ class ServeEngine:
     # ------------------------------------------------------------- one tick
 
     def tick(self):
-        t0 = time.monotonic()
         tick = self.tick_count
-        span = (self.tracer.begin("tick", tick)
-                if self.tracer is not None else None)
+        tr = self.tracer
+        span = tr.begin("tick", tick) if tr is not None else None
+        sched = tr.begin("schedule", tick) if tr is not None else None
         calls = 0
         if self.fault_plan is not None:
             self._inject_cache_faults(tick)
@@ -664,6 +670,8 @@ class ServeEngine:
             # by preempting the youngest-admitted slot
             self._page_growth(tick)
             self.page_alloc.check()
+        if sched is not None:
+            tr.end(sched)
         if self.prefill_mode == "chunked":
             calls += self._prefill_phase(tick)
         calls += self._decode_phase(tick)
@@ -678,14 +686,11 @@ class ServeEngine:
                              n_decoding=n_dec, device_calls=calls,
                              pages_used=pages_used,
                              pages_total=pages_total)
-        if span is not None:
-            attrs = dict(queue_depth=qd, n_prefilling=n_pre,
-                         n_decoding=n_dec, device_calls=calls)
-            if self.paged:
-                attrs.update(pages_used=pages_used,
-                             pages_total=pages_total)
-            self.tracer.end(span, **attrs)
         self.tick_count += 1
+        snapshot = bool(self.snapshot_every) and \
+            self.tick_count % self.snapshot_every == 0
+        commit = (tr.begin("commit", tick) if tr is not None and
+                  (self.journal is not None or snapshot) else None)
         if self.journal is not None:
             # ONE write + fsync for the whole tick's batch (admits,
             # tokens, terminal events) — durability costs one fsync per
@@ -693,13 +698,19 @@ class ServeEngine:
             # the current tick's uncommitted records, which restore
             # re-derives bitwise
             self.journal.commit()
-        if self.straggler.record(time.monotonic() - t0):
-            self.metrics.on_straggler(tick)
         if self.sentinel is not None:
             self.sentinel.check()
-        if self.snapshot_every and \
-                self.tick_count % self.snapshot_every == 0:
+        if snapshot:
             self.save_snapshot()
+        if commit is not None:
+            tr.end(commit)
+        if span is not None:
+            attrs = dict(queue_depth=qd, n_prefilling=n_pre,
+                         n_decoding=n_dec, device_calls=calls)
+            if self.paged:
+                attrs.update(pages_used=pages_used,
+                             pages_total=pages_total)
+            tr.end(span, **attrs)
 
     # -------------------------------------------------------------- phases
 
@@ -930,17 +941,18 @@ class ServeEngine:
                       if slot.state is SlotState.PREFILLING}
         if not prefilling:
             return 0
+        tr = self.tracer
+        replaying = any(self.slots[s].replay for s in prefilling)
+        restoring = any(self.slots[s].restore for s in prefilling)
+        span = (tr.begin("call", tick, phase="prefill",
+                         kind=self.prefill_kind, arch=self.cfg.name,
+                         participants=sorted(prefilling),
+                         occupancy=len(prefilling) / self.n_slots,
+                         replay=replaying, restore=restoring)
+                if tr is not None else None)
         cursors = {s: self.slots[s].cursor for s in prefilling}
         tokens, n_valid = assemble_chunk(prefilling, cursors, self.n_slots,
                                          self.prefill_chunk)
-        replaying = any(self.slots[s].replay for s in prefilling)
-        restoring = any(self.slots[s].restore for s in prefilling)
-        span = (self.tracer.begin(
-                    "call", tick, phase="prefill", kind=self.prefill_kind,
-                    arch=self.cfg.name, participants=sorted(prefilling),
-                    occupancy=len(prefilling) / self.n_slots,
-                    replay=replaying, restore=restoring)
-                if self.tracer is not None else None)
         c0 = time.monotonic()
         args = (self.params, self.stacked_tables, self.cache,
                 jnp.asarray(tokens), jnp.asarray(n_valid))
@@ -948,18 +960,21 @@ class ServeEngine:
             args = args + (self._ptab(),)
         res = self._device_call("prefill", self.prefill_kind,
                                 self._prefill, *args)
+        if res is not None:
+            logits, self.cache = res
+            lg = self._host_logits(logits, tick, "prefill")
         dur_s = time.monotonic() - c0
         if span is not None:
-            self.tracer.end(span, ok=res is not None)
+            tr.end(span, ok=res is not None, rows=tokens.size,
+                   rows_valid=int(n_valid.sum()))
         if res is None:                   # persistent step failure:
             for s in prefilling:          # quarantine every participant
                 self._quarantine(s, tick, "step_exception")
             return 0
-        logits, self.cache = res
         self.metrics.on_device_call("prefill", kind=self.prefill_kind,
                                     replay=replaying, restore=restoring,
                                     dur_s=dur_s)
-        lg = self._host_logits(logits, tick, "prefill")
+        sample = tr.begin("sample", tick) if tr is not None else None
         nxt = lg.argmax(axis=-1)
         for s in prefilling:
             if not np.isfinite(lg[s]).all():
@@ -978,6 +993,8 @@ class ServeEngine:
                 # first generated token — TTFT lands here
                 self._finish_prefill(s, int(nxt[s]),
                                      np.asarray(logits[s]), tick)
+        if sample is not None:
+            tr.end(sample)
         return 1
 
     def _decode_phase(self, tick: int) -> int:
@@ -993,30 +1010,32 @@ class ServeEngine:
                 active[s] = True
         if not active.any():
             return 0
-        span = (self.tracer.begin(
-                    "call", tick, phase="decode", kind="decode",
-                    arch=self.cfg.name,
-                    participants=[s for s in range(self.n_slots)
-                                  if active[s]],
-                    occupancy=float(active.mean()))
-                if self.tracer is not None else None)
+        tr = self.tracer
+        span = (tr.begin("call", tick, phase="decode", kind="decode",
+                         arch=self.cfg.name,
+                         participants=[s for s in range(self.n_slots)
+                                       if active[s]],
+                         occupancy=float(active.mean()))
+                if tr is not None else None)
         c0 = time.monotonic()
         args = (self.params, self.stacked_tables, self.cache,
                 jnp.asarray(tokens), jnp.asarray(active))
         if self.paged:
             args = args + (self._ptab(),)
         res = self._device_call("decode", "decode", self._decode, *args)
+        if res is not None:
+            logits, self.cache = res
+            lg = self._host_logits(logits, tick, "decode")
         dur_s = time.monotonic() - c0
         if span is not None:
-            self.tracer.end(span, ok=res is not None)
+            tr.end(span, ok=res is not None)
         if res is None:
             for s in range(self.n_slots):
                 if active[s]:
                     self._quarantine(s, tick, "step_exception")
             return 0
-        logits, self.cache = res
         self.metrics.on_device_call("decode", kind="decode", dur_s=dur_s)
-        lg = self._host_logits(logits, tick, "decode")
+        sample = tr.begin("sample", tick) if tr is not None else None
         nxt = lg.argmax(axis=-1)
         for s, slot in enumerate(self.slots):
             if not active[s]:
@@ -1045,6 +1064,8 @@ class ServeEngine:
                                     token=tok)
             if len(self.outputs[slot.rid]) >= slot.gen_len:
                 self._release(s, tick)
+        if sample is not None:
+            tr.end(sample)
         return 1
 
     # ----------------------------------------------- fault containment ----
@@ -1087,7 +1108,10 @@ class ServeEngine:
     def _host_logits(self, logits, tick: int, call: str) -> np.ndarray:
         """Host-side (B, V) f32 logits for argmax + the finite-guard;
         the fault plan's nan_logits events poison rows here (the
-        corruption a real device would hand back)."""
+        corruption a real device would hand back). Blocks until the
+        step is done: the "logits" span."""
+        span = (self.tracer.begin("logits", tick)
+                if self.tracer is not None else None)
         lg = np.asarray(logits[:, 0, :], np.float32)
         if self.fault_plan is not None:
             bad = self.fault_plan.logit_slots(tick, call)
@@ -1095,6 +1119,8 @@ class ServeEngine:
                 lg = lg.copy()
                 for s in bad:
                     lg[s] = np.nan
+        if span is not None:
+            self.tracer.end(span)
         return lg
 
     def _inject_cache_faults(self, tick: int):

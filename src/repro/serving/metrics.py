@@ -88,7 +88,6 @@ class MetricsRecorder:
         self.replays = 0                        # recovery-by-replay resets
         self.rejected = 0                       # refused at submit
         self.shed = 0                           # dropped after acceptance
-        self.straggler_ticks = 0                # wall-time outlier ticks
         # paging counters (paged engines; zero otherwise)
         self.preemptions = 0                    # page-pressure evictions
         self.alloc_failures = 0                 # unsatisfiable page asks
@@ -166,8 +165,8 @@ class MetricsRecorder:
         carries a slot re-prefilling after a warm restart (restore wins:
         restart traffic is the cost snapshot cadence trades against, so
         it must not hide inside the fault-replay bucket). ``dur_s``
-        (wall seconds around the device call) feeds the per-kind
-        log-bucketed latency histogram."""
+        (wall seconds from dispatch until the call's logits are on the
+        host) feeds the per-kind log-bucketed latency histogram."""
         if call == "decode":
             self.decode_calls += 1
         elif call == "prefill":
@@ -243,9 +242,6 @@ class MetricsRecorder:
         generously; climbing means preemption churn)."""
         self.alloc_failures += 1
 
-    def on_straggler(self, tick):
-        self.straggler_ticks += 1
-
     def record_slot_log(self, intervals: List[Tuple[int, int, Optional[int]]],
                         n_slots: int):
         """Install the engine's slot audit log — [(slot, admit_tick,
@@ -281,7 +277,6 @@ class MetricsRecorder:
             "replays": self.replays,
             "rejected": self.rejected,
             "shed": self.shed,
-            "straggler_ticks": self.straggler_ticks,
             "preemptions": self.preemptions,
             "alloc_failures": self.alloc_failures,
             "calls_by_kind": dict(self.calls_by_kind),
@@ -308,7 +303,6 @@ class MetricsRecorder:
         self.replays = int(d["replays"])
         self.rejected = int(d["rejected"])
         self.shed = int(d["shed"])
-        self.straggler_ticks = int(d["straggler_ticks"])
         # .get: pre-paging snapshots carry no paging counters
         self.preemptions = int(d.get("preemptions", 0))
         self.alloc_failures = int(d.get("alloc_failures", 0))
@@ -380,7 +374,6 @@ class MetricsRecorder:
             "retries": self.retries,
             "retries_by_kind": dict(self.retries_by_kind),
             "replays": self.replays,
-            "straggler_ticks": self.straggler_ticks,
             # paging block: preemption churn + page-pool occupancy over
             # the run (None when the engine is not paged)
             "n_preemptions": self.preemptions,

@@ -52,6 +52,26 @@ def tiny():
 
 
 @pytest.fixture(scope="module")
+def traced(tiny):
+    """One fault-free traced run shared by the host-phase span tests."""
+    cfg, params = tiny
+    tracer = Tracer(arch=cfg.name)
+    engine, outputs = _run(cfg, params, tracer=tracer)
+    return engine, tracer
+
+
+def _spans(tracer, name):
+    return [r for r in tracer.records
+            if r.get("type") == "span" and r["name"] == name]
+
+
+def _within(inner, outer) -> bool:
+    return (outer["ts_us"] <= inner["ts_us"] and
+            inner["ts_us"] + inner["dur_us"] <=
+            outer["ts_us"] + outer["dur_us"])
+
+
+@pytest.fixture(scope="module")
 def chaos_traced(tiny):
     """One seeded-fault traced run shared by the structural tests."""
     cfg, params = tiny
@@ -122,6 +142,160 @@ def test_trace_roundtrip_and_report(chaos_traced, tmp_path, capsys):
         assert section in out, f"report missing {section} section"
     ct = json.loads(chrome.read_text())
     assert any(e.get("ph") == "X" for e in ct["traceEvents"])
+
+
+# ------------------------------------------------ host-phase spans -------
+
+def test_host_phase_spans_nest_in_their_tick(traced):
+    """Every span other than "tick" lies inside its own tick's span,
+    and every "logits" inside a "call" of the same tick."""
+    engine, tracer = traced
+    ticks = {t["tick"]: t for t in _spans(tracer, "tick")}
+    names = {r["name"] for r in tracer.records if r.get("type") == "span"}
+    assert names == {"tick", "schedule", "call", "logits", "sample"}
+    assert len(_spans(tracer, "schedule")) == len(ticks)
+    for r in tracer.records[1:]:
+        if r.get("type") == "span" and r["name"] != "tick":
+            assert _within(r, ticks[r["tick"]]), r
+    calls = _spans(tracer, "call")
+    for lg in _spans(tracer, "logits"):
+        assert any(c["tick"] == lg["tick"] and _within(lg, c)
+                   for c in calls), lg
+
+
+def test_call_span_covers_its_logits_and_sample_follows(traced):
+    """One "logits" per call, inside it, so a call never times less
+    than its wait for the logits; one "sample" after each call."""
+    engine, tracer = traced
+    calls, logits = _spans(tracer, "call"), _spans(tracer, "logits")
+    samples = _spans(tracer, "sample")
+    assert len(calls) == len(logits) == len(samples) == \
+        engine.metrics.device_calls
+    for c, lg, sm in zip(calls, logits, samples):
+        assert c["dur_us"] >= lg["dur_us"]
+        assert sm["ts_us"] >= c["ts_us"] + c["dur_us"]
+
+
+def test_prefill_rows_valid_add_up_to_prompt_tokens(traced):
+    """The prefill calls' rows_valid count every prompt token served
+    once; each chunk computes n_slots x chunk rows."""
+    engine, tracer = traced
+    pre = [c["attrs"] for c in _spans(tracer, "call")
+           if c["attrs"]["phase"] == "prefill"]
+    assert pre and all(a["rows"] == N_SLOTS * CHUNK for a in pre)
+    assert sum(a["rows_valid"] for a in pre) == \
+        sum(len(r.prompt) for r in _requests())
+    decode = [c["attrs"] for c in _spans(tracer, "call")
+              if c["attrs"]["phase"] == "decode"]
+    assert decode and not any("rows" in a for a in decode)
+
+
+def test_call_latency_includes_the_wait_for_logits(traced):
+    """The recorder's call latency runs from dispatch until the logits
+    are on the host: per call kind it sums to at least the logits spans
+    and at most the call spans."""
+    engine, tracer = traced
+    calls, logits = _spans(tracer, "call"), _spans(tracer, "logits")
+    for tag, hist in engine.metrics.call_latency.items():
+        mine = [i for i, c in enumerate(calls) if c["attrs"]["kind"] == tag]
+        assert len(mine) == hist.count
+        lo = sum(logits[i]["dur_us"] for i in mine) * 1e-6
+        hi = sum(calls[i]["dur_us"] for i in mine) * 1e-6
+        assert lo <= hist.total <= hi, (tag, lo, hist.total, hi)
+
+
+def test_submit_admit_first_token_pair_per_request(traced):
+    """Each request's submit, admit and first_token events carry its
+    rid, in that order on the wall clock."""
+    engine, tracer = traced
+    at = {}
+    for r in tracer.records:
+        if r.get("type") == "event" and \
+                r["name"] in ("submit", "admit", "first_token"):
+            at.setdefault(r["attrs"]["rid"], {})[r["name"]] = r["ts_us"]
+    assert sorted(at) == [r.rid for r in _requests()]
+    for rid, t in at.items():
+        assert t["submit"] <= t["admit"] <= t["first_token"], rid
+
+
+def test_commit_span_covers_journal_and_snapshot(tiny, tmp_path):
+    """With a journal and snapshots, each tick ends in a "commit" span
+    inside it, and the trace still validates."""
+    cfg, params = tiny
+    tracer = Tracer(arch=cfg.name)
+    engine = ServeEngine(cfg, params, n_slots=N_SLOTS, max_len=MAX_LEN,
+                         prefill_chunk=CHUNK, tracer=tracer,
+                         journal=str(tmp_path / "j.jsonl"),
+                         snapshot_dir=str(tmp_path / "snaps"),
+                         snapshot_every=3)
+    engine.run(_requests(3))
+    validate(tracer.records)
+    ticks = _spans(tracer, "tick")
+    commits = _spans(tracer, "commit")
+    assert len(commits) == len(ticks)
+    names = [r["name"] for r in tracer.records if r.get("type") == "event"]
+    assert names.count("snapshot") == len(ticks) // 3
+
+
+def test_report_self_times_add_up_to_the_tick(traced):
+    """The report's host-phase self times split each tick exactly: the
+    names' self times of one tick add up to its span's duration."""
+    from repro.launch.report import render, self_times
+    engine, tracer = traced
+    per_tick = self_times([r for r in tracer.records
+                           if r.get("type") == "span"])
+    for t in _spans(tracer, "tick"):
+        got = per_tick[t["tick"]]
+        assert sum(got.values()) == pytest.approx(t["dur_us"], abs=1e-6)
+        assert all(v >= -1e-6 for v in got.values()), got
+    out = render(tracer.records)
+    for label in ("schedule", "call (dispatch)", "logits", "sample",
+                  "tick (rest)"):
+        assert label in out
+
+
+def test_engine_spans_reach_the_profiler_trace(tiny, tmp_path):
+    """Under jax.profiler a traced engine leaves its spans as
+    ``engine.*`` host events in the .xplane.pb, one per record; an
+    engine with no tracer leaves none."""
+    import sys
+    from pathlib import Path
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from jax.profiler import ProfileData
+    from bench.host_phases import engine_spans
+
+    cfg, params = tiny
+
+    def profiled(tracer, logdir):
+        with jax.profiler.trace(str(logdir)):
+            _run(cfg, params, tracer=tracer)
+        files = list(Path(logdir).rglob("*.xplane.pb"))
+        assert len(files) == 1
+        return engine_spans(ProfileData.from_file(str(files[0])))
+
+    tracer = Tracer(arch=cfg.name)
+    got = profiled(tracer, tmp_path / "on")
+    recorded = [r["name"] for r in tracer.records if r.get("type") == "span"]
+    names = [s.name for s in got]
+    for name in ("tick", "schedule", "call", "logits", "sample"):
+        assert names.count(f"engine.{name}") == recorded.count(name), name
+    assert profiled(None, tmp_path / "off") == []
+
+
+def test_validate_rejects_a_span_outside_its_tick():
+    tr = Tracer()
+    t = tr.begin("tick", 0)
+    tr.end(t)
+    s = tr.begin("sample", 0)
+    tr.end(s)
+    with pytest.raises(TraceError, match="sample span"):
+        validate(tr.records)
+    tr2 = Tracer()
+    tr2.event("preempt", 0, rid=1, slot=0)
+    tr2.event("submit", 0, rid=1)
+    assert validate(tr2.records)["events"] == 2
 
 
 def test_span_nesting_is_lifo_enforced():

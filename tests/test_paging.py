@@ -154,6 +154,23 @@ def test_tight_pool_preempts_and_resumes_bitwise(served):
     assert engine.page_alloc.free_pages == TIGHT_PAGES  # all released
 
 
+def test_traced_tight_pool_preempts_and_validates(served):
+    """A traced engine over the oversubscribed pool records its
+    preemptions as "preempt" events, and its records pass
+    obs.trace.validate; the streams stay bitwise."""
+    from repro.obs import Tracer, validate
+    cfg, params, trace, ref_out = served
+    tracer = Tracer(arch=cfg.name)
+    engine = ServeEngine(cfg, params, paged=True, page_size=PAGE_SIZE,
+                         n_pages=TIGHT_PAGES, tracer=tracer, **ENGINE_KW)
+    out = engine.run(trace)
+    assert out == ref_out
+    validate(tracer.records)
+    preempts = [r for r in tracer.records
+                if r.get("type") == "event" and r["name"] == "preempt"]
+    assert len(preempts) == engine.metrics.summary()["n_preemptions"] >= 1
+
+
 def test_oversized_judged_against_paged_capacity(served):
     """A request whose total exceeds the POOL (even though it fits the
     per-slot cap) must be rejected at submit — admitting it would make

@@ -136,3 +136,17 @@ def test_device_call_latency_histogram_by_kind():
     assert lat["decode"]["count"] == 8
     assert abs(lat["decode"]["p50_ms"] - 10.0) / 10.0 < 0.10
     assert s["calls_by_kind"]["prefill_parallel+replay"] == 1
+
+
+def test_state_from_before_the_straggler_count_loads():
+    """A snapshot's metrics state written while the recorder still kept
+    a straggler count loads unchanged, and the count is gone from the
+    summary."""
+    m = MetricsRecorder()
+    _submit(m, 0)
+    m.on_admit(0, tick=1, skips=0)
+    old = dict(m.state_dict(), straggler_ticks=4)
+    back = MetricsRecorder()
+    back.load_state_dict(old)
+    assert back.state_dict() == m.state_dict()
+    assert "straggler_ticks" not in back.summary()
