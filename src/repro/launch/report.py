@@ -8,7 +8,8 @@ Sections (each reads one record type of the obs.trace taxonomy):
 
   * TIMELINE   — per-call-kind span latency (count, total, p50/p95 from
     the recorded dur_us; a "call" runs from input assembly until its
-    logits are on the host) plus engine-tick stats, and each host
+    logits are on the host), the K/V rows each decode step and prefill
+    chunk wrote, engine-tick stats, and each host
     phase's self time per tick: schedule, call (input assembly and
     dispatch), logits (the wait for the step and the copy), sample,
     commit, and the rest of the tick;
@@ -129,6 +130,19 @@ def render(records: List[dict], width: int = 64) -> str:
                      f"p50={_percentile(durs, 0.5):.2f} "
                      f"p95={_percentile(durs, 0.95):.2f} ms  "
                      f"total={sum(durs):.1f} ms{occ_s}")
+    written = [c["attrs"]["slots_written"] for c in calls
+               if "slots_written" in c["attrs"]]
+    if written:
+        of = f" of {meta['n_slots']}" if meta.get("n_slots") else ""
+        lines.append(f"  K/V rows written per decode step: "
+                     f"{sum(written) / len(written):.2f}{of} slots, one "
+                     f"position each (a whole-cache rewrite is every "
+                     f"slot's every position)")
+    chunks = [c["attrs"] for c in calls if "rows_valid" in c["attrs"]]
+    if chunks:
+        lines.append(f"  K/V rows written per prefill chunk: "
+                     f"{sum(a['rows_valid'] for a in chunks) / len(chunks):.1f}"
+                     f" of {chunks[0]['rows']}")
     if ticks:
         per_tick = self_times(spans)
         wall = max(sum(t["dur_us"] for t in ticks), 1e-9)

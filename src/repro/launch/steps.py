@@ -156,9 +156,10 @@ def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
       * "decode" — the serving engine's slot decode step,
         ``(params, tables, cache, token, active)``: inactive slots (free,
         draining, or mid-prefill while their neighbors decode) compute
-        alongside the batch but their cache writes and position advances
-        are discarded (models.decode.merge_slots) — continuous batching
-        with ZERO per-request recompilation. Positions come from
+        alongside the batch but their K/V writes are dropped in-step
+        (decode_step's write_mask) and their position and SSM state
+        advances discarded (models.decode.merge_slots) — continuous
+        batching with ZERO per-request recompilation. Positions come from
         cache["pos"], a (B,) vector of per-slot depths. Tag "decode".
       * "prefill_chunk" — chunked cache-filling prefill,
         ``(params, tables, cache, tokens, n_valid)``: C prompt tokens per
@@ -180,10 +181,10 @@ def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
     int32 — the host allocator's page table — through which every KV
     gather/scatter resolves in-graph. The table is a fixed-shape
     per-call operand (never cache-resident), so page churn between ticks
-    costs ZERO recompiles. The "decode" step routes ``active`` into the
-    attention write mask (pooled leaves have no batch dim for
-    merge_slots to select on — inactive slots' writes are dropped at the
-    scatter). "serve" (lock-step, no allocator) stays contiguous.
+    costs ZERO recompiles. As in the contiguous "decode" step,
+    ``active`` is the attention write mask: inactive slots' writes are
+    dropped at the scatter. "serve" (lock-step, no allocator) stays
+    contiguous.
     """
     if call_kind not in SERVE_CALL_KINDS:
         raise ValueError(f"call_kind {call_kind!r} not in "
@@ -233,7 +234,8 @@ def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
     elif call_kind == "decode":
         def step_fn(params, tables, cache, token, active):
             logits, new_cache = decode_step(params, cache, token, cfg,
-                                            tables=tables)
+                                            tables=tables,
+                                            write_mask=active)
             return logits, merge_slots(new_cache, cache, active, cfg)
         step_fn.call_kind = "decode"
 

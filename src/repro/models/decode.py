@@ -2,12 +2,13 @@
 family (dense/MoE/VLM, SSM, hybrid, enc-dec).
 
 The decoder is a list of per-kind segments (models.segments): decode
-walks it, scanning each segment's stacked layer params with that
-segment's cache slices (and packed-table slices) as scan xs — the HLO
-stays O(segments) in depth, and every composition of attention / SSM /
-MoE / cross-attention sublayers flows through the same four bodies.
-Caches are static-shape; SWA archs allocate only the window (ring
-buffer).
+walks it, scanning each segment's stacked layer params (and packed-table
+slices) as scan xs — the HLO stays O(segments) in depth, and every
+composition of attention / SSM / MoE / cross-attention sublayers flows
+through the same four bodies. A contiguous K/V stack rides the scan as
+carry and each layer writes its own rows into it in place; paged pools
+and SSM states ride as per-layer xs/ys. Caches are static-shape; SWA
+archs allocate only the window (ring buffer).
 """
 
 from __future__ import annotations
@@ -83,6 +84,43 @@ def _sinusoidal_at(positions, d: int):
     return pe.at[..., 0::2].set(jnp.sin(ang)).at[..., 1::2].set(jnp.cos(ang))
 
 
+def _page_table(c, ptab):
+    """The page table an attention segment's cache needs: ptab for a
+    paged pool ({"pk","pv"}), None for contiguous k/v."""
+    if "pk" not in c:
+        return None
+    if ptab is None:
+        raise ValueError("paged cache requires a page table (ptab) operand")
+    return ptab
+
+
+def _attn_scan(layer, x, p_stack, c, txs, mk):
+    """Scan an attention segment's layers: ``layer(h, p, ck, cv, mm, li)``
+    -> (h, ck, cv). Returns (x, the segment's new k/v leaves).
+
+    Contiguous k/v ride the scan as CARRY: every layer gets the whole
+    stacked buffer and its index li, writes its rows in place and reads
+    its own slice — the stack is never sliced out, re-laid out or
+    restacked, so a donated cache is updated where it lies. A paged pool
+    rides as xs/ys, one layer's pool per step (li is None)."""
+    if "pk" in c:
+        def step(h, inp):
+            p, ck, cv, slices = inp
+            h, ck, cv = layer(h, p, ck, cv, mk(slices), None)
+            return h, (ck, cv)
+        x, (ks, vs) = jax.lax.scan(step, x, (p_stack, c["pk"], c["pv"], txs))
+        return x, {"pk": ks, "pv": vs}
+
+    def step(carry, inp):
+        h, ck, cv = carry
+        li, p, slices = inp
+        return layer(h, p, ck, cv, mk(slices), li), None
+    n = c["k"].shape[0]
+    (x, ks, vs), _ = jax.lax.scan(step, (x, c["k"], c["v"]),
+                                  (jnp.arange(n), p_stack, txs))
+    return x, {"k": ks, "v": vs}
+
+
 # ---------------------------------------------------------------------------
 # Decode step
 # ---------------------------------------------------------------------------
@@ -99,13 +137,15 @@ def decode_step(params, cache, token, cfg: ModelConfig, tables=None,
     slice; enc-dec: cross-attention packs next to self-attention; hybrid
     segments pack independently). None keeps the plain matmuls.
 
-    ptab (B, max_pages) int32 + write_mask (B,) bool switch attention
-    segments to the PAGED cache (pooled {"pk","pv"} leaves): KV writes
-    route through the page table and inactive slots' writes are dropped
-    in-step (merge_slots cannot per-slot-select a pooled leaf — the
-    write_mask replaces it for pools, while SSM/"pos" leaves still merge
-    the old way). The table rides every segment's scan as a broadcast
-    operand: one global page-id space across segments.
+    write_mask (B,) bool drops the K/V writes of slots where it is False,
+    in-step: their attention cache rows stay bitwise as they were, so
+    merge_slots has no K/V to select (SSM and "pos" leaves still merge
+    there). None writes every slot.
+
+    ptab (B, max_pages) int32 switches attention segments to the PAGED
+    cache (pooled {"pk","pv"} leaves): KV writes route through the page
+    table. The table rides every segment's scan as a broadcast operand:
+    one global page-id space across segments.
     """
     segs = decoder_layout(cfg)
     seg_tables = segment_tables(tables, segs, cfg)
@@ -125,24 +165,14 @@ def decode_step(params, cache, token, cfg: ModelConfig, tables=None,
               st.dense_fn(slices) if st is not None else None)
         c = cache[seg.cache]
         if seg.mixer == "attn":
-            paged = "pk" in c
-            if paged and ptab is None:
-                raise ValueError("paged cache requires a page table "
-                                 "(ptab) operand")
-            def step(h, inp, seg=seg, mk=mk, paged=paged):
-                p, ck, cv, slices = inp
-                mm = mk(slices)
+            def layer(h, p, ck, cv, mm, li, seg=seg, pt=_page_table(c, ptab)):
                 hn = apply_norm(p["norm1"], h, cfg)
                 y, ck, cv = attn_mod.decode_attention(
-                    p["attn"], hn, ck, cv, pos, cfg, dense_fn=mm,
-                    ptab=ptab if paged else None,
-                    write_mask=write_mask if paged else None)
+                    p["attn"], hn, ck, cv, pos, cfg, dense_fn=mm, ptab=pt,
+                    write_mask=write_mask, layer=li)
                 h = _block_tail(seg, p, h + y, cfg, mm, enc_out)
-                return h, (ck, cv)
-            kk, vk = ("pk", "pv") if paged else ("k", "v")
-            x, (cks, cvs) = jax.lax.scan(
-                step, x, (params[seg.name], c[kk], c[vk], txs))
-            nc = {kk: cks, vk: cvs}
+                return h, ck, cv
+            x, nc = _attn_scan(layer, x, params[seg.name], c, txs, mk)
             if "pos" in c:
                 nc["pos"] = pos + 1
             new_cache[seg.cache] = nc
@@ -171,8 +201,8 @@ def decode_chunk(params, cache, tokens, n_valid, cfg: ModelConfig,
     ptab (B, max_pages) int32 switches attention segments to the PAGED
     cache ({"pk","pv"} pool leaves) — chunk writes scatter through the
     page table with the same drop-sentinel idiom as the contiguous path
-    (idle slots' n_valid = 0 already gates their writes, so no separate
-    write mask is needed here).
+    (idle slots' n_valid = 0 already gates their writes in place, so no
+    separate write mask is needed here).
 
     tokens (B, C) int32; n_valid (B,) int32 in [0, C] — the number of real
     prompt tokens per slot this chunk (ragged tail chunks and idle slots
@@ -232,24 +262,15 @@ def decode_chunk(params, cache, tokens, n_valid, cfg: ModelConfig,
               st.dense_fn(slices) if st is not None else None)
         c = cache[seg.cache]
         if seg.mixer == "attn":
-            paged = "pk" in c
-            if paged and ptab is None:
-                raise ValueError("paged cache requires a page table "
-                                 "(ptab) operand")
-            def step(h, inp, seg=seg, mk=mk, paged=paged):
-                p, ck, cv, slices = inp
-                mm = mk(slices)
+            def layer(h, p, ck, cv, mm, li, seg=seg, pt=_page_table(c, ptab)):
                 hn = apply_norm(p["norm1"], h, cfg)
                 y, ck, cv = attn_mod.prefill_attention(
                     p["attn"], hn, ck, cv, pos, n_valid, cfg, dense_fn=mm,
-                    ptab=ptab if paged else None)
+                    ptab=pt, layer=li)
                 h = _block_tail(seg, p, h + y, cfg, mm, enc_out,
                                 per_position=True)
-                return h, (ck, cv)
-            kk, vk = ("pk", "pv") if paged else ("k", "v")
-            x, (cks, cvs) = jax.lax.scan(
-                step, x, (params[seg.name], c[kk], c[vk], txs))
-            nc = {kk: cks, vk: cvs}
+                return h, ck, cv
+            x, nc = _attn_scan(layer, x, params[seg.name], c, txs, mk)
             if "pos" in c:
                 nc["pos"] = pos + n_valid
             new_cache[seg.cache] = nc
@@ -291,39 +312,48 @@ def merge_slots(new_cache, old_cache, keep_mask, cfg: ModelConfig):
     This is what lets ONE fixed-shape decode step serve a batch where
     only some slots are actively decoding (others are mid-prefill, free,
     or draining): the step computes updates for every slot, and the merge
-    discards the writes of inactive ones. Positions come out as (B,)
-    vectors regardless of input shape. Encoder output (enc-dec) is shared
-    across the batch and passes through unchanged.
+    discards the updates of inactive ones. Positions come out as (B,)
+    vectors regardless of input shape.
 
-    The walk is layout-generic: every cache leaf carries the batch on
-    axis 1 ((L_seg, B, ...) for k/v, conv, and state alike — the
-    segmented layout), "pos" leaves select per-slot scalars, "enc_out"
-    passes through. No family switches.
-
-    Paged pool leaves ("pk"/"pv": (L_seg, n_pages, page_size, Hkv, hd))
-    have NO batch axis to select on — they pass through updated. Their
-    per-slot write gating happened IN-STEP (decode_attention's
-    write_mask / prefill's n_valid sentinel-drop), so an inactive slot's
-    pages were never touched in the first place."""
+    Only "pos" and the SSM conv/state leaves ((L_seg, B, ...), batch on
+    axis 1) are selected. Attention K/V — contiguous "k"/"v" and paged
+    "pk"/"pv" alike — pass through updated: the step gated their writes
+    per slot in place (decode_attention's write_mask, prefill's n_valid),
+    so an inactive slot's rows were never touched, and a select over the
+    whole cache would only copy it. Encoder output (enc-dec) is shared
+    across the batch and passes through unchanged."""
     B = keep_mask.shape[0]
-
-    def sel_pos(new, old):
-        return jnp.where(keep_mask, attn_mod._per_slot_pos(new, B),
-                         attn_mod._per_slot_pos(old, B))
 
     def visit(path, new, old):
         key = str(getattr(path[-1], "key", path[-1]))
         if key == "pos":
-            return sel_pos(new, old)
-        if key in ("enc_out", "pk", "pv"):
-            return new
-        return _select_batch(keep_mask, new, old, axis=1)
+            return jnp.where(keep_mask, attn_mod._per_slot_pos(new, B),
+                             attn_mod._per_slot_pos(old, B))
+        if key in ("conv", "state"):
+            return _select_batch(keep_mask, new, old, axis=1)
+        return new
 
     return jax.tree_util.tree_map_with_path(visit, new_cache, old_cache)
 
 
+def _zero_slots(leaf, slot_mask):
+    """Zero leaf[:, b] (batch on axis 1) for each b where slot_mask is
+    set: a dynamic-update-slice of zeros per masked slot, skipped for
+    the others, which XLA performs in place on a donated buffer."""
+    zero = jnp.zeros((leaf.shape[0], 1) + leaf.shape[2:], leaf.dtype)
+
+    def body(b, x):
+        start = (0, b) + (0,) * (x.ndim - 2)
+        return jax.lax.cond(
+            slot_mask[b],
+            lambda x: jax.lax.dynamic_update_slice(x, zero, start),
+            lambda x: x, x)
+
+    return jax.lax.fori_loop(0, slot_mask.shape[0], body, leaf)
+
+
 def reset_slots(cache, slot_mask, cfg: ModelConfig, ptab=None):
-    """Zero the KV/SSM cache slices and position of the slots where
+    """Zero the KV/SSM cache rows and position of the slots where
     slot_mask (B,) is True — the admission step before a freed slot takes
     a new request. Without this, a refilled slot's attention would still
     mask correctly (pos restarts at 0) but SSM states and ring buffers
@@ -331,33 +361,34 @@ def reset_slots(cache, slot_mask, cfg: ModelConfig, ptab=None):
     output (enc-dec) is shared and not per-request; callers that rotate
     enc-dec requests must swap it themselves.
 
+    Contiguous k/v are zeroed in place, one slot at a time and only
+    where the mask is set (_zero_slots), so an admission writes its own
+    slots and never reads or selects the whole cache.
     Paged caches additionally take ``ptab`` (B, max_pages): the reset is
     PAGE-TABLE SURGERY — only the pages the masked slots' table rows
     point at are zeroed (fixed-shape scatter; -1 rows route to the drop
     sentinel), so admitting one request never touches another slot's
-    pages. SSM/"pos" leaves are per-slot and reset the contiguous way."""
-    zeroed = {}
-    for key, val in cache.items():
-        if key == "enc_out":
-            zeroed[key] = val
-        else:
-            zeroed[key] = jax.tree_util.tree_map(jnp.zeros_like, val)
-    out = merge_slots(cache, zeroed, ~slot_mask, cfg)
-    if ptab is None:
-        return out
-
-    sel = slot_mask[:, None] & (ptab >= 0)                   # (B, MP)
+    pages. SSM/"pos" leaves are per-slot and reset by select."""
+    B = slot_mask.shape[0]
+    if ptab is not None:
+        sel = slot_mask[:, None] & (ptab >= 0)               # (B, MP)
 
     def visit(path, leaf):
         key = str(getattr(path[-1], "key", path[-1]))
-        if key not in ("pk", "pv"):
-            return leaf
-        np_ = leaf.shape[1]
-        pids = jnp.where(sel, ptab, np_).reshape(-1)         # (B*MP,)
-        return leaf.at[:, pids].set(jnp.zeros((), leaf.dtype),
-                                    mode="drop")
+        if key == "pos":
+            return jnp.where(slot_mask, 0, attn_mod._per_slot_pos(leaf, B))
+        if key in ("conv", "state"):
+            return _select_batch(slot_mask, jnp.zeros_like(leaf), leaf,
+                                 axis=1)
+        if key in ("k", "v"):
+            return _zero_slots(leaf, slot_mask)
+        if key in ("pk", "pv") and ptab is not None:
+            pids = jnp.where(sel, ptab, leaf.shape[1]).reshape(-1)
+            return leaf.at[:, pids].set(jnp.zeros((), leaf.dtype),
+                                        mode="drop")
+        return leaf
 
-    return jax.tree_util.tree_map_with_path(visit, out)
+    return jax.tree_util.tree_map_with_path(visit, cache)
 
 
 def prefill(params, tokens, cfg: ModelConfig,

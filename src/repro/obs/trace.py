@@ -51,7 +51,8 @@ The engine's spans split one tick by host phase:
             its slot-reset dispatch) and, when paged, page growth
   call      one device call, from input assembly to its logits on the
             host; prefill calls carry ``rows`` (slots x chunk) and
-            ``rows_valid`` (prompt tokens in the chunk)
+            ``rows_valid`` (prompt tokens in the chunk), decode calls
+            ``slots_written`` (slots whose K/V rows the step wrote)
   logits    inside "call": the wait for the step and the device-to-host
             copy of the last position's logits
   sample    after each call: argmax, the finite guard and the per-slot

@@ -216,7 +216,8 @@ def batch_specs(batch_tree, mesh: Mesh):
 def cache_specs(cache_tree, cfg, mesh: Mesh):
     """KV/SSM cache sharding for decode.
 
-    Layout reminders: attn k/v (L, B, A, Hkv, hd); ssm conv
+    Layout reminders: attn k/v (L, B, Hkv // G, A, G * hd) with G KV
+    heads per lane row (models.attention.kv_group); ssm conv
     (L, B, W-1, C), ssm state (L, B, H, Pd, N) — uniform across segments
     (hybrid segments use the same per-segment layouts).
     Batch shards over DP when divisible; otherwise (long_500k, B=1) the
@@ -234,14 +235,14 @@ def cache_specs(cache_tree, cfg, mesh: Mesh):
         if not shape or leaf.ndim <= 1:
             return P()
         if pathstr.endswith("/k") or pathstr.endswith("/v"):
-            L, B, A, H, hd = shape
+            L, B, H, A, W = shape
             spec = [None, None, None, None, None]
             if B % dpn == 0:
                 spec[1] = dpa
             elif A % mesh.shape.get("data", 1) == 0:
-                spec[2] = "data"
+                spec[3] = "data"
             if H % mesh.shape.get("model", 1) == 0:
-                spec[3] = "model"
+                spec[2] = "model"
             # NOTE: when kv-heads < model axis the cache REPLICATES over
             # `model`. Sharding the seq dim instead was tried and REFUTED:
             # the dynamic-index cache update scatter cannot be partitioned

@@ -11,8 +11,9 @@ executables (three with slot reset), fixed-shape so NO recompilation ever
 happens per request:
 
   * decode step   (B, 1) tokens + (B,) active mask
-    (launch.steps.build_step("decode") — inactive slots' cache writes
-    are discarded by models.decode.merge_slots);
+    (launch.steps.build_step("decode") — inactive slots' K/V writes are
+    dropped in the step, their position and SSM state updates discarded
+    by models.decode.merge_slots);
   * prefill chunk (B, C) tokens + (B,) n_valid
     (serving.prefill.build_chunk_step — only in "chunked" mode);
   * slot reset — zeroes a freed slot's KV/SSM cache slices and position
@@ -94,7 +95,8 @@ traced run (the zero-overhead contract the chaos bench guards):
     by host phase: "tick", and inside it "schedule" (fault injection,
     shedding, admission, page growth), one "call" per device call
     (call_kind/arch/occupancy/replay attrs, plus rows/rows_valid on
-    prefill chunks) running from input assembly until the logits are
+    prefill chunks and slots_written on decode steps) running from
+    input assembly until the logits are
     on the host, its child "logits" (the wait for the step and the
     device-to-host copy), one "sample" per call (argmax, finite guard,
     slot updates), and "commit" (journal commit, snapshot). It also
@@ -1028,7 +1030,9 @@ class ServeEngine:
             lg = self._host_logits(logits, tick, "decode")
         dur_s = time.monotonic() - c0
         if span is not None:
-            tr.end(span, ok=res is not None)
+            # the step writes K/V rows for its active slots only
+            tr.end(span, ok=res is not None,
+                   slots_written=int(active.sum()) if res is not None else 0)
         if res is None:
             for s in range(self.n_slots):
                 if active[s]:

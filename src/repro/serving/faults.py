@@ -44,9 +44,8 @@ granularity):
     and keeping it out of the sampler keeps every existing seeded
     chaos schedule bit-identical.
 
-Poisoning uses the same layout-generic slot surgery as admission
-zeroing (models.decode.merge_slots): float leaves carry the batch on
-axis 1, ``pos`` stays valid (a corrupted cache with a trashed position
+Poisoning is layout-generic slot surgery (``corrupt_cache``): float
+leaves carry the batch on axis 1, ``pos`` stays valid (a corrupted cache with a trashed position
 would be a *different* fault), ``enc_out`` is shared and passes
 through.
 """
@@ -193,25 +192,22 @@ def corrupt_logits(logits: np.ndarray, slots: List[int]) -> np.ndarray:
 def corrupt_cache(cache, slots: List[int], n_slots: int, cfg):
     """NaN-poison every inexact cache leaf's slices for ``slots``.
 
-    Mirrors models.decode.reset_slots: merge_slots does the per-slot
-    select with the batch on axis 1, ``pos`` and integer leaves stay
-    intact (position corruption would be a different fault class), and
-    ``enc_out`` is shared, not per-slot state."""
-    from repro.models import merge_slots
-
+    Per-slot leaves (k/v, conv, state) carry the batch on axis 1; ``pos``
+    and integer leaves stay intact (position corruption would be a
+    different fault class), and ``enc_out`` is shared, not per-slot
+    state. A paged pool has no slot axis and is poisoned whole."""
     mask = np.zeros((n_slots,), bool)
     for s in slots:
         mask[s] = True
 
-    def poison(leaf):
-        if not jnp.issubdtype(leaf.dtype, jnp.inexact):
+    def poison(path, leaf):
+        key = str(getattr(path[-1], "key", path[-1]))
+        if key == "enc_out" or not jnp.issubdtype(leaf.dtype, jnp.inexact):
             return leaf
-        return jnp.full_like(leaf, jnp.nan)
+        bad = jnp.full_like(leaf, jnp.nan)
+        if key in ("pk", "pv"):
+            return bad
+        sel = mask.reshape((1, n_slots) + (1,) * (leaf.ndim - 2))
+        return jnp.where(sel, bad, leaf)
 
-    poisoned = {}
-    for key, val in cache.items():
-        if key in ("enc_out", "pos"):
-            poisoned[key] = val
-        else:
-            poisoned[key] = jax.tree_util.tree_map(poison, val)
-    return merge_slots(poisoned, cache, jnp.asarray(mask), cfg)
+    return jax.tree_util.tree_map_with_path(poison, cache)

@@ -1,6 +1,7 @@
 """Serving engine: chunked cache-filling prefill (bit-identical to
-stepwise decode), slot scheduler invariants under randomized traces,
-stale-cache zeroing on slot refill, and the thin serve CLI."""
+stepwise decode), in-place per-slot cache writes and resets, slot
+scheduler invariants under randomized traces, stale-cache zeroing on
+slot refill, and the thin serve CLI."""
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +9,9 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.models import decode_chunk, init_cache, init_params
+from repro.launch.steps import build_step
+from repro.models import decode_chunk, init_cache, init_params, reset_slots
+from repro.obs import Tracer
 from repro.serving import (Request, ServeEngine, WorkloadSpec, assemble_chunk,
                            make_trace)
 from repro.sparsity.sparse_linear import build_stacked_tables
@@ -93,6 +96,24 @@ def test_chunk_with_zero_valid_leaves_cache_untouched(arch):
         a, b = np.asarray(sub[key]), np.asarray(sub2[key])
         if a.ndim >= 2:
             np.testing.assert_array_equal(a[:, 1], b[:, 1])
+
+
+def test_chunk_window_clamped_at_cache_end_bit_identical():
+    """A chunk that would run past the cache's last position is written
+    as a window shifted back to end there; the rows it shifts over keep
+    the tokens already cached. Prompt 9 in a 10-position cache, chunk 4:
+    the last chunk (1 token at position 8) writes inside the window
+    6..9."""
+    cfg = _cfg("tinyllama-1.1b")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    prompts = np.random.default_rng(11).integers(
+        1, cfg.vocab_size, (2, 9)).astype(np.int32)
+    ls, cs = _stepwise(params, cfg, prompts, 10)
+    lc, cc = _chunked(params, cfg, prompts, 10, chunk=4)
+    np.testing.assert_array_equal(np.asarray(ls), np.asarray(lc))
+    for a, b in zip(jax.tree_util.tree_leaves(cs),
+                    jax.tree_util.tree_leaves(cc)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_chunked_prefill_rejects_unsupported_families():
@@ -354,3 +375,99 @@ def test_serve_cli_exits_nonzero_on_lost_requests(monkeypatch, tmp_path,
     else:
         with pytest.raises(SystemExit, match="requests not served"):
             main(argv)
+
+
+# ------------------------------------------- in-place slot cache surgery --
+
+def _filled(cfg, params, n_slots, max_len, steps, seed):
+    """A cache after ``steps`` decode steps of random tokens in every
+    slot, each slot at its own depth (slot b skips its first b steps)."""
+    step, _ = build_step(cfg, None, "decode")
+    step = jax.jit(step)
+    cache = init_cache(cfg, n_slots, max_len)
+    cache["pos"] = jnp.zeros((n_slots,), jnp.int32)
+    if "pos" in cache.get("attn", {}):
+        cache["attn"]["pos"] = jnp.zeros((n_slots,), jnp.int32)
+    rng = np.random.default_rng(seed)
+    for t in range(steps):
+        tok = rng.integers(1, cfg.vocab_size, (n_slots, 1)).astype(np.int32)
+        active = np.arange(n_slots) <= t
+        _, cache = step(params, None, cache, jnp.asarray(tok),
+                        jnp.asarray(active))
+    return step, cache
+
+
+@pytest.mark.parametrize("window", [0, 8])       # 8: a ring that wraps
+def test_decode_step_writes_only_active_slots_rows(window):
+    """A decode step writes one position of each ACTIVE slot in place:
+    an inactive slot's K/V rows and position come out bitwise unchanged,
+    and an active slot changes at its write position only (pos, or
+    pos % alloc on a sliding-window ring)."""
+    cfg = _cfg("tinyllama-1.1b", window=window)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    step, cache = _filled(cfg, params, 3, 16, 11, seed=6)
+    before = jax.tree_util.tree_map(np.asarray, cache)
+    active = np.array([True, False, True])
+    tok = jnp.asarray([[5], [6], [7]], jnp.int32)
+    _, after = step(params, None, cache, tok, jnp.asarray(active))
+    alloc = before["attn"]["k"].shape[3]
+    for b in range(3):
+        p = int(before["pos"][b])
+        assert int(after["pos"][b]) == p + int(active[b])
+        for key in ("k", "v"):
+            old = before["attn"][key][:, b]
+            new = np.asarray(after["attn"][key][:, b])
+            if not active[b]:
+                np.testing.assert_array_equal(new, old)
+                continue
+            at = p % alloc if window else p
+            rest = np.arange(alloc) != at
+            np.testing.assert_array_equal(new[:, :, rest], old[:, :, rest])
+            assert not np.array_equal(new[:, :, at], old[:, :, at])
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_reset_slots_zeroes_exactly_the_masked_slots(arch):
+    """reset_slots zeroes every cache row and the position of the masked
+    slots and leaves every other slot bitwise as it was."""
+    cfg = _cfg(arch)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    _, cache = _filled(cfg, params, 4, 16, 6, seed=7)
+    before = jax.tree_util.tree_map(np.asarray, cache)
+    mask = np.array([False, True, False, True])
+    after = jax.jit(lambda c, m: reset_slots(c, m, cfg))(
+        cache, jnp.asarray(mask))
+    np.testing.assert_array_equal(
+        np.asarray(after["pos"]), np.where(mask, 0, before["pos"]))
+    leaves = jax.tree_util.tree_leaves_with_path(before)
+    got = jax.tree_util.tree_leaves(after)
+    for (path, old), new in zip(leaves, got):
+        new = np.asarray(new)
+        if old.ndim < 2:                                   # positions
+            continue
+        for b in range(4):
+            if mask[b]:
+                assert not new[:, b].any(), path
+            else:
+                np.testing.assert_array_equal(new[:, b], old[:, b])
+
+
+def test_decode_spans_count_slots_written():
+    """Each decode call span carries slots_written: the slots whose K/V
+    rows the step wrote, which are exactly its active participants."""
+    cfg = _cfg("tinyllama-1.1b")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    trace = make_trace(WorkloadSpec(n_requests=5, arrival_rate=2.0,
+                                    prompt_len=(2, 8), gen_len=(2, 6),
+                                    seed=8), cfg.vocab_size)
+    tracer = Tracer(arch=cfg.name)
+    eng = ServeEngine(cfg, params, n_slots=3, max_len=24, prefill_chunk=4,
+                      tracer=tracer)
+    eng.run(trace)
+    dec = [r["attrs"] for r in tracer.records
+           if r.get("type") == "span" and r["name"] == "call"
+           and r["attrs"].get("kind") == "decode"]
+    assert dec
+    for a in dec:
+        assert a["slots_written"] == len(a["participants"]) > 0
+    assert sum(a["slots_written"] for a in dec) < 3 * len(dec)

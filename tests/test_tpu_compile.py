@@ -12,6 +12,7 @@ every test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -108,3 +109,79 @@ def test_decode_step_compiles_with_kernel_and_tables_as_arguments(
     code = compiled.memory_analysis().generated_code_size_in_bytes
     # the int8 payload rides in as an argument, not as baked constants
     assert code < table_bytes, (code, table_bytes)
+
+
+# -------------------------------------------- the K/V cache stays in place --
+
+_DEF = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
+_ARR = re.compile(r"(\w+)\[([\d,]*)\]")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+
+
+def _hlo_defs(text):
+    """name -> (result arrays [(dtype, elements)], opcode, operand names)
+    for every instruction of an optimized HLO module, fused computations
+    included (instruction names are unique within a module)."""
+    defs = {}
+    for line in text.splitlines():
+        m = _DEF.match(line)
+        if not m:
+            continue
+        name, result, op, rest = m.groups()
+        arrays = [(dt, int(np.prod([int(d) for d in dims.split(",") if d])))
+                  for dt, dims in _ARR.findall(result)]
+        defs[name] = (arrays, op, _OPERAND.findall(rest.split("), ")[0]))
+    return defs
+
+
+def _whole_kv_ops(text, kv_elems, dtype="bf16"):
+    """Copies and selects whose result, and dynamic-update-slices whose
+    UPDATE, is a whole layer's or the whole stack's K or V, in any layout
+    or shape. The in-place write of a step's own rows (a scatter, or a
+    dynamic-update-slice of a few rows into the stack) passes."""
+    defs = _hlo_defs(text)
+
+    def whole(arrays):
+        return any(dt == dtype and n in kv_elems for dt, n in arrays)
+
+    bad = []
+    for name, (arrays, op, operands) in defs.items():
+        if op in ("copy", "select") and whole(arrays):
+            bad.append(name)
+        elif op == "dynamic-update-slice" and len(operands) > 1 and \
+                operands[1] in defs and whole(defs[operands[1]][0]):
+            bad.append(name)
+    return bad
+
+
+def test_serving_steps_write_the_kv_cache_in_place(one_chip, monkeypatch):
+    """Two published-width stablelm layers, 16 slots x 1024, dense: the
+    decode step and the prefill chunk step update the donated K/V cache
+    where it lies. No copy, select or dynamic-update-slice moves a whole
+    layer's or the whole stack's K/V (the per-layer relayout copies and
+    the whole-cache select this replaced), and neither step's scratch
+    holds a layer's K or V. A prefill chunk's scratch does hold its
+    float32 attention scores (slots x heads x chunk x cache positions)."""
+    monkeypatch.setenv(INTERPRET_ENV, "0")
+    cfg = get_config(ARCH, dbpim_mode="dense").scaled(n_layers=2)
+    n_slots, max_len, chunk = 16, 1024, 128
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: init_cache(cfg, n_slots, max_len))
+    cache["pos"] = jax.ShapeDtypeStruct((n_slots,), jnp.int32)
+    cache["attn"]["pos"] = jax.ShapeDtypeStruct((n_slots,), jnp.int32)
+    layer = n_slots * max_len * cfg.n_kv_heads * cfg.hd
+    kv_elems = {layer, cfg.n_layers * layer}
+    layer_bytes = 2 * layer
+    scores = 4 * n_slots * cfg.n_heads * chunk * max_len
+    for kind, last, scratch in (
+            ("decode", (jax.ShapeDtypeStruct((n_slots, 1), jnp.int32),
+                        jax.ShapeDtypeStruct((n_slots,), jnp.bool_)), 0),
+            ("prefill_chunk",
+             (jax.ShapeDtypeStruct((n_slots, chunk), jnp.int32),
+              jax.ShapeDtypeStruct((n_slots,), jnp.int32)), scores)):
+        step, _ = build_step(cfg, None, kind)
+        args = _on(one_chip, (params, None, cache) + last)
+        compiled = jax.jit(step, donate_argnums=(2,)).lower(*args).compile()
+        assert _whole_kv_ops(compiled.as_text(), kv_elems) == [], kind
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < scratch + layer_bytes, (kind, temp)
