@@ -138,6 +138,17 @@ def render(records: List[dict], width: int = 64) -> str:
                      f"{sum(written) / len(written):.2f}{of} slots, one "
                      f"position each (a whole-cache rewrite is every "
                      f"slot's every position)")
+    advanced = [c["attrs"]["state_slots"] for c in calls
+                if "state_slots" in c["attrs"]]
+    if advanced:
+        of = f" of {meta['n_slots']}" if meta.get("n_slots") else ""
+        lines.append(f"  SSM states advanced per call: "
+                     f"{sum(advanced) / len(advanced):.2f}{of} slots")
+    resets = [r["attrs"]["state_resets"] for r in spans
+              if r["name"] == "schedule" and "state_resets" in r["attrs"]]
+    if resets:
+        lines.append(f"  SSM states zeroed at admission: {sum(resets)} "
+                     f"slots over {len(resets)} ticks")
     chunks = [c["attrs"] for c in calls if "rows_valid" in c["attrs"]]
     if chunks:
         lines.append(f"  K/V rows written per prefill chunk: "

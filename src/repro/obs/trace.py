@@ -48,11 +48,15 @@ The engine's spans split one tick by host phase:
   ========  ===========================================================
   tick      the whole tick, journal commit and snapshot included
   schedule  cache-fault injection, deadline shedding, admission (with
-            its slot-reset dispatch) and, when paged, page growth
+            its slot-reset dispatch) and, when paged, page growth; an
+            SSM model's carries ``state_resets`` (slots whose conv and
+            SSM state admission zeroed)
   call      one device call, from input assembly to its logits on the
             host; prefill calls carry ``rows`` (slots x chunk) and
             ``rows_valid`` (prompt tokens in the chunk), decode calls
-            ``slots_written`` (slots whose K/V rows the step wrote)
+            ``slots_written`` (slots whose K/V rows the step wrote);
+            an SSM model's calls ``state_slots`` (slots whose conv
+            and SSM state the step advanced)
   logits    inside "call": the wait for the step and the device-to-host
             copy of the last position's logits
   sample    after each call: argmax, the finite guard and the per-slot

@@ -95,9 +95,10 @@ traced run (the zero-overhead contract the chaos bench guards):
     by host phase: "tick", and inside it "schedule" (fault injection,
     shedding, admission, page growth), one "call" per device call
     (call_kind/arch/occupancy/replay attrs, plus rows/rows_valid on
-    prefill chunks and slots_written on decode steps) running from
-    input assembly until the logits are
-    on the host, its child "logits" (the wait for the step and the
+    prefill chunks and slots_written on decode steps; an SSM model's
+    calls also carry state_slots, its "schedule" state_resets) running
+    from input assembly until the logits are on the host, its child
+    "logits" (the wait for the step and the
     device-to-host copy), one "sample" per call (argmax, finite guard,
     slot updates), and "commit" (journal commit, snapshot). It also
     records slot lifecycle events (submit / admit / prefill /
@@ -318,6 +319,9 @@ class ServeEngine:
         self.max_step_retries = max_step_retries
         self.max_replays = max_replays
         self.tracer = tracer
+        # SSM conv/state per slot: counted on the tracer's spans
+        self._ssm_state = any(seg.mixer == "ssm" for seg in
+                              cfg.serving_capabilities().segments)
         # -- durability layer (all host-side: journaling/snapshotting
         # never issue device calls, so journal=None vs a live journal is
         # bitwise-output- and device-call-count-identical — the same
@@ -665,7 +669,7 @@ class ServeEngine:
             self._inject_cache_faults(tick)
         if self._has_deadlines:
             self._shed_hopeless_slots(tick)
-        self._admit(tick)
+        resets = self._admit(tick)
         if self.paged:
             # every occupied slot must own the pages this tick's writes
             # land in BEFORE the device calls go out; pressure resolves
@@ -673,7 +677,8 @@ class ServeEngine:
             self._page_growth(tick)
             self.page_alloc.check()
         if sched is not None:
-            tr.end(sched)
+            tr.end(sched, **({"state_resets": resets}
+                             if self._ssm_state else {}))
         if self.prefill_mode == "chunked":
             calls += self._prefill_phase(tick)
         calls += self._decode_phase(tick)
@@ -775,7 +780,7 @@ class ServeEngine:
         pages for the full (re-)prefill record rather than merely a free
         slot. A gate miss is head-of-line blocking: nothing younger
         jumps it (jumping would re-trigger the very preemptions that
-        freed the pages)."""
+        freed the pages). Returns the number of slots reset."""
         if self._has_deadlines:
             self._shed_hopeless_queue(tick)
         mask = np.zeros((self.n_slots,), bool)
@@ -848,6 +853,7 @@ class ServeEngine:
             self._open_interval[s] = iv
         if mask.any():
             self.cache = self._reset_call(mask)
+        return int(mask.sum())
 
     # ------------------------------------------------------- page pressure
 
@@ -967,8 +973,13 @@ class ServeEngine:
             lg = self._host_logits(logits, tick, "prefill")
         dur_s = time.monotonic() - c0
         if span is not None:
-            tr.end(span, ok=res is not None, rows=tokens.size,
-                   rows_valid=int(n_valid.sum()))
+            attrs = dict(ok=res is not None, rows=tokens.size,
+                         rows_valid=int(n_valid.sum()))
+            if self._ssm_state:
+                # the chunk advances the state of slots with prompt tokens
+                attrs["state_slots"] = (int((n_valid > 0).sum())
+                                        if res is not None else 0)
+            tr.end(span, **attrs)
         if res is None:                   # persistent step failure:
             for s in prefilling:          # quarantine every participant
                 self._quarantine(s, tick, "step_exception")
@@ -1030,9 +1041,11 @@ class ServeEngine:
             lg = self._host_logits(logits, tick, "decode")
         dur_s = time.monotonic() - c0
         if span is not None:
-            # the step writes K/V rows for its active slots only
-            tr.end(span, ok=res is not None,
-                   slots_written=int(active.sum()) if res is not None else 0)
+            # the step writes K/V rows and advances SSM state for its
+            # active slots only
+            n = int(active.sum()) if res is not None else 0
+            tr.end(span, ok=res is not None, slots_written=n,
+                   **({"state_slots": n} if self._ssm_state else {}))
         if res is None:
             for s in range(self.n_slots):
                 if active[s]:

@@ -449,3 +449,77 @@ def test_chrome_trace_structure():
            if e["ph"] == "X" and e.get("tid") == 2]
     assert len(ivs) == 1 and "rid7" in ivs[0]["name"]
     json.dumps(ct)                            # must be serializable
+
+
+# ------------------------------------------------------- SSM state counters --
+
+@pytest.fixture(scope="module")
+def ssm_traced():
+    """One traced run of the reduced mamba2 preset: five admissions into
+    two slots, the first two in the same tick."""
+    cfg = _cfg("mamba2-1.3b")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tracer = Tracer(arch=cfg.name)
+    engine, outputs = _run_ssm(cfg, params, tracer)
+    return cfg, params, engine, outputs, tracer
+
+
+def _run_ssm(cfg, params, tracer=None):
+    engine = ServeEngine(cfg, params, n_slots=N_SLOTS, max_len=MAX_LEN,
+                         prefill_chunk=CHUNK, tracer=tracer)
+    reqs = [Request(rid=i, prompt=list(range(1, 5 + i)), gen_len=5,
+                    arrival=max(0, i - 1)) for i in range(5)]
+    return engine, engine.run(reqs)
+
+
+def test_ssm_call_spans_count_state_slots(ssm_traced):
+    """Every decode and prefill call of an SSM model carries
+    state_slots: the slots whose conv and SSM state the step advanced,
+    which are exactly its participants."""
+    *_, tracer = ssm_traced
+    calls = [c["attrs"] for c in _spans(tracer, "call")]
+    assert {a["phase"] for a in calls} == {"prefill", "decode"}
+    for a in calls:
+        assert a["state_slots"] == len(a["participants"]) > 0
+
+
+def test_ssm_schedule_spans_count_state_resets(ssm_traced):
+    """Each tick's schedule span carries state_resets: the slots whose
+    state admission zeroed, one per admission of that tick."""
+    *_, tracer = ssm_traced
+    admits = {}
+    for r in tracer.records:
+        if r.get("type") == "event" and r["name"] == "admit":
+            admits[r["tick"]] = admits.get(r["tick"], 0) + 1
+    sched = _spans(tracer, "schedule")
+    assert len(sched) == len(_spans(tracer, "tick"))
+    for s in sched:
+        assert s["attrs"]["state_resets"] == admits.get(s["tick"], 0)
+    assert sum(s["attrs"]["state_resets"] for s in sched) == 5
+    assert sched[0]["attrs"]["state_resets"] == 2
+
+
+def test_report_prints_ssm_state_counters(ssm_traced, traced):
+    """launch.report prints the SSM counters where the spans carry
+    them, and an attention model's spans carry none."""
+    from repro.launch.report import render
+    *_, tracer = ssm_traced
+    out = render(tracer.records)
+    assert "SSM states advanced per call: " in out
+    assert "SSM states zeroed at admission: 5 slots over" in out
+    _, bare = traced
+    for r in bare.records:
+        if r.get("type") == "span":
+            assert not {"state_slots", "state_resets"} & set(r["attrs"])
+    assert "SSM states" not in render(bare.records)
+
+
+def test_ssm_tracer_off_is_bitwise_free(ssm_traced):
+    """The SSM counters observe the engine and never steer it: the same
+    tokens (bitwise) and device calls with the tracer off."""
+    cfg, params, traced_engine, traced_out, _ = ssm_traced
+    bare_engine, bare_out = _run_ssm(cfg, params)
+    assert traced_out == bare_out
+    ts, bs = traced_engine.metrics.summary(), bare_engine.metrics.summary()
+    assert ts["calls_by_kind"] == bs["calls_by_kind"]
+    assert ts["engine_ticks"] == bs["engine_ticks"]
