@@ -112,11 +112,13 @@ def check_served_path(cfg, *, n_slots: int, max_len: int, prefill_chunk: int,
                 jax.device_put(jax.tree_util.tree_map(jnp.zeros_like,
                                                       engine.cache),
                                engine._cache_sharding),
-                jnp.asarray(chunk), jnp.full((n_slots,), P, jnp.int32))
+                jnp.asarray(chunk), jnp.full((n_slots,), P, jnp.int32),
+                jnp.arange(n_slots, dtype=jnp.int32))
+    prefill = engine._prefill[n_slots]       # the whole-batch bucket
     t0 = time.perf_counter()
-    engine._prefill.lower(*pre_args).compile()
+    prefill.lower(*pre_args).compile()
     timings[f"compile_{engine.prefill_kind}"] = time.perf_counter() - t0
-    logits_p, cache = engine._prefill(*pre_args)
+    logits_p, cache = prefill(*pre_args)
     dec_args = (engine.params, engine.stacked_tables, cache,
                 jnp.asarray(toks[:, P:P + 1]), jnp.ones((n_slots,), bool))
     t0 = time.perf_counter()

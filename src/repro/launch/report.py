@@ -33,7 +33,7 @@ from __future__ import annotations
 import argparse
 import json
 import sys
-from collections import defaultdict
+from collections import Counter, defaultdict
 from typing import Dict, List
 
 from repro.obs import SPAN_NAMES, to_chrome_trace, validate
@@ -151,9 +151,14 @@ def render(records: List[dict], width: int = 64) -> str:
                      f"slots over {len(resets)} ticks")
     chunks = [c["attrs"] for c in calls if "rows_valid" in c["attrs"]]
     if chunks:
+        n = len(chunks)
+        buckets = Counter(a["bucket"] for a in chunks if "bucket" in a)
         lines.append(f"  K/V rows written per prefill chunk: "
-                     f"{sum(a['rows_valid'] for a in chunks) / len(chunks):.1f}"
-                     f" of {chunks[0]['rows']}")
+                     f"{sum(a['rows_valid'] for a in chunks) / n:.1f}"
+                     f" of {sum(a['rows'] for a in chunks) / n:.1f} computed"
+                     + (f"; chunks per row bucket "
+                        f"{dict(sorted(buckets.items()))}" if buckets
+                        else ""))
     if ticks:
         per_tick = self_times(spans)
         wall = max(sum(t["dur_us"] for t in ticks), 1e-9)

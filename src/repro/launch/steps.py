@@ -110,10 +110,10 @@ def _moment_specs(params, pspecs, moments, mesh):
 SERVE_CALL_KINDS = ("serve", "decode", "prefill_chunk")
 
 #: Call-kind tag suffix for RECOVERY traffic: the serving engine reuses
-#: the one compiled prefill executable for recovery-by-replay
-#: re-prefills (a faulted slot's durable record re-enters through the
-#: same fixed-shape chunk step — no extra compilation for the rare
-#: path), but meters those calls separately by suffixing the step's
+#: the compiled prefill executables for recovery-by-replay re-prefills
+#: (a faulted slot's durable record re-enters through the same
+#: fixed-shape chunk steps — no extra compilation for the rare path),
+#: but meters those calls separately by suffixing the step's
 #: call_kind tag, e.g. "prefill_parallel+replay". Benchmarks multiply
 #: metrics.calls_by_kind["<kind>+replay"] by the per-call weight bytes
 #: of the base kind to price recovery overhead.
@@ -162,11 +162,16 @@ def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
         batching with ZERO per-request recompilation. Positions come from
         cache["pos"], a (B,) vector of per-slot depths. Tag "decode".
       * "prefill_chunk" — chunked cache-filling prefill,
-        ``(params, tables, cache, tokens, n_valid)``: C prompt tokens per
-        slot in ONE fixed-shape device call (models.decode.decode_chunk),
-        so time-to-first-token is ceil(P/C) steps instead of P. n_valid
-        (B,) carries each slot's real token count this chunk (0 = slot
-        not prefilling; its cache is untouched). Tag "prefill_parallel"
+        ``(params, tables, cache, tokens, n_valid, slots)``: C prompt
+        tokens per row in ONE fixed-shape device call
+        (models.decode.decode_chunk), so time-to-first-token is
+        ceil(P/C) steps instead of P. tokens (R, C), n_valid (R,) and
+        slots (R,) int32: row r carries n_valid[r] real tokens of slot
+        slots[r] (0 = a padding row; its slot index may be out of range
+        and nothing is written). R is the step's row bucket, fixed by
+        the shapes it is compiled for: R == n_slots is the whole batch
+        in slot order (slots = arange), a smaller R gathers and writes
+        back only the named slots' cache. Tag "prefill_parallel"
         when SSM segments run the parallel SSD chunk form (one read of
         the stacked in/out projections per chunk;
         models.ssm.prefill_ssm_parallel), "prefill_chunk_exact" when
@@ -248,38 +253,38 @@ def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
                     bspec["active"])
 
     elif paged:                            # "prefill_chunk", paged
-        def step_fn(params, tables, cache, tokens, n_valid, ptab):
+        def step_fn(params, tables, cache, tokens, n_valid, slots, ptab):
             return decode_chunk(params, cache, tokens, n_valid, cfg,
-                                tables=tables, ptab=ptab)
+                                tables=tables, ptab=ptab, slots=slots)
         step_fn.call_kind = _chunk_kind(cfg)
 
-        def shardings(params, tables, cache, tokens, n_valid, ptab):
-            pspec = _serving_param_specs(params, mesh)
-            cspec = shr.cache_specs(cache, cfg, mesh)
-            bspec = shr.batch_specs({"tokens": tokens, "n_valid": n_valid},
-                                    mesh)
-            return (pspec, _table_specs(tables), cspec, bspec["tokens"],
-                    bspec["n_valid"], P())
+        def shardings(params, tables, cache, tokens, n_valid, slots, ptab):
+            return _chunk_specs(cfg, mesh, params, tables, cache, tokens,
+                                n_valid, slots) + (P(),)
 
     else:                                  # "prefill_chunk"
-        def step_fn(params, tables, cache, tokens, n_valid):
+        def step_fn(params, tables, cache, tokens, n_valid, slots):
             return decode_chunk(params, cache, tokens, n_valid, cfg,
-                                tables=tables)
+                                tables=tables, slots=slots)
         step_fn.call_kind = _chunk_kind(cfg)
 
-        def shardings(params, tables, cache, tokens, n_valid):
-            pspec = _serving_param_specs(params, mesh)
-            cspec = shr.cache_specs(cache, cfg, mesh)
-            bspec = shr.batch_specs({"tokens": tokens, "n_valid": n_valid},
-                                    mesh)
-            return (pspec, _table_specs(tables), cspec, bspec["tokens"],
-                    bspec["n_valid"])
+        def shardings(params, tables, cache, tokens, n_valid, slots):
+            return _chunk_specs(cfg, mesh, params, tables, cache, tokens,
+                                n_valid, slots)
 
     # which model family compiled this step — paired with call_kind it
     # forms the recompile sentinel's registry key and the tracer's
     # call-span arch attribute
     step_fn.arch = cfg.name
     return step_fn, shardings
+
+
+def _chunk_specs(cfg, mesh, params, tables, cache, tokens, n_valid, slots):
+    bspec = shr.batch_specs({"tokens": tokens, "n_valid": n_valid,
+                             "slots": slots}, mesh)
+    return (_serving_param_specs(params, mesh), _table_specs(tables),
+            shr.cache_specs(cache, cfg, mesh), bspec["tokens"],
+            bspec["n_valid"], bspec["slots"])
 
 
 def _chunk_kind(cfg: ModelConfig) -> str:

@@ -253,18 +253,18 @@ def _write_token(stack, layer, slot, kv, ok):
                     pos].set(upd, mode="drop")
 
 
-def _write_chunk(stack, layer, start, kv, n):
-    """Write a chunk's kv (B, C, Hkv, hd) into one layer of a contiguous
-    cache stack (L, B, Hg, A, W), in place: slot b's first n[b] tokens
-    go to positions start[b] .. start[b] + n[b] - 1, as a
-    dynamic-update-slice of its contiguous (C, W) window in each head
-    group, in which every other row keeps what it held (a window that
-    would run past the cache's end starts earlier, its rows shifted to
-    match). A slot with n[b] == 0 is skipped: its cache stays bitwise as
-    it was, and the step touches only the windows of the slots that
-    prefill."""
+def _write_chunk(stack, layer, start, kv, n, slots=None):
+    """Write a chunk's kv (R, C, Hkv, hd) into one layer of a contiguous
+    cache stack (L, B, Hg, A, W), in place: row r's first n[r] tokens
+    go to slot slots[r] (r itself when slots is None) at positions
+    start[r] .. start[r] + n[r] - 1, as a dynamic-update-slice of its
+    contiguous (C, W) window in each head group, in which every other
+    row keeps what it held (a window that would run past the cache's
+    end starts earlier, its rows shifted to match). A row with
+    n[r] == 0 is skipped: its slot's cache stays bitwise as it was, and
+    the step touches only the windows of the slots that prefill."""
     L, B, Hg, A, W = stack.shape
-    C = kv.shape[1]
+    R, C = kv.shape[0], kv.shape[1]
     if C > A:
         raise ValueError(f"a chunk of {C} tokens does not fit a cache of "
                          f"{A} positions")
@@ -276,14 +276,14 @@ def _write_chunk(stack, layer, start, kv, n):
         keep = ((src >= 0) & (src < n[b]))[:, None]
         new = jnp.take(upd[b], jnp.clip(src, 0, C - 1), axis=1)
         for g in range(Hg):              # one contiguous (C, W) window each
-            idx = (layer, b, g, at[b], 0)
+            idx = (layer, b if slots is None else slots[b], g, at[b], 0)
             old = jax.lax.dynamic_slice(st, idx, (1, 1, 1, C, W))[0, 0, 0]
             blk = jnp.where(keep, new[g], old)
             st = jax.lax.dynamic_update_slice(st, blk[None, None, None], idx)
         return st
 
     return jax.lax.fori_loop(
-        0, B, lambda b, st: jax.lax.cond(n[b] > 0, write,
+        0, R, lambda b, st: jax.lax.cond(n[b] > 0, write,
                                          lambda b, st: st, b, st), stack)
 
 
@@ -421,27 +421,43 @@ def decode_attention(p, x, cache_k, cache_v, pos, cfg: ModelConfig,
     return mm(p["wo"], out.reshape(B, 1, cfg.q_dim), "wo"), new_k, new_v
 
 
+def _read_rows(stack, layer, slots):
+    """(R, Hg, A, W): the rows of slots[0..R-1] in one layer of a
+    contiguous stack (L, B, Hg, A, W), each read by a dynamic slice. A
+    gather of them (``stack[layer, slots]``) compiles on TPU to a tiled
+    gather about ten times slower than the attention that reads it."""
+    _, _, Hg, A, W = stack.shape
+    return jnp.concatenate([
+        jax.lax.dynamic_slice(stack, (layer, slots[r], 0, 0, 0),
+                              (1, 1, Hg, A, W))[0]
+        for r in range(slots.shape[0])])
+
+
 def prefill_attention(p, x, cache_k, cache_v, pos, n_valid,
                       cfg: ModelConfig, dense_fn=None, ptab=None,
-                      layer=None):
+                      layer=None, slots=None):
     """Chunked cache-filling attention: C prompt tokens in one step.
 
-    x (B, C, D); pos (B,) tokens already in the cache per slot; n_valid
-    (B,) in [0, C] real tokens in this chunk (the tail chunk of a prompt
-    is ragged; slots not prefilling pass 0). Writes the valid tokens' k/v
-    at positions pos..pos+n_valid-1 and nothing else (a slot with n_valid
-    0 is skipped, so its cache rows are untouched) and attends each query
-    to every cached position <= its own —
-    bit-identical per token to running `decode_attention` n_valid times,
-    but one MXU-shaped step. Returns (out, new_k, new_v).
+    x (R, C, D), one row per slot; pos (R,) tokens already in the cache
+    per row's slot; n_valid (R,) in [0, C] real tokens in this chunk
+    (the tail chunk of a prompt is ragged; rows not prefilling pass 0).
+    Writes the valid tokens' k/v at positions pos..pos+n_valid-1 and
+    nothing else (a row with n_valid 0 is skipped, so its slot's cache
+    rows are untouched) and attends each query to every cached position
+    <= its own — bit-identical per token to running `decode_attention`
+    n_valid times, but one MXU-shaped step. Returns (out, new_k, new_v).
 
     cache_k/v are, as in `decode_attention`, the segment's whole
     contiguous stack (L, B, Hg, A, W) written in place at ``layer``
-    (_write_chunk), or with ptab one layer's page pool (n_pages,
-    page_size, Hkv, hd); paged writes scatter through the table, invalid
-    columns routed to the out-of-range sentinel and DROPPED, and reads
-    gather the contiguous layout. Bitwise-identical to the contiguous
-    chunk when max_pages * page_size == A.
+    (_write_chunk), or with ptab (R, max_pages) one layer's page pool
+    (n_pages, page_size, Hkv, hd); paged writes scatter through the
+    table, invalid columns routed to the out-of-range sentinel and
+    DROPPED, and reads gather the contiguous layout. Bitwise-identical
+    to the contiguous chunk when max_pages * page_size == A.
+
+    slots (R,) int32, in range, names each row's slot in a contiguous
+    stack of B >= R slots: only those slots' windows are written and
+    only their K/V rows are read. None means row r is slot r (R == B).
 
     Requires cfg.window == 0: a sliding-window ring buffer overwrites
     slots within the chunk, which only a sequential walk reproduces.
@@ -456,9 +472,13 @@ def prefill_attention(p, x, cache_k, cache_v, pos, n_valid,
     qpos = posv[:, None] + jnp.arange(C)[None, :]                  # (B, C)
     q, k, v = _project_qkv(p, x, qpos, cfg, mm)
     if ptab is None:
-        new_k = _write_chunk(cache_k, layer, posv, k, n_valid)
-        new_v = _write_chunk(cache_v, layer, posv, v, n_valid)
-        kk, vv = new_k[layer], new_v[layer]
+        new_k = _write_chunk(cache_k, layer, posv, k, n_valid, slots)
+        new_v = _write_chunk(cache_v, layer, posv, v, n_valid, slots)
+        if slots is None:
+            kk, vv = new_k[layer], new_v[layer]
+        else:
+            kk = _read_rows(new_k, layer, slots)
+            vv = _read_rows(new_v, layer, slots)
     else:
         tok_valid = jnp.arange(C)[None, :] < n_valid[:, None]      # (B, C)
         wpos = jnp.minimum(qpos, A - 1)                            # (B, C)

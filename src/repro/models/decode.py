@@ -194,8 +194,18 @@ def decode_step(params, cache, token, cfg: ModelConfig, tables=None,
     return logits_from_hidden(params["embed"], x, cfg), new_cache
 
 
+def _n_slots(cache, segs, ptab) -> int:
+    """Slots the cache holds: the page table's rows when paged, else the
+    batch axis (1) of the first segment's per-slot leaf, its k (an
+    attention segment) or its conv window (an SSM segment)."""
+    if ptab is not None:
+        return ptab.shape[0]
+    seg = segs[0]
+    return cache[seg.cache]["k" if seg.mixer == "attn" else "conv"].shape[1]
+
+
 def decode_chunk(params, cache, tokens, n_valid, cfg: ModelConfig,
-                 tables=None, ptab=None):
+                 tables=None, ptab=None, slots=None):
     """Chunked cache-filling prefill: C prompt tokens per slot, one step.
 
     ptab (B, max_pages) int32 switches attention segments to the PAGED
@@ -234,6 +244,19 @@ def decode_chunk(params, cache, tokens, n_valid, cfg: ModelConfig,
     Like decode_step, `tables` threads the per-segment uniform-MAXB
     joint-sparse packs through each segment's scan, so prompt chunks run
     the DB-PIM kernel too.
+
+    COMPACT rows: with slots (P,) int32 and P smaller than the cache's
+    B slots, tokens and n_valid are (P, C) and (P,), row r prefilling
+    slot slots[r]. The step gathers only those slots' position, K/V
+    rows (contiguous), page-table rows (paged) and SSM conv/state, runs
+    the same per-row math on P rows, and writes back only what they
+    changed, in place: K/V windows as above, SSM conv/state and the
+    position scattered at slots. A row with n_valid 0 (padding up to
+    P; its slot index may be B, out of range) writes nothing. Logits
+    are (P, 1, V), row r for slot slots[r]. With P == B the rows are the
+    slots in order and slots is not read (a caller passes arange(B),
+    as serving.prefill.assemble_chunk does): the whole-batch chunk,
+    with no gather or scatter.
     """
     if cfg.window:
         raise ValueError(f"chunked prefill is not supported for {cfg.name}"
@@ -241,15 +264,37 @@ def decode_chunk(params, cache, tokens, n_valid, cfg: ModelConfig,
                          f"prefill")
     segs = decoder_layout(cfg)
     seg_tables = segment_tables(tables, segs, cfg)
-    B, C = tokens.shape
-    pos = attn_mod._per_slot_pos(cache["pos"], B)
+    R, C = tokens.shape
     n_valid = jnp.asarray(n_valid, jnp.int32)
+    B = _n_slots(cache, segs, ptab) if slots is not None else R
+    pos_all = attn_mod._per_slot_pos(cache["pos"], B)
+    enc_out = cache.get("enc_out")
+    if R < B:
+        # gathers read in-range slots (padding rows read the last slot
+        # and compute nothing that is kept); writes go to dst, where a
+        # row with no tokens names no slot and is dropped
+        sl = jnp.clip(jnp.asarray(slots, jnp.int32), 0, B - 1)
+        dst = jnp.where(n_valid > 0, sl, B)
+        pos = pos_all[sl]
+        if ptab is not None:
+            ptab = ptab[sl]
+        if enc_out is not None and enc_out.shape[0] == B:
+            enc_out = enc_out[sl]
+    else:
+        sl = dst = None
+        pos = pos_all
+
+    def put(old, rows):                  # per-slot leaf, batch on axis 1
+        if dst is None:
+            return rows
+        return old.at[:, dst].set(rows, mode="drop")
 
     x = embed_tokens(params["embed"], tokens, cfg)
     if cfg.rope_pct == 0:
         qpos = pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
         x = x + _sinusoidal_at(qpos, cfg.d_model).astype(x.dtype)
-    enc_out = cache.get("enc_out")
+    new_pos = (pos + n_valid if dst is None
+               else pos_all.at[dst].set(pos + n_valid, mode="drop"))
     new_cache = dict(cache)
 
     ssm_prefill = (ssm_mod.prefill_ssm if cfg.prefill_exact
@@ -266,13 +311,13 @@ def decode_chunk(params, cache, tokens, n_valid, cfg: ModelConfig,
                 hn = apply_norm(p["norm1"], h, cfg)
                 y, ck, cv = attn_mod.prefill_attention(
                     p["attn"], hn, ck, cv, pos, n_valid, cfg, dense_fn=mm,
-                    ptab=pt, layer=li)
+                    ptab=pt, layer=li, slots=None if pt is not None else sl)
                 h = _block_tail(seg, p, h + y, cfg, mm, enc_out,
                                 per_position=True)
                 return h, ck, cv
             x, nc = _attn_scan(layer, x, params[seg.name], c, txs, mk)
             if "pos" in c:
-                nc["pos"] = pos + n_valid
+                nc["pos"] = new_pos
             new_cache[seg.cache] = nc
         else:
             def step(h, inp, seg=seg, mk=mk):
@@ -284,14 +329,18 @@ def decode_chunk(params, cache, tokens, n_valid, cfg: ModelConfig,
                 h = _block_tail(seg, p, h + y, cfg, mm, enc_out,
                                 per_position=True)
                 return h, (conv, state)
+            conv, state = c["conv"], c["state"]
+            if sl is not None:           # only the P rows ride the scan
+                conv, state = conv[:, sl], state[:, sl]
             x, (convs, states) = jax.lax.scan(
-                step, x, (params[seg.name], c["conv"], c["state"], txs))
-            new_cache[seg.cache] = {"conv": convs, "state": states}
+                step, x, (params[seg.name], conv, state, txs))
+            new_cache[seg.cache] = {"conv": put(c["conv"], convs),
+                                    "state": put(c["state"], states)}
 
-    new_cache["pos"] = pos + n_valid
+    new_cache["pos"] = new_pos
     x = apply_norm(params["final_norm"], x, cfg)
     last = jnp.clip(n_valid - 1, 0, C - 1)
-    x_last = x[jnp.arange(B), last][:, None]                  # (B, 1, D)
+    x_last = x[jnp.arange(R), last][:, None]                  # (R, 1, D)
     return logits_from_hidden(params["embed"], x_last, cfg), new_cache
 
 

@@ -6,10 +6,10 @@ docstring). That property used to be folklore — a shape regression (a
 scalar position sneaking back in, a cache dtype flip between calls)
 would silently recompile every tick and only surface as a wall-clock
 anomaly. The sentinel turns it into a hard invariant: each jitted step
-is registered under a ``(call_kind, arch)`` key with a compile budget
-(default: ONE), and ``check()`` — called once per engine tick — raises
-``RecompileError`` the tick the budget is exceeded, naming the offender
-and its compile count.
+is registered under a ``(call_kind, arch[, rows])`` key with a compile
+budget (default: ONE), and ``check()`` — called once per engine tick —
+raises ``RecompileError`` the tick the budget is exceeded, naming the
+offender and its compile count.
 
 Counting uses the jit cache size (``PjitFunction._cache_size``), i.e.
 the number of distinct (shape, dtype, sharding) signatures the
@@ -44,8 +44,10 @@ class RecompileSentinel:
         self._fns: Dict[str, object] = {}
 
     @staticmethod
-    def key(call_kind: str, arch: str) -> str:
-        return f"{call_kind}@{arch}"
+    def key(call_kind: str, arch: str, rows: int = 0) -> str:
+        """Registry key; ``rows`` names one row bucket of a step that
+        compiles an executable per bucket (the prefill chunk)."""
+        return f"{call_kind}@{arch}" + (f"/{rows}" if rows else "")
 
     def register(self, key: str, jitted):
         """Track ``jitted`` (a jax.jit result) under ``key``. Re-registering

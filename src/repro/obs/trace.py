@@ -52,8 +52,9 @@ The engine's spans split one tick by host phase:
             SSM model's carries ``state_resets`` (slots whose conv and
             SSM state admission zeroed)
   call      one device call, from input assembly to its logits on the
-            host; prefill calls carry ``rows`` (slots x chunk) and
-            ``rows_valid`` (prompt tokens in the chunk), decode calls
+            host; prefill calls carry ``bucket`` (the step's row
+            bucket), ``rows`` (bucket x chunk) and ``rows_valid``
+            (prompt tokens in the chunk), decode calls
             ``slots_written`` (slots whose K/V rows the step wrote);
             an SSM model's calls ``state_slots`` (slots whose conv
             and SSM state the step advanced)

@@ -51,16 +51,17 @@ def serving_cost_by_kind(cfg, mesh, params, cache, *, n_slots: int,
     if caps.chunked_prefill:
         tokc = jnp.zeros((n_slots, prefill_chunk), jnp.int32)
         nv = jnp.full((n_slots,), prefill_chunk, jnp.int32)
+        slots = jnp.arange(n_slots, dtype=jnp.int32)  # the whole batch
         chunk_fn, _ = build_step(cfg, mesh, "prefill_chunk", paged=paged)
-        calls[chunk_fn.call_kind] = (chunk_fn,
-                                     (params, tables, cache, tokc, nv) + extra)
+        calls[chunk_fn.call_kind] = (chunk_fn, (params, tables, cache, tokc,
+                                                nv, slots) + extra)
         if include_exact_fallback and caps.parallel_prefill \
                 and not cfg.prefill_exact:
             exact_fn, _ = build_step(cfg.scaled(prefill_exact=True), mesh,
                                      "prefill_chunk", paged=paged)
             calls[exact_fn.call_kind] = (exact_fn,
-                                         (params, tables, cache, tokc, nv)
-                                         + extra)
+                                         (params, tables, cache, tokc, nv,
+                                          slots) + extra)
     # params and the packed tables (args 0 and 1) seed the provenance
     # tags: table leaves label as "tables/<family>/<part>"
     return analyze_call_kinds(calls, weight_argnums=(0, 1))

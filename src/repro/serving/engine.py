@@ -6,16 +6,18 @@ moves through
 
     QUEUED -> PREFILLING -> DECODING -> DONE
 
-with all scheduling host-side and all math in exactly TWO compiled
-executables (three with slot reset), fixed-shape so NO recompilation ever
-happens per request:
+with all scheduling host-side and all math in a few compiled
+executables, fixed-shape so NO recompilation ever happens per request:
 
   * decode step   (B, 1) tokens + (B,) active mask
     (launch.steps.build_step("decode") — inactive slots' K/V writes are
     dropped in the step, their position and SSM state updates discarded
     by models.decode.merge_slots);
-  * prefill chunk (B, C) tokens + (B,) n_valid
-    (serving.prefill.build_chunk_step — only in "chunked" mode);
+  * prefill chunk (R, C) tokens + (R,) n_valid + (R,) slots, one
+    executable per row bucket R in 1, 2, 4, ..., B
+    (serving.prefill.chunk_buckets / build_chunk_step — only in
+    "chunked" mode), each compiled at construction; a chunk runs on the
+    smallest bucket that holds its prefilling slots;
   * slot reset — zeroes a freed slot's KV/SSM cache slices and position
     before admission (models.decode.reset_slots), so a refilled slot is
     indistinguishable from a fresh batch.
@@ -109,9 +111,10 @@ traced run (the zero-overhead contract the chaos bench guards):
     (``engine.<name>``), so a device trace shows what the host was
     doing in each idle gap.
   * the RECOMPILE SENTINEL (on by default) registers every jitted step
-    under its (call_kind, arch) key and raises obs.RecompileError the
-    tick any of them compiles more than once — the fixed-shape
-    no-recompile contract above, enforced instead of assumed.
+    under its (call_kind, arch[, rows]) key and raises
+    obs.RecompileError the tick any of them compiles more than once —
+    the fixed-shape no-recompile contract above, enforced instead of
+    assumed.
   * every device call's wall latency, from dispatch until its logits
     are on the host, feeds a log-bucketed per-kind histogram
     (metrics.summary()["call_latency_ms"]: p50/p95/p99 without storing
@@ -164,8 +167,15 @@ from repro.serving.journal import Journal
 from repro.serving.metrics import MetricsRecorder
 from repro.serving.paging import PageAllocator
 from repro.serving.prefill import (PREFILL_MODES, assemble_chunk,
-                                   build_chunk_step)
+                                   build_chunk_step, chunk_buckets)
 from repro.serving.workload import Request
+
+
+def _device_row(lg: np.ndarray, r: int, dtype) -> np.ndarray:
+    """Row r of the host logits as the step returned it: shape (1, V)
+    in the step's dtype (the float32 host copy holds every value of a
+    narrower float exactly), with no further device op or transfer."""
+    return lg[r:r + 1].astype(dtype)
 
 
 class SlotState(enum.Enum):
@@ -308,7 +318,9 @@ class ServeEngine:
         self._ptab_version = -1
         self.preempted: deque = deque()   # _Preempted, FIFO re-admission
         self._admit_seq = 0
-        self.prefill_chunk = prefill_chunk
+        # no prompt is longer than the cache, and a chunk longer than
+        # the cache cannot be written
+        self.prefill_chunk = prefill_chunk = min(prefill_chunk, max_len)
         self.prefill_mode = prefill_mode
         self.schedule = schedule
         self.spf_age_cap = spf_age_cap
@@ -385,12 +397,15 @@ class ServeEngine:
                 in_shardings=dec_in,
                 out_shardings=(None, cache_sh),
                 donate_argnums=(2,))
-            self._prefill = None
+            # one chunk executable per row bucket: a chunk runs on the
+            # smallest bucket that holds its prefilling slots
+            self._prefill: Dict[int, object] = {}
             if prefill_mode == "chunked":
-                self._prefill = build_chunk_step(
-                    cfg, self.mesh, params, stacked_tables, cache, n_slots,
-                    prefill_chunk, paged=self.paged,
-                    max_pages=self.max_pages_per_slot)
+                for rows in chunk_buckets(cfg, n_slots, prefill_chunk):
+                    self._prefill[rows] = build_chunk_step(
+                        cfg, self.mesh, params, stacked_tables, cache,
+                        n_slots, prefill_chunk, rows, paged=self.paged,
+                        max_pages=self.max_pages_per_slot)
             if self.paged:
                 self._reset = jax.jit(
                     lambda c, m, pt: reset_slots(c, m, cfg, ptab=pt),
@@ -402,11 +417,20 @@ class ServeEngine:
                     out_shardings=cache_sh,
                     donate_argnums=(0,))
 
+        # compile every bucket now, by a warm call that writes nothing
+        # (outside the mesh context, as serving calls are: a different
+        # tracing context would compile again): no bucket may compile
+        # later, mid-serving
+        for rows in self._prefill:
+            tokens, n_valid, slots = assemble_chunk({}, {}, n_slots,
+                                                    prefill_chunk, rows)
+            self.cache = self._chunk_call(rows, tokens, n_valid, slots)[1]
+
         # which chunk math this engine's prefill executable compiles to
         # ("prefill_parallel" / "prefill_chunk_exact"; None in "full" mode
         # where prompt tokens ride the decode call)
-        self.prefill_kind = (self._prefill.call_kind
-                             if self._prefill is not None else None)
+        self.prefill_kind = (self._prefill[n_slots].call_kind
+                             if self._prefill else None)
 
         # the fixed-shape no-recompile contract, enforced: each jitted
         # step gets ONE compile; check() runs every tick (obs.sentinel)
@@ -415,10 +439,10 @@ class ServeEngine:
             self.sentinel = RecompileSentinel()
             self.sentinel.register(RecompileSentinel.key("decode", cfg.name),
                                    self._decode)
-            if self._prefill is not None:
+            for rows, fn in self._prefill.items():
                 self.sentinel.register(
-                    RecompileSentinel.key(self.prefill_kind, cfg.name),
-                    self._prefill)
+                    RecompileSentinel.key(self.prefill_kind, cfg.name,
+                                          rows), fn)
             self.sentinel.register(RecompileSentinel.key("reset", cfg.name),
                                    self._reset)
 
@@ -944,6 +968,15 @@ class ServeEngine:
             deadline=slot.deadline, fault_count=slot.fault_count))
         self.slots[s] = _Slot()
 
+    def _chunk_call(self, rows: int, tokens, n_valid, slots):
+        """Dispatch the chunk step of bucket ``rows``; (logits, cache)."""
+        args = (self.params, self.stacked_tables, self.cache,
+                jnp.asarray(tokens), jnp.asarray(n_valid),
+                jnp.asarray(slots))
+        if self.paged:
+            args = args + (self._ptab(),)
+        return self._prefill[rows](*args)
+
     def _prefill_phase(self, tick: int) -> int:
         prefilling = {s: slot.prompt for s, slot in enumerate(self.slots)
                       if slot.state is SlotState.PREFILLING}
@@ -958,22 +991,23 @@ class ServeEngine:
                          occupancy=len(prefilling) / self.n_slots,
                          replay=replaying, restore=restoring)
                 if tr is not None else None)
+        # the smallest bucket that holds every prefilling slot
+        rows = min(b for b in self._prefill if b >= len(prefilling))
         cursors = {s: self.slots[s].cursor for s in prefilling}
-        tokens, n_valid = assemble_chunk(prefilling, cursors, self.n_slots,
-                                         self.prefill_chunk)
+        tokens, n_valid, slots = assemble_chunk(
+            prefilling, cursors, self.n_slots, self.prefill_chunk, rows)
+        row_of = {int(s): r for r, s in enumerate(slots)
+                  if int(s) in prefilling}
         c0 = time.monotonic()
-        args = (self.params, self.stacked_tables, self.cache,
-                jnp.asarray(tokens), jnp.asarray(n_valid))
-        if self.paged:
-            args = args + (self._ptab(),)
         res = self._device_call("prefill", self.prefill_kind,
-                                self._prefill, *args)
+                                self._chunk_call, rows, tokens, n_valid,
+                                slots)
         if res is not None:
             logits, self.cache = res
-            lg = self._host_logits(logits, tick, "prefill")
+            lg = self._host_logits(logits, tick, "prefill", row_of)
         dur_s = time.monotonic() - c0
         if span is not None:
-            attrs = dict(ok=res is not None, rows=tokens.size,
+            attrs = dict(ok=res is not None, rows=tokens.size, bucket=rows,
                          rows_valid=int(n_valid.sum()))
             if self._ssm_state:
                 # the chunk advances the state of slots with prompt tokens
@@ -990,11 +1024,12 @@ class ServeEngine:
         sample = tr.begin("sample", tick) if tr is not None else None
         nxt = lg.argmax(axis=-1)
         for s in prefilling:
-            if not np.isfinite(lg[s]).all():
+            r = row_of[s]
+            if not np.isfinite(lg[r]).all():
                 self._quarantine(s, tick, "nonfinite_logits")
                 continue
             slot = self.slots[s]
-            slot.cursor += int(n_valid[s])
+            slot.cursor += int(n_valid[r])
             self.metrics.on_prefill_step(slot.rid)
             if self.tracer is not None:
                 self.tracer.event("prefill", tick, rid=slot.rid, slot=s,
@@ -1004,8 +1039,8 @@ class ServeEngine:
             if slot.cursor >= len(slot.prompt):
                 # the chunk containing the last prompt token yields the
                 # first generated token — TTFT lands here
-                self._finish_prefill(s, int(nxt[s]),
-                                     np.asarray(logits[s]), tick)
+                self._finish_prefill(s, int(nxt[r]),
+                                     _device_row(lg, r, logits.dtype), tick)
         if sample is not None:
             tr.end(sample)
         return 1
@@ -1070,7 +1105,8 @@ class ServeEngine:
                                       replay=slot.replay)
                 if slot.cursor >= len(slot.prompt):
                     self._finish_prefill(s, int(nxt[s]),
-                                         np.asarray(logits[s]), tick)
+                                         _device_row(lg, s, logits.dtype),
+                                         tick)
                 continue
             tok = int(nxt[s])
             self.outputs[slot.rid].append(tok)
@@ -1122,20 +1158,24 @@ class ServeEngine:
                     self.tracer.event("retry", self.tick_count, call=kind,
                                       attempt=attempt)
 
-    def _host_logits(self, logits, tick: int, call: str) -> np.ndarray:
-        """Host-side (B, V) f32 logits for argmax + the finite-guard;
-        the fault plan's nan_logits events poison rows here (the
-        corruption a real device would hand back). Blocks until the
-        step is done: the "logits" span."""
+    def _host_logits(self, logits, tick: int, call: str,
+                     row_of: Optional[Dict[int, int]] = None) -> np.ndarray:
+        """Host-side (rows, V) f32 logits for argmax + the finite-guard;
+        the fault plan's nan_logits events poison the rows of their
+        slots here (the corruption a real device would hand back).
+        row_of maps a slot to its row (None: row s is slot s). Blocks
+        until the step is done: the "logits" span."""
         span = (self.tracer.begin("logits", tick)
                 if self.tracer is not None else None)
         lg = np.asarray(logits[:, 0, :], np.float32)
         if self.fault_plan is not None:
             bad = self.fault_plan.logit_slots(tick, call)
+            if row_of is not None:
+                bad = [row_of[s] for s in bad if s in row_of]
             if bad:
                 lg = lg.copy()
-                for s in bad:
-                    lg[s] = np.nan
+                for r in bad:
+                    lg[r] = np.nan
         if span is not None:
             self.tracer.end(span)
         return lg

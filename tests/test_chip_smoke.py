@@ -28,7 +28,8 @@ def test_served_path_check_at_reduced_preset():
     assert s["n_completed"] == s["n_requests"] == 4
     assert s["n_faults"] == s["n_shed"] == s["n_rejected"] == 0
     assert set(rep["compiles"]) == {"decode@stablelm-smoke",
-                                    "prefill_chunk_exact@stablelm-smoke",
+                                    "prefill_chunk_exact@stablelm-smoke/1",
+                                    "prefill_chunk_exact@stablelm-smoke/2",
                                     "reset@stablelm-smoke"}
     assert all(c == 1 for c in rep["compiles"].values())
     assert rep["prefill_rel_err"] <= smoke.LOGITS_REL_TOL

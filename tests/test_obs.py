@@ -178,11 +178,15 @@ def test_call_span_covers_its_logits_and_sample_follows(traced):
 
 def test_prefill_rows_valid_add_up_to_prompt_tokens(traced):
     """The prefill calls' rows_valid count every prompt token served
-    once; each chunk computes n_slots x chunk rows."""
+    once; each chunk computes bucket x chunk rows, its bucket the
+    smallest that holds the call's prefilling slots."""
     engine, tracer = traced
     pre = [c["attrs"] for c in _spans(tracer, "call")
            if c["attrs"]["phase"] == "prefill"]
-    assert pre and all(a["rows"] == N_SLOTS * CHUNK for a in pre)
+    assert pre and all(a["rows"] == a["bucket"] * CHUNK for a in pre)
+    assert all(a["bucket"] == min(b for b in engine._prefill
+                                  if b >= len(a["participants"]))
+               for a in pre)
     assert sum(a["rows_valid"] for a in pre) == \
         sum(len(r.prompt) for r in _requests())
     decode = [c["attrs"] for c in _spans(tracer, "call")

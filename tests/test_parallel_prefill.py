@@ -198,12 +198,13 @@ def test_parallel_chunk_reads_projections_once():
     cache["pos"] = jnp.zeros((B,), jnp.int32)
     toks = jnp.zeros((B, C), jnp.int32)
     nv = jnp.full((B,), C, jnp.int32)
+    slots = jnp.arange(B, dtype=jnp.int32)
     par_fn, _ = build_prefill_chunk_step(cfg, mesh)
     ex_fn, _ = build_prefill_chunk_step(cfg.scaled(prefill_exact=True),
                                         mesh)
     kinds = analyze_call_kinds({
-        par_fn.call_kind: (par_fn, (params, tables, cache, toks, nv)),
-        ex_fn.call_kind: (ex_fn, (params, tables, cache, toks, nv))},
+        par_fn.call_kind: (par_fn, (params, tables, cache, toks, nv, slots)),
+        ex_fn.call_kind: (ex_fn, (params, tables, cache, toks, nv, slots))},
         weight_argnums=(0, 1))
     par = kinds["prefill_parallel"]["weight_bytes"]
     ex = kinds["prefill_chunk_exact"]["weight_bytes"]

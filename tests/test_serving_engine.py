@@ -11,6 +11,7 @@ import pytest
 from repro.configs import get_config
 from repro.launch.steps import build_step
 from repro.models import decode_chunk, init_cache, init_params, reset_slots
+from repro.models.transformer import encode
 from repro.obs import Tracer
 from repro.serving import (Request, ServeEngine, WorkloadSpec, assemble_chunk,
                            make_trace)
@@ -338,10 +339,16 @@ def test_engine_rejects_oversized_requests():
 def test_assemble_chunk_ragged():
     prompts = {0: np.arange(1, 6, dtype=np.int32),       # 5 tokens
                2: np.arange(10, 13, dtype=np.int32)}     # 3 tokens
-    tokens, n_valid = assemble_chunk(prompts, {0: 4, 2: 0}, 3, 4)
+    tokens, n_valid, slots = assemble_chunk(prompts, {0: 4, 2: 0}, 3, 4)
     assert tokens.shape == (3, 4) and n_valid.tolist() == [1, 0, 3]
     assert tokens[0, 0] == 5 and tokens[2, :3].tolist() == [10, 11, 12]
-    assert not tokens[1].any()
+    assert not tokens[1].any() and slots.tolist() == [0, 1, 2]
+    # a smaller bucket holds the prefilling slots in order, then padding
+    # rows with the out-of-range slot index and no tokens
+    tokens, n_valid, slots = assemble_chunk(prompts, {0: 4, 2: 0}, 8, 4, 4)
+    assert slots.tolist() == [0, 2, 8, 8] and n_valid.tolist() == [1, 3, 0, 0]
+    assert tokens[0, 0] == 5 and tokens[1, :3].tolist() == [10, 11, 12]
+    assert not tokens[2:].any()
 
 
 # ------------------------------------------------------------- serve CLI --
@@ -471,3 +478,186 @@ def test_decode_spans_count_slots_written():
     for a in dec:
         assert a["slots_written"] == len(a["participants"]) > 0
     assert sum(a["slots_written"] for a in dec) < 3 * len(dec)
+
+
+# ---------------------------------------------- compact chunk row buckets --
+
+N_SLOTS, MAX_LEN, CHUNK, PAGE = 8, 16, 4, 4
+
+COMPACT_CASES = {
+    "dense": lambda: _cfg("tinyllama-1.1b"),
+    "paged": lambda: _cfg("tinyllama-1.1b"),
+    "ssm-parallel": lambda: _cfg("mamba2-1.3b"),
+    "ssm-exact": lambda: _cfg("mamba2-1.3b", prefill_exact=True),
+    # 4 experts, top-2: a capacity factor of 4 covers every assignment
+    # at every bucket, so no row's experts depend on the other rows
+    "moe": lambda: _cfg("arctic-480b", capacity_factor=4.0),
+    # SSM, attention and MoE segments in one cache, the first an SSM's
+    "hybrid": lambda: _cfg("jamba-v0.1-52b", capacity_factor=4.0),
+    # a decoder whose cross-attention reads each slot's own encoder output
+    "encdec": lambda: _cfg("whisper-base"),
+}
+#: cases whose chunk runs the parallel SSD form: equal within its
+#: tolerance, not bitwise
+PARALLEL_SSD = ("ssm-parallel", "hybrid")
+
+
+@pytest.fixture(scope="module")
+def compact_setups():
+    """Per case: config, params, the jitted chunk step and a cache in
+    which every slot holds a first chunk of its own length (0-4)."""
+    built = {}
+
+    def get(case):
+        if case in built:
+            return built[case]
+        cfg = COMPACT_CASES[case]()
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        paged = case == "paged"
+        ptab = None
+        if paged:
+            n_pages = N_SLOTS * MAX_LEN // PAGE
+            cache = init_cache(cfg, N_SLOTS, MAX_LEN, n_pages=n_pages,
+                               page_size=PAGE)
+            perm = np.random.default_rng(3).permutation(n_pages)
+            ptab = jnp.asarray(perm.reshape(N_SLOTS, -1).astype(np.int32))
+        elif cfg.is_encdec:
+            frames = jax.random.normal(
+                jax.random.PRNGKey(5), (N_SLOTS, cfg.encoder_seq,
+                                        cfg.d_model), jnp.float32)
+            cache = init_cache(cfg, N_SLOTS, MAX_LEN,
+                               enc_out=encode(params, frames, cfg))
+        else:
+            cache = init_cache(cfg, N_SLOTS, MAX_LEN)
+        cache["pos"] = jnp.zeros((N_SLOTS,), jnp.int32)
+        if "pos" in cache.get("attn", {}):
+            cache["attn"]["pos"] = jnp.zeros((N_SLOTS,), jnp.int32)
+        step = jax.jit(build_step(cfg, None, "prefill_chunk",
+                                  paged=paged)[0])
+        extra = () if ptab is None else (ptab,)
+        rng = np.random.default_rng(9)
+        first = {s: rng.integers(1, cfg.vocab_size, s % (CHUNK + 1))
+                 for s in range(N_SLOTS)}
+        args = assemble_chunk(first, dict.fromkeys(first, 0), N_SLOTS, CHUNK)
+        _, cache = step(params, None, cache, *map(jnp.asarray, args), *extra)
+        built[case] = (cfg, params, step, cache, extra)
+        return built[case]
+
+    return get
+
+
+def _slot_rows(cache, s, extra):
+    """Every cache array of slot s: its position, its K/V rows (or, paged,
+    the pages its table row names), its SSM conv and state and its
+    encoder output."""
+    out = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+        key = jax.tree_util.keystr(path)
+        leaf = np.asarray(leaf)
+        if leaf.ndim == 1 or key == "['enc_out']":
+            out[key] = leaf[s]
+        elif key.endswith("['pk']") or key.endswith("['pv']"):
+            out[key] = leaf[:, np.asarray(extra[0][s])]
+        else:
+            out[key] = leaf[:, s]
+    return out
+
+
+@pytest.mark.parametrize("case", sorted(COMPACT_CASES))
+@pytest.mark.parametrize("prefilling,rows", [
+    ((5,), 1), ((2, 7), 2), ((0, 3, 4, 6), 4),
+    ((1, 4, 6), 4)])                        # padded: 3 slots in a 4-bucket
+def test_compact_chunk_rows_match_the_whole_batch(compact_setups, case,
+                                                  prefilling, rows):
+    """A chunk on a row bucket smaller than the batch computes, for each
+    slot it names, the logits and cache of the whole-batch chunk (today's
+    call): bitwise for attention (cross-attention to each slot's own
+    encoder output included), MoE and the exact SSM recurrence, within
+    the parallel SSD form's tolerance (the SSM and the hybrid stack).
+    Every other slot (the one padding rows read included) comes out
+    bitwise untouched."""
+    if case == "ssm-exact" and rows == 1:
+        # chunk_buckets leaves this bucket out (one-row projections);
+        # one slot then runs padded in the 2-row bucket
+        rows = 2
+    from repro.models.ssm import PARALLEL_PREFILL_ATOL
+    cfg, params, step, cache, extra = compact_setups(case)
+    rng = np.random.default_rng(11)
+    prompts = {s: rng.integers(1, cfg.vocab_size, 2 + s % 3)
+               for s in prefilling}
+    cursors = dict.fromkeys(prompts, 0)
+    full = assemble_chunk(prompts, cursors, N_SLOTS, CHUNK)
+    compact = assemble_chunk(prompts, cursors, N_SLOTS, CHUNK, rows)
+    lf, cf = step(params, None, cache, *map(jnp.asarray, full), *extra)
+    lc, cc = step(params, None, cache, *map(jnp.asarray, compact), *extra)
+    assert lc.shape == (rows, 1, cfg.vocab_size)
+    if case in PARALLEL_SSD:
+        same = lambda a, b: np.testing.assert_allclose(
+            a, b, rtol=0, atol=PARALLEL_PREFILL_ATOL["float32"])
+    else:
+        same = np.testing.assert_array_equal
+    slots = compact[2].tolist()
+    assert slots[len(prefilling):] == [N_SLOTS] * (rows - len(prefilling))
+    for r, s in enumerate(slots[:len(prefilling)]):
+        same(np.asarray(lc[r]), np.asarray(lf[s]))
+    for s in range(N_SLOTS):
+        got = _slot_rows(cc, s, extra)
+        want = _slot_rows(cf if s in prompts else cache, s, extra)
+        for key in want:
+            if s in prompts:
+                same(got[key], want[key])
+            else:
+                np.testing.assert_array_equal(got[key], want[key], key)
+
+
+def test_chunk_buckets_follow_slots_and_moe_capacity():
+    """Powers of two below n_slots, then n_slots; a MoE configuration
+    whose expert capacity at some bucket drops assignments keeps the
+    whole batch alone, and no bucket leaves a projection one row."""
+    from repro.serving.prefill import chunk_buckets
+    dense = _cfg("tinyllama-1.1b")
+    assert chunk_buckets(dense, 16, 4) == (1, 2, 4, 8, 16)
+    assert chunk_buckets(dense, 6, 4) == (1, 2, 4, 6)
+    assert chunk_buckets(dense, 1, 4) == (1,)
+    assert chunk_buckets(dense, 4, 1) == (2, 4)   # one token a row
+    moe = _cfg("arctic-480b")                     # E=4, top-2, factor 1.25
+    assert chunk_buckets(moe, 4, 4) == (1, 2, 4)  # capacity covers all
+    assert chunk_buckets(moe, 8, 4) == (8,)       # 8 slots of 16 pairs
+    assert chunk_buckets(moe.scaled(capacity_factor=4.0), 8, 4) == \
+        (1, 2, 4, 8)
+    ssm = _cfg("mamba2-1.3b")
+    assert chunk_buckets(ssm, 8, 4) == (1, 2, 4, 8)
+    # the exact recurrence projects one token of each row at a time
+    assert chunk_buckets(ssm.scaled(prefill_exact=True), 8, 4) == (2, 4, 8)
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_engine_row_buckets_serve_the_same_tokens(arch):
+    """Eight requests whose prompts take 1-8 chunks, all due at once:
+    8, 7, ..., 1 slots prefill together, so the chunks run on every
+    bucket. Every bucket compiled once, at construction; the tokens are
+    those of stepwise prefill through the decode step."""
+    cfg = _exact(_cfg(arch))
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(12)
+    trace = [Request(rid=i, prompt=tuple(int(t) for t in rng.integers(
+                 1, cfg.vocab_size, CHUNK * (i + 1))), gen_len=3, arrival=0)
+             for i in range(8)]
+    tracer = Tracer(arch=cfg.name)
+    eng = ServeEngine(cfg, params, n_slots=8, max_len=40,
+                      prefill_chunk=CHUNK, tracer=tracer)
+    built = {k: n for k, n in eng.sentinel.counts().items()
+             if k.startswith(eng.prefill_kind)}
+    built_rows = sorted(int(k.rsplit("/", 1)[1]) for k in built)
+    # the exact SSM recurrence has no 1-row bucket (chunk_buckets)
+    assert built_rows == ([2, 4, 8] if cfg.family == "ssm" else [1, 2, 4, 8])
+    assert set(built.values()) == {1}
+    out = eng.run(trace)
+    assert {k: eng.sentinel.counts()[k] for k in built} == built
+    used = [r["attrs"]["bucket"] for r in tracer.records
+            if r.get("type") == "span" and r["name"] == "call"
+            and r["attrs"]["phase"] == "prefill"]
+    assert used == [8, 8, 8, 8, 4, 4, 2, min(built_rows)]
+    ref = ServeEngine(cfg, params, n_slots=8, max_len=40,
+                      prefill_chunk=CHUNK, prefill_mode="full").run(trace)
+    assert out == ref
