@@ -12,7 +12,6 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.configs.paper_cnns import joint_bench_shapes
-from repro.core import pruning
 from repro.kernels import ops, ref
 from .common import emit, timed
 
@@ -186,31 +185,8 @@ def run(smoke: bool = False):
     rows = []
     rng = np.random.default_rng(0)
 
-    # block-sparse: HBM bytes scale with survival
-    M, K, N = (128, 256, 256) if smoke else (256, 1024, 256)
-    x = jnp.asarray(rng.normal(0, 1, (M, K)), jnp.float32)
-    w = rng.normal(0, 1, (K, N)).astype(np.float32)
-    for sp in ((0.5,) if smoke else (0.0, 0.5, 0.75)):
-        mask = _tile_mask(rng, K, N, sp)
-        w_blocks, idx = ops.pack_block_sparse(w * mask,
-                                              np.ones_like(w, np.int32))
-        (y,), us = timed(lambda: (ops.sparse_dense(x, w_blocks, idx),))
-        dense_bytes = w.nbytes
-        stored = w_blocks.size * 4
-        rows.append((f"kernel.block_sparse.s{int(sp*100)}", us,
-                     f"weight_bytes={stored} vs dense={dense_bytes} "
-                     f"({stored/dense_bytes:.2f}x)"))
-
-    # fta int8: 2x weight traffic vs bf16, 4x vs f32
-    Kq = 512 if smoke else 1024
-    wq = jnp.asarray(rng.integers(-127, 128, (Kq, 256)), jnp.int8)
-    scales = jnp.asarray(rng.uniform(0.005, 0.02, (1, 256)), jnp.float32)
-    xb = jnp.asarray(rng.normal(0, 1, (M, Kq)), jnp.bfloat16)
-    (y,), us = timed(lambda: (ops.fta_dense(xb, wq, scales),))
-    rows.append(("kernel.fta_int8", us,
-                 f"weight_bytes={wq.size} vs bf16={wq.size*2} (0.50x)"))
-
-    # joint value x bit: the paper's headline configuration
+    # joint value x bit: the paper's headline configuration, beside the
+    # dense / value-only / bit-only weight bytes of the same shapes
     _joint_cases(rows, smoke)
 
     # stacked joint pack driven through a scan — the serving layout

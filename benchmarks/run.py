@@ -1,5 +1,5 @@
 """Benchmark harness: one module per paper table/figure + kernel micro-
-benchmarks + the roofline table. Prints ``name,us_per_call,derived`` CSV."""
+benchmarks. Prints ``name,us_per_call,derived`` CSV."""
 
 from __future__ import annotations
 
@@ -16,7 +16,6 @@ MODULES = [
     "benchmarks.tab2_comparison",
     "benchmarks.tab3_exec_time",
     "benchmarks.kernel_bench",
-    "benchmarks.roofline_table",
 ]
 
 

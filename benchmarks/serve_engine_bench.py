@@ -241,7 +241,7 @@ def bench_arch(arch: str) -> dict:
     # policies: "chunked" is the arch's default chunk math (parallel SSD
     # for SSM, exact for attention); SSM adds the exact-chunk fallback.
     policies = {"chunked": cfg, "full": cfg}
-    if cfg.supports_parallel_prefill:
+    if cfg.serving_capabilities().parallel_prefill:
         policies = {"chunked": cfg,
                     "chunked_exact": cfg.scaled(prefill_exact=True),
                     "full": cfg}
@@ -314,7 +314,7 @@ def bench_arch(arch: str) -> dict:
                         if k not in ("outputs", "first_logits")}
 
     # guard 4 (SSM only): parallel-form equivalence + traffic contract
-    if cfg.supports_parallel_prefill:
+    if cfg.serving_capabilities().parallel_prefill:
         atol = PARALLEL_PREFILL_ATOL[cfg.dtype]
         dmax = 0.0
         for rid, lg in runs["full"]["first_logits"].items():
@@ -554,7 +554,7 @@ def bench_restart(arch: str = "tinyllama-1.1b",
     tells the run's whole story — is copied to ``journal_out``.
     """
     cfg = get_config(arch, reduced=True, dbpim_mode="joint")
-    if cfg.supports_parallel_prefill:
+    if cfg.serving_capabilities().parallel_prefill:
         # restart re-prefill must be BITWISE, so the SSM serves exact
         # per-token chunks (the parallel form is tolerance-equivalent)
         cfg = cfg.scaled(prefill_exact=True)
@@ -661,7 +661,7 @@ def bench_restart(arch: str = "tinyllama-1.1b",
     return {
         "arch": cfg.name, "n_slots": N_SLOTS, "max_len": MAX_LEN,
         "prefill_chunk": PREFILL_CHUNK,
-        "prefill_exact": bool(cfg.supports_parallel_prefill),
+        "prefill_exact": bool(cfg.serving_capabilities().parallel_prefill),
         "snapshot_every": RESTART_SNAPSHOT_EVERY,
         "workload": {"n_requests": RESTART_SPEC.n_requests,
                      "arrival_rate": RESTART_SPEC.arrival_rate,

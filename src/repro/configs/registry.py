@@ -5,6 +5,8 @@ from __future__ import annotations
 import importlib
 from typing import Dict, List
 
+from repro.models.config import KERNEL_MODES
+
 ARCHS: List[str] = [
     "qwen3-8b", "tinyllama-1.1b", "gemma-7b", "stablelm-1.6b",
     "arctic-480b", "mixtral-8x7b", "mamba2-1.3b", "pixtral-12b",
@@ -15,24 +17,16 @@ _MODULES: Dict[str, str] = {a: a.replace("-", "_").replace(".", "_")
                             for a in ARCHS}
 
 
-#: DB-PIM kernel modes selectable per config (mirrors
-#: sparsity.sparse_linear.KERNEL_MODES; kept literal so the registry
-#: stays import-light).
-DBPIM_MODES = ("dense", "value", "bit", "joint")
-
-
 def get_config(arch: str, reduced: bool = False,
                dbpim_mode: str = None, prefill_exact: bool = None):
     """Load the ModelConfig for `arch`. reduced=True returns the small
     smoke-test variant of the same family. dbpim_mode selects the DB-PIM
-    kernel path ("dense" | "value" | "bit" | "joint") the serving stack
+    kernel mode (one of models.config.KERNEL_MODES) the serving stack
     packs for: launch.serve builds uniform-MAXB stacked tables
     (sparsity.sparse_linear.build_stacked_tables) and threads them
-    through the scanned layer stacks, so "joint"/"bit" (INT8/FTA
+    through every family's segment scans, so "joint"/"bit" (INT8/FTA
     payload) and "value" (bf16 payload, value level only) change the
-    compiled serving HLO end-to-end (dense-attention and SSM families;
-    per-layer hooks via build_kernel_tables -> models.layers.make_matmul
-    remain for the others). prefill_exact=True forces SSM chunked
+    compiled serving HLO end to end. prefill_exact=True forces SSM chunked
     prefill onto the exact per-token recurrence (bit-identical to
     decode, C x the projection traffic) instead of the default parallel
     SSD form (one stacked-weight read per chunk, tolerance-equivalent —
@@ -42,9 +36,9 @@ def get_config(arch: str, reduced: bool = False,
     mod = importlib.import_module(f"repro.configs.{_MODULES[arch]}")
     cfg = mod.reduced_config() if reduced else mod.config()
     if dbpim_mode is not None:
-        if dbpim_mode not in DBPIM_MODES:
+        if dbpim_mode not in KERNEL_MODES:
             raise KeyError(f"unknown dbpim_mode {dbpim_mode!r}; "
-                           f"choose from {DBPIM_MODES}")
+                           f"choose from {KERNEL_MODES}")
         cfg = cfg.scaled(dbpim=True, dbpim_mode=dbpim_mode)
     if prefill_exact is not None:
         cfg = cfg.scaled(prefill_exact=prefill_exact)

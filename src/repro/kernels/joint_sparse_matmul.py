@@ -5,14 +5,13 @@ bit sparsity are exploited on the SAME layer, in one pass. The weight
 operand is simultaneously
 
   * COMPACTED (value level): for every N-column tile only its surviving
-    K-blocks are stored, exactly like ``block_sparse_matmul`` — the pruned
-    1 x alpha blocks of the paper's sparse allocation network become
-    MXU-tile-granular skipped blocks, so HBM weight traffic and MXU work
-    scale with (1 - value_sparsity);
+    K-blocks are stored — the pruned 1 x alpha blocks of the paper's
+    sparse allocation network become MXU-tile-granular skipped blocks, so
+    HBM weight traffic and MXU work scale with (1 - value_sparsity);
   * QUANTIZED (bit level): the surviving block payload is INT8 (the FTA
     projection makes the weights exactly representable as INT8 x one
-    per-filter scale, as in ``fta_int8_matmul``), so each surviving byte
-    is 2x cheaper than bf16 and 4x cheaper than f32.
+    per-filter scale), so each surviving byte is 2x cheaper than bf16 and
+    4x cheaper than f32.
 
 Net weight traffic: ``(1 - value_sparsity) * 0.5`` of dense bf16.
 

@@ -1,72 +1,17 @@
-"""jit'd wrappers + packing utilities for the Pallas kernels."""
+"""Packing utilities and the jit'd wrapper for the joint kernel, plus the
+bit-true DBMU reference entry point."""
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import dyadic, fta, pruning, qat
-from .block_sparse_matmul import BK, BN, block_sparse_matmul
+from repro.core import fta
 from .dbmu_sim import dbmu_matmul
-from .fta_int8_matmul import fta_int8_matmul
-from .joint_sparse_matmul import BM as JBM, joint_sparse_matmul
+from .joint_sparse_matmul import BK, BM as JBM, BN, joint_sparse_matmul
 
-
-def pack_block_sparse(w_dense: np.ndarray, mask: np.ndarray,
-                      bk: int = BK, bn: int = BN):
-    """Compact a masked weight matrix into gathered K-blocks per N tile.
-
-    Returns (w_blocks (NT, MAXB, bk, bn), idx (NT, MAXB) int32). A K-block
-    survives for an N tile iff any weight in the (bk, bn) tile is kept.
-    MAXB = max surviving blocks over tiles (zero-padded elsewhere).
-    """
-    w = np.asarray(w_dense) * np.asarray(mask)
-    K, N = w.shape
-    assert K % bk == 0 and N % bn == 0
-    kt, nt = K // bk, N // bn
-    tiles = w.reshape(kt, bk, nt, bn)
-    alive = np.abs(tiles).sum(axis=(1, 3)) > 0          # (kt, nt)
-    maxb = max(int(alive.sum(axis=0).max()), 1)
-    w_blocks = np.zeros((nt, maxb, bk, bn), w.dtype)
-    idx = np.zeros((nt, maxb), np.int32)
-    for n in range(nt):
-        rows = np.nonzero(alive[:, n])[0]
-        for b, kblk in enumerate(rows):
-            w_blocks[n, b] = tiles[kblk, :, n, :]
-            idx[n, b] = kblk
-    return jnp.asarray(w_blocks), jnp.asarray(idx)
-
-
-def sparse_dense(x, w_blocks, idx, interpret: bool = None):
-    """Public op: block-sparse y = x @ W for 2D/3D activations."""
-    shape = x.shape
-    x2 = x.reshape(-1, shape[-1])
-    y = block_sparse_matmul(x2, w_blocks, idx, interpret=interpret)
-    return y.reshape(shape[:-1] + (y.shape[-1],))
-
-
-def fta_pack(w: jnp.ndarray, mask, value_sparsity: float = 0.0):
-    """Full DB-PIM weight compilation: block prune -> FTA quantize ->
-    (int8 qweights, scale, packed dyadic terms)."""
-    scale = jnp.max(jnp.abs(w)) / 127.0
-    q = qat.quantize_int8(w, scale)
-    q_fta, phi = fta.fta_quantize(q, mask)
-    packed = dyadic.pack_terms(np.asarray(q_fta))
-    return q_fta.astype(jnp.int8), scale, packed, phi
-
-
-def fta_dense(x, w_q, scales, interpret: bool = None):
-    """Public op: y = x @ (int8 FTA weights x per-filter scales)."""
-    shape = x.shape
-    x2 = x.reshape(-1, shape[-1])
-    y = fta_int8_matmul(x2, w_q, scales, interpret=interpret)
-    return y.reshape(shape[:-1] + (y.shape[-1],))
-
-
-# ------------------------------------------------- joint value x bit -------
 
 def random_tile_mask(rng, K: int, N: int, sparsity: float,
                      tile: int = 128) -> np.ndarray:
@@ -82,41 +27,18 @@ def random_tile_mask(rng, K: int, N: int, sparsity: float,
     return full[:K, :N].astype(np.int32)
 
 
-def tile_prune_mask(w: np.ndarray, value_sparsity: float,
-                    bk: int = BK, bn: int = BN) -> np.ndarray:
-    """TPU-granular value pruning: drop the lowest-L2 (bk, bn) weight
-    tiles at the target ratio (ceil + crop for ragged shapes).
-
-    This is the MXU mapping of the paper's 1 x alpha sparse allocation
-    network: the unit the joint/value kernels can actually SKIP is a
-    whole weight tile, so pruning for the kernel path must happen at
-    tile granularity — finer 1 x alpha pruning (core.pruning, used for
-    the accuracy experiments) essentially never empties a full tile and
-    would leave the packed layout dense. At least one tile survives.
-    """
-    K, N = w.shape
-    kt, nt = -(-K // bk), -(-N // bn)
-    wp = np.zeros((kt * bk, nt * bn), np.float32)
-    wp[:K, :N] = w
-    norms = (wp.reshape(kt, bk, nt, bn) ** 2).sum(axis=(1, 3))   # (kt, nt)
-    alive = np.ones((kt, nt), bool)
-    n_drop = min(int(round(value_sparsity * kt * nt)), kt * nt - 1)
-    if n_drop > 0:
-        order = np.argsort(norms, axis=None)                     # ascending
-        alive.flat[order[:n_drop]] = False
-    full = np.repeat(np.repeat(alive, bk, 0), bn, 1)[:K, :N]
-    return full.astype(np.int32)
-
-
 def tile_prune_mask_balanced(w: np.ndarray, value_sparsity: float,
                              bk: int = BK, bn: int = BN) -> np.ndarray:
     """Column-balanced tile pruning: drop the lowest-L2 ``round(vs * kt)``
     K-tiles in EVERY N-tile column (ceil + crop for ragged shapes).
 
-    Unlike ``tile_prune_mask`` (global lowest-norm tiles, variable
-    survivors per column), every column keeps exactly the same number of
-    K-blocks — so MAXB == the survivor count, the packed layout carries
-    ZERO padded slots, and a whole layer stack packs to one shared MAXB.
+    This is the MXU mapping of the paper's 1 x alpha sparse allocation
+    network: the unit the joint kernel can SKIP is a whole weight tile,
+    and finer 1 x alpha pruning (core.pruning, used for the accuracy
+    experiments) essentially never empties one. Every column keeps
+    exactly the same number of K-blocks — so MAXB == the survivor count,
+    the packed layout carries ZERO padded slots, and a whole layer stack
+    packs to one shared MAXB.
     This is the uniformity SparseP-style PIM serving needs: stored bytes
     equal ``(1 - vs)`` of dense exactly, per layer, per column.
     """
@@ -233,16 +155,17 @@ def pack_joint_sparse(w_dense, mask=None, *, bk: int = BK, bn: int = BN,
 
     A K-block survives for an N tile iff the (bk, bn) mask tile keeps any
     weight. When no mask is given and value_sparsity is set, pruning
-    happens at (bk, bn) TILE granularity (tile_prune_mask) — the unit the
-    kernel can skip. Surviving payload is INT8 on the per-filter-scale
-    grid (FTA projection keeps it exactly representable); K and N are
-    zero-padded to the tile size, so odd shapes pack fine.
+    happens at (bk, bn) TILE granularity (tile_prune_mask_balanced) — the
+    unit the kernel can skip. Surviving payload is INT8 on the
+    per-filter-scale grid (FTA projection keeps it exactly
+    representable); K and N are zero-padded to the tile size, so odd
+    shapes pack fine.
     """
     w = np.asarray(w_dense, np.float32)
     K, N = w.shape
     if mask is None:
-        m = (tile_prune_mask(w, value_sparsity, bk, bn) if value_sparsity
-             else np.ones_like(w, np.int32))
+        m = (tile_prune_mask_balanced(w, value_sparsity, bk, bn)
+             if value_sparsity else np.ones_like(w, np.int32))
     else:
         m = np.asarray(mask, np.int32)
     w_blocks, idx, nblocks, scales, Kp, _ = _quantize_and_compact(

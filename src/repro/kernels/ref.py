@@ -8,17 +8,6 @@ import numpy as np
 from repro.core.dyadic import unpack_terms
 
 
-def block_sparse_matmul_ref(x, w_dense, mask):
-    """Oracle for block_sparse_matmul: dense matmul with the pruned W."""
-    return x @ (w_dense * mask.astype(w_dense.dtype))
-
-
-def fta_int8_matmul_ref(x, w_q, scales, out_dtype=jnp.bfloat16):
-    """Oracle for fta_int8_matmul."""
-    w = w_q.astype(jnp.float32) * scales.astype(jnp.float32)
-    return (x.astype(jnp.float32) @ w).astype(out_dtype)
-
-
 def joint_sparse_matmul_ref(x, q_dense, mask, scales,
                             out_dtype=jnp.float32):
     """Oracle for joint_sparse_matmul: dense matmul against the pruned,

@@ -1,9 +1,8 @@
 """Production meshes.
 
 Defined as FUNCTIONS so importing this module never touches jax device
-state. The dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512
-before any jax import; smoke tests and benchmarks see the real single
-device and use `make_test_mesh`.
+state. `make_production_mesh` needs 256 devices (512 multi-pod); smoke
+tests and benchmarks see the real single device and use `make_test_mesh`.
 
 Every axis is ``AxisType.Auto``: the sharding rules in runtime.sharding
 hand specs to jit and let the compiler propagate the rest, which explicit

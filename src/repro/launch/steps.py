@@ -1,11 +1,9 @@
-"""jit-able production steps: train (grad-accumulation + AdamW + schedule),
-prefill, and decode — with explicit in/out shardings for a given mesh.
+"""jit-able production steps: train (grad-accumulation + AdamW + schedule)
+and the serving engine's decode and chunked-prefill steps — with explicit
+in/out shardings for a given mesh.
 """
 
 from __future__ import annotations
-
-import functools
-from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -13,8 +11,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models import decode_chunk, decode_step, loss_fn, merge_slots
 from repro.models.config import ModelConfig
-from repro.optim import AdamWState, adamw_init, adamw_update, \
-    cosine_with_warmup
+from repro.optim import AdamWState, adamw_update, cosine_with_warmup
 from repro.runtime import sharding as shr
 
 
@@ -107,7 +104,7 @@ def _moment_specs(params, pspecs, moments, mesh):
     return treedef.unflatten(out)
 
 
-SERVE_CALL_KINDS = ("serve", "decode", "prefill_chunk")
+SERVE_CALL_KINDS = ("decode", "prefill_chunk")
 
 #: Call-kind tag suffix for RECOVERY traffic: the serving engine reuses
 #: the compiled prefill executables for recovery-by-replay re-prefills
@@ -129,7 +126,7 @@ RESTORE_TAG = "+restore"
 
 
 def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
-               int8_weights: bool = False, paged: bool = False):
+               paged: bool = False):
     """One entry point for every fixed-shape serving step. Returns
     (step_fn, shardings_fn); step_fn carries a ``call_kind`` tag that
     runtime.jaxpr_cost.analyze_call_kinds and the serving engine consume
@@ -147,12 +144,6 @@ def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
 
     call_kind selects the step:
 
-      * "serve" — plain (B, 1) decode step, ``(params, tables, cache,
-        token)``. Tag "decode". int8_weights=True keeps projections in
-        HBM as INT8 + per-filter scale (the FTA/DB-PIM serving format),
-        dequantized in-graph so the dequant fuses into the matmuls —
-        halving decode weight traffic. Mutually exclusive with tables
-        (the tables carry their own payload).
       * "decode" — the serving engine's slot decode step,
         ``(params, tables, cache, token, active)``: inactive slots (free,
         draining, or mid-prefill while their neighbors decode) compute
@@ -188,37 +179,12 @@ def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
     per-call operand (never cache-resident), so page churn between ticks
     costs ZERO recompiles. As in the contiguous "decode" step,
     ``active`` is the attention write mask: inactive slots' writes are
-    dropped at the scatter. "serve" (lock-step, no allocator) stays
-    contiguous.
+    dropped at the scatter.
     """
     if call_kind not in SERVE_CALL_KINDS:
         raise ValueError(f"call_kind {call_kind!r} not in "
                          f"{SERVE_CALL_KINDS}")
-    if int8_weights and call_kind != "serve":
-        raise ValueError("int8_weights is a 'serve' step format")
-    if paged and call_kind == "serve":
-        raise ValueError("paged cache is a serving-engine format; the "
-                         "lock-step 'serve' step stays contiguous")
-
-    if call_kind == "serve":
-        def step_fn(params, tables, cache, token):
-            if int8_weights:
-                if tables is not None:
-                    raise ValueError("int8_weights and stacked tables are "
-                                     "mutually exclusive serving formats")
-                from repro.sparsity.sparse_linear import \
-                    dequant_params_for_serving
-                params = dequant_params_for_serving(params)
-            return decode_step(params, cache, token, cfg, tables=tables)
-        step_fn.call_kind = "decode"
-
-        def shardings(params, tables, cache, token):
-            pspec = _serving_param_specs(params, mesh)
-            cspec = shr.cache_specs(cache, cfg, mesh)
-            tspec = shr.batch_specs({"token": token}, mesh)["token"]
-            return pspec, _table_specs(tables), cspec, tspec
-
-    elif call_kind == "decode" and paged:
+    if call_kind == "decode" and paged:
         def step_fn(params, tables, cache, token, active, ptab):
             logits, new_cache = decode_step(params, cache, token, cfg,
                                             tables=tables,
@@ -294,22 +260,6 @@ def _chunk_kind(cfg: ModelConfig) -> str:
             else "prefill_chunk_exact")
 
 
-def build_serve_step(cfg: ModelConfig, mesh: Mesh,
-                     int8_weights: bool = False):
-    """Thin wrapper over build_step(call_kind="serve")."""
-    return build_step(cfg, mesh, "serve", int8_weights=int8_weights)
-
-
-def build_slot_decode_step(cfg: ModelConfig, mesh: Mesh):
-    """Thin wrapper over build_step(call_kind="decode")."""
-    return build_step(cfg, mesh, "decode")
-
-
-def build_prefill_chunk_step(cfg: ModelConfig, mesh: Mesh):
-    """Thin wrapper over build_step(call_kind="prefill_chunk")."""
-    return build_step(cfg, mesh, "prefill_chunk")
-
-
 def _table_specs(tables):
     # packed tables stay whole on every device: a tensor-parallel split
     # would have to shard each tile's compacted blocks and index table
@@ -328,18 +278,3 @@ def _serving_param_specs(params, mesh: Mesh):
     tp = mesh.shape.get("model", 1)
     fsdp = (pbytes / tp) > 12e9
     return shr.param_specs(params, mesh, fsdp=fsdp)
-
-
-def build_prefill_step(cfg: ModelConfig, mesh: Mesh):
-    from repro.models import prefill
-
-    def prefill_step(params, batch):
-        return prefill(params, batch["tokens"], cfg,
-                       frames=batch.get("frames"))
-
-    def shardings(params, batch):
-        return (shr.param_specs(params, mesh,
-                                fsdp=_needs_fsdp(params, mesh)),
-                shr.batch_specs(batch, mesh))
-
-    return prefill_step, shardings

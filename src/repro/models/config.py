@@ -1,13 +1,20 @@
 """Model configuration for the 10 assigned architectures.
 
 One frozen dataclass drives parameter creation, the forward pass, sharding
-rules, DB-PIM sparsity instrumentation, and the dry-run input specs.
+rules and DB-PIM sparsity instrumentation.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field, replace
-from typing import Optional, Tuple
+from dataclasses import dataclass, replace
+from typing import Optional
+
+#: Which sparsity level(s) the serving kernel exploits (``dbpim_mode``):
+#: "dense" plain matmuls, "value" tile-pruned bf16 payload, "bit" INT8/FTA
+#: payload at zero value sparsity, "joint" both — the paper's headline
+#: configuration. Every non-dense mode packs the same stacked layout for
+#: the one joint kernel (sparsity.sparse_linear.build_stacked_tables).
+KERNEL_MODES = ("dense", "value", "bit", "joint")
 
 
 @dataclass(frozen=True)
@@ -61,9 +68,7 @@ class ModelConfig:
     # DB-PIM integration
     dbpim: bool = False                   # FTA-quantized projections
     dbpim_value_sparsity: float = 0.6
-    dbpim_mode: str = "joint"             # dense | value | bit | joint:
-                                          # which sparsity level(s) the
-                                          # serving kernels exploit
+    dbpim_mode: str = "joint"             # one of KERNEL_MODES
 
     # serving prefill: False (default) lets SSM chunked prefill use the
     # parallel SSD form — one in/out projection read per chunk instead of
@@ -104,62 +109,12 @@ class ModelConfig:
     def serving_capabilities(self):
         """What the serving stack supports for this config, derived from
         the segment layout (models.segments.ServingCapabilities): segment
-        descriptors, packable projections, prefill modes. Single source
-        of truth — the supports_* properties below are thin shims over
-        it, kept for callers written against the old boolean API."""
+        descriptors, packable projections, prefill modes."""
         from .segments import serving_capabilities
         return serving_capabilities(self)
 
-    @property
-    def supports_stacked_tables(self) -> bool:
-        """Deprecated shim — use serving_capabilities().stacked_tables.
-        True for every family since the segmented per-kind layer scans:
-        each segment (attention / SSM / MoE / cross-attention run) packs
-        independently and rides its own scan, so hybrid periods and
-        enc-dec stacks serve through the joint kernel too."""
-        return self.serving_capabilities().stacked_tables
-
-    @property
-    def supports_chunked_prefill(self) -> bool:
-        """Deprecated shim — use serving_capabilities().chunked_prefill.
-        True whenever attention is full-causal (window == 0): sliding-
-        window ring buffers overwrite slots within a chunk, which only a
-        sequential walk reproduces. MoE chunks dispatch expert capacity
-        per chunk position (each position competes exactly like one
-        decode step's token pool), and hybrid / enc-dec chunks walk the
-        segment list — so those families chunk too."""
-        return self.serving_capabilities().chunked_prefill
-
-    @property
-    def supports_parallel_prefill(self) -> bool:
-        """Deprecated shim — use serving_capabilities().parallel_prefill.
-        True when an SSM segment exists (ssm / hybrid families): its
-        chunk can use the parallel SSD form, reading the stacked in/out
-        projections ONCE per chunk instead of per token
-        (models.ssm.prefill_ssm_parallel). Attention chunks already
-        project the whole chunk in one matmul."""
-        return self.serving_capabilities().parallel_prefill
-
     def scaled(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
-
-
-@dataclass(frozen=True)
-class ShapeSpec:
-    """One assigned input-shape cell."""
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str            # train | prefill | decode
-    microbatches: int = 1
-
-
-SHAPES = {
-    "train_4k": ShapeSpec("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeSpec("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeSpec("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeSpec("long_500k", 524288, 1, "decode"),
-}
 
 
 def param_count(cfg: ModelConfig) -> int:
@@ -205,18 +160,3 @@ def param_count(cfg: ModelConfig) -> int:
         per_layer += cfg.n_layers * (d * cfg.q_dim + 2 * d * cfg.kv_dim
                                      + cfg.q_dim * d)   # cross-attention
     return per_layer + emb + enc
-
-
-def active_param_count(cfg: ModelConfig) -> int:
-    """Parameters touched per token (MoE: top_k of n_experts)."""
-    if not cfg.n_experts:
-        return param_count(cfg)
-    full = param_count(cfg)
-    d, f = cfg.d_model, cfg.d_ff
-    mlp = (3 if cfg.mlp_type in ("swiglu", "geglu") else 2) * d * f
-    if cfg.family == "hybrid":
-        n_moe = cfg.n_layers // cfg.moe_every
-    else:
-        n_moe = cfg.n_layers
-    inactive = n_moe * (cfg.n_experts - cfg.top_k) * mlp
-    return full - inactive

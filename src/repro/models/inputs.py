@@ -1,38 +1,14 @@
-"""Input specs: ShapeDtypeStruct stand-ins for the dry-run (no allocation)
-and concrete random batches for smoke tests / examples.
-
-`decode_*` shapes feed `serve_step` (one new token against a cache of
-seq_len); `train_*`/`prefill_*` feed full-sequence steps.
-"""
+"""Concrete random batches for smoke tests / examples: a training batch
+(tokens, labels and any frontend input the family needs) and one decode
+token per row."""
 
 from __future__ import annotations
 
-from typing import Dict
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .config import ModelConfig, ShapeSpec
+from .config import ModelConfig
 from .layers import dtype_of
-
-
-def train_batch_spec(cfg: ModelConfig, batch: int, seq: int) -> Dict:
-    spec = {
-        "tokens": jax.ShapeDtypeStruct((batch, seq), jnp.int32),
-        "labels": jax.ShapeDtypeStruct((batch, seq), jnp.int32),
-    }
-    if cfg.is_encdec:
-        spec["frames"] = jax.ShapeDtypeStruct(
-            (batch, cfg.encoder_seq, cfg.d_model), dtype_of(cfg))
-    if cfg.frontend == "vision_stub":
-        spec["frontend"] = jax.ShapeDtypeStruct(
-            (batch, cfg.n_patches, cfg.d_model), dtype_of(cfg))
-    return spec
-
-
-def decode_token_spec(cfg: ModelConfig, batch: int) -> Dict:
-    return {"token": jax.ShapeDtypeStruct((batch, 1), jnp.int32)}
 
 
 def make_train_batch(cfg: ModelConfig, batch: int, seq: int, seed: int = 0):

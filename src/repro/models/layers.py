@@ -2,7 +2,7 @@
 
 Everything is a pure function over explicit param pytrees (no framework).
 Params are created by `init_*` functions that only use jax.random — they
-can run under `jax.eval_shape` for the allocation-free dry-run.
+can run under `jax.eval_shape` for allocation-free shape inference.
 """
 
 from __future__ import annotations
@@ -84,30 +84,6 @@ def init_mlp(cfg: ModelConfig, key, d: int, f: int):
                 "w_down": (jax.random.normal(k3, (f, d)) * s_out).astype(dt)}
     return {"w_up": (jax.random.normal(k1, (d, f)) * s_in).astype(dt),
             "w_down": (jax.random.normal(k2, (f, d)) * s_out).astype(dt)}
-
-
-def make_matmul(cfg: ModelConfig, tables=None, interpret: bool = None):
-    """dense_fn factory for apply_mlp / attention.
-
-    When ``cfg.dbpim`` is set and packed kernel tables (from
-    ``sparsity.sparse_linear.build_kernel_tables``) are supplied, eligible
-    projections run on the DB-PIM Pallas kernel selected by
-    ``cfg.dbpim_mode`` — "joint" fuses value-level block skipping with
-    bit-level INT8 weights in one kernel. Returns None (plain matmuls)
-    otherwise, so call sites can pass the result straight through.
-    interpret=None uses the backend default (compile on TPU, interpret
-    elsewhere; REPRO_PALLAS_INTERPRET overrides).
-
-    Scope note: this is the PER-LAYER hook (single unstacked tables).
-    The scan-stacked serving forwards thread
-    ``sparsity.sparse_linear.StackedKernelTables`` instead — uniform-MAXB
-    stacked packs carried as scan xs (transformer.forward(tables=...),
-    decode.decode_step(tables=...)).
-    """
-    if not getattr(cfg, "dbpim", False) or not tables:
-        return None
-    from repro.sparsity.sparse_linear import kernel_dense_fn
-    return kernel_dense_fn(tables, interpret=interpret)
 
 
 def apply_mlp(p, x, cfg: ModelConfig, dense_fn=None):

@@ -1,7 +1,7 @@
 """Mixture-of-Experts with sort-based capacity dispatch.
 
 Design goals (in order):
-  1. Static shapes (pjit/dry-run friendly).
+  1. Static shapes (pjit friendly).
   2. FLOPs proportional to top_k/n_experts (roofline-faithful) — the
      dispatch is scatter/gather, NOT a (T, E, C) einsum, so the compiled
      compute term reflects the real expert math.

@@ -20,9 +20,8 @@ switching on cfg.family — ANY composition of attention / SSM / MoE /
 cross-attention sublayers serves through the joint-sparse Pallas path.
 
 `ServingCapabilities` (returned by ModelConfig.serving_capabilities())
-is the single source of truth the old boolean properties
-(`supports_stacked_tables` / `supports_chunked_prefill` /
-`supports_parallel_prefill`) now delegate to as thin deprecated shims.
+is the single source of truth for what the serving stack supports per
+config: stacked tables, chunked prefill, parallel SSM prefill.
 """
 
 from __future__ import annotations

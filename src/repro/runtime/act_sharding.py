@@ -9,7 +9,8 @@ single-device smoke tests are unaffected.
 Pinning activations matters: GSPMD propagates shardings from weights, but
 mixed-divisibility cases (e.g. 8 KV heads on a 16-way model axis) let
 replicated operands "win" and silently blow up per-device activation
-memory. These constraints are load-bearing for the dry-run memory budget.
+memory. These constraints are load-bearing for the production meshes'
+memory budget.
 """
 
 from __future__ import annotations

@@ -5,7 +5,7 @@ quantized to INT8 (4x less all-reduce traffic than f32) with per-256-block
 scales; the quantization residual is carried in an error-feedback buffer
 so the compression bias vanishes over steps (Seide et al. / EF-SGD line).
 
-`compress_tree` (stateless, used in the dry-run train step) quantizes and
+`compress_tree` (stateless, build_train_step's grad_compression) quantizes and
 immediately dequantizes — the all-reduce then operates on values that are
 exactly representable in INT8 blocks, modeling the traffic reduction while
 keeping the pjit program simple. `EFCompressor` is the stateful

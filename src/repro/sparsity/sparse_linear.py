@@ -12,11 +12,11 @@ projection matrices of any of the 10 assigned architectures:
     INT8 x scale grid) so the SAME model code runs the compressed model;
   * `pim_speedup_estimate` maps each projection to the DB-PIM cost model
     -> a beyond-paper result: DB-PIM speedup/energy for transformer
-    inference (EXPERIMENTS.md §Beyond-paper).
+    inference.
 
-On TPU the compressed tensors feed the Pallas kernels
-(kernels.block_sparse_matmul for the value level, kernels.fta_int8_matmul
-for the bit level).
+Serving packs the same projections into stacked tables
+(`build_stacked_tables`) that feed the one joint Pallas kernel
+(kernels.joint_sparse_matmul) through the layer scans.
 """
 
 from __future__ import annotations
@@ -33,13 +33,7 @@ from repro.core import fta, pruning
 from repro.core.pim_model import (DEFAULT_PIM, LayerGEMM, evaluate_model,
                                   evaluate_dense_baseline,
                                   sparsity_from_export)
-from repro.models.config import ModelConfig
-
-#: Kernel dispatch modes for compressed projections. "value" skips pruned
-#: weight blocks (block_sparse_matmul), "bit" serves FTA/INT8 weights
-#: (fta_int8_matmul), "joint" fuses both in one kernel
-#: (joint_sparse_matmul) — the paper's headline configuration.
-KERNEL_MODES = ("dense", "value", "bit", "joint")
+from repro.models.config import KERNEL_MODES, ModelConfig
 
 ELIGIBLE = re.compile(
     r"(attn|xattn)/(wq|wk|wv|wo)$|mlp/w_(gate|up|down)$|"
@@ -157,100 +151,6 @@ def pim_speedup_estimate(comp: DBPIMCompressed, cfg: ModelConfig,
         "u_act": ours.u_act,
         "n_projections": len(layers),
     }
-
-
-# ---------------------------------------------------------------------------
-# Kernel-mode dispatch: pack projections once offline, then intercept the
-# model's matmuls (the dense_fn hook of apply_mlp / attention) with the
-# Pallas kernel selected by ModelConfig.dbpim_mode.
-# ---------------------------------------------------------------------------
-
-def pack_projection(w2, mode: str, value_sparsity: float = 0.6) -> dict:
-    """Compile one 2D projection (K, N) into the artifact for `mode`.
-
-    Value pruning here is TILE-granular (ops.tile_prune_mask) — the unit
-    the kernels can skip; the paper-faithful 1 x alpha pruning lives in
-    sparsify_params for the accuracy/cost-model artifacts. Falls back to
-    a reference artifact (same math, plain jnp) when the weight shape
-    does not divide the kernel tiling — "joint" pads internally and
-    never needs the fallback.
-    """
-    from repro.kernels import block_sparse_matmul as bsk
-    from repro.kernels import fta_int8_matmul as ftk
-    from repro.kernels import ops
-    if mode not in KERNEL_MODES:
-        raise ValueError(f"mode {mode!r} not in {KERNEL_MODES}")
-    w = np.asarray(w2, np.float32)
-    K, N = w.shape
-    if mode == "dense":
-        return {"kind": "dense"}
-    if mode == "joint":
-        packed = ops.pack_joint_sparse(w, value_sparsity=value_sparsity)
-        return {"kind": "joint", "packed": packed}
-
-    if mode == "value":
-        # tile-granular pruning: the unit block_sparse_matmul can skip
-        mask = ops.tile_prune_mask(w, value_sparsity, bsk.BK, bsk.BN)
-        art = {"kind": "value_ref", "w": jnp.asarray(w * mask)}
-        if K % bsk.BK == 0 and N % bsk.BN == 0:
-            w_blocks, idx = ops.pack_block_sparse(w * mask,
-                                                  np.ones_like(w, np.int32))
-            art.update(kind="value", w_blocks=w_blocks, idx=idx)
-        return art
-    # mode == "bit": per-filter INT8 scale + FTA projection, dense layout
-    # (no value pruning — same quantization step the joint pack uses)
-    q, scales = ops.quantize_int8_fta(w, np.ones_like(w, np.int32))
-    kind = "bit" if (K % ftk.BK == 0 and N % ftk.BN == 0) else "bit_ref"
-    return {"kind": kind, "q": jnp.asarray(q.astype(np.int8)),
-            "scales": jnp.asarray(scales)}
-
-
-def build_kernel_tables(named_weights: Dict[str, np.ndarray],
-                        cfg: Optional[ModelConfig] = None,
-                        mode: Optional[str] = None,
-                        value_sparsity: Optional[float] = None,
-                        ) -> Dict[str, dict]:
-    """Pack every named 2D projection for the configured kernel mode."""
-    mode = mode or (cfg.dbpim_mode if cfg is not None else "joint")
-    vs = value_sparsity if value_sparsity is not None else \
-        (cfg.dbpim_value_sparsity if cfg is not None else 0.6)
-    return {name: pack_projection(w, mode, vs)
-            for name, w in named_weights.items()}
-
-
-def kernel_dense_fn(tables: Dict[str, dict], interpret: bool = None):
-    """Build the dense_fn(w, x, name) hook for apply_mlp / attention.
-
-    Projections found in `tables` run on the packed artifact (Pallas
-    kernel or its reference fallback); anything else stays a plain
-    matmul. Kernel tilings that need M % 128 == 0 fall back to the
-    reference math for ragged activation batches.
-    """
-    from repro.kernels import block_sparse_matmul as bsk
-    from repro.kernels import fta_int8_matmul as ftk
-    from repro.kernels import ops
-
-    def mm(w, x, name):
-        t = tables.get(name)
-        if t is None or t["kind"] == "dense":
-            return x @ w
-        rows = int(np.prod(x.shape[:-1]))
-        if t["kind"] == "joint":
-            return ops.joint_dense(x, t["packed"],
-                                   interpret=interpret).astype(x.dtype)
-        if t["kind"] == "value" and rows % bsk.BM == 0:
-            return ops.sparse_dense(x, t["w_blocks"].astype(x.dtype),
-                                    t["idx"], interpret=interpret)
-        if t["kind"] in ("value", "value_ref"):
-            return x @ t["w"].astype(x.dtype)
-        if t["kind"] == "bit" and rows % ftk.BM == 0:
-            return ops.fta_dense(x, t["q"], t["scales"],
-                                 interpret=interpret).astype(x.dtype)
-        # bit_ref / ragged-M bit: same INT8 x scale math in plain jnp
-        wd = t["q"].astype(jnp.float32) * t["scales"]
-        return (x.astype(jnp.float32) @ wd).astype(x.dtype)
-
-    return mm
 
 
 # ---------------------------------------------------------------------------
@@ -558,37 +458,3 @@ def reconstruct_stacked_params(params, tables: SegmentedKernelTables, cfg):
             return dense.astype(leaf.dtype)
         return leaf
     return jax.tree_util.tree_map_with_path(visit, params)
-
-
-# ---------------------------------------------------------------------------
-# In-graph INT8 weight serving (decode is weight-traffic-bound: storing
-# projections INT8 + per-filter scale halves the dominant roofline term).
-# jax-traceable => works under eval_shape for the dry-run.
-# ---------------------------------------------------------------------------
-
-def quantize_params_for_serving(params):
-    """Eligible projection leaves -> {"q": int8, "scale": f32 per-filter}."""
-    def visit(path, leaf):
-        key = _key(path)
-        if not ELIGIBLE.search(key) or leaf.ndim < 2:
-            return leaf
-        amax = jnp.max(jnp.abs(leaf.astype(jnp.float32)), axis=-2,
-                       keepdims=True)
-        scale = amax / 127.0 + 1e-12
-        q = jnp.clip(jnp.round(leaf.astype(jnp.float32) / scale),
-                     -127, 127).astype(jnp.int8)
-        return {"q": q, "scale": scale.astype(jnp.float32)}
-    return jax.tree_util.tree_map_with_path(visit, params)
-
-
-def dequant_params_for_serving(qparams, dtype=jnp.bfloat16):
-    """Inverse of quantize_params_for_serving (dequant fuses into matmuls
-    on TPU — HBM traffic stays INT8)."""
-    def visit(node):
-        if isinstance(node, dict) and set(node) == {"q", "scale"}:
-            return (node["q"].astype(jnp.float32) * node["scale"]
-                    ).astype(dtype)
-        return node
-    return jax.tree_util.tree_map(
-        visit, qparams,
-        is_leaf=lambda x: isinstance(x, dict) and set(x) == {"q", "scale"})

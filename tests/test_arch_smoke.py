@@ -7,8 +7,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, list_archs
-from repro.models import (SHAPES, decode_step, forward, init_cache,
-                          init_params, loss_fn, param_count)
+from repro.models import (decode_step, forward, init_cache, init_params,
+                          loss_fn, param_count)
 from repro.models.inputs import make_decode_token, make_train_batch
 from repro.models.transformer import encode
 

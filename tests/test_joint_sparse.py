@@ -1,11 +1,11 @@
 """Joint value x bit sparse kernel: pack/unpack round-trip, kernel-vs-
 dense-reference equivalence across sparsity ratios and odd shapes, the
-padded-slot zero guard, and the mode dispatch through the model layers.
+padded-slot zero guard, and the value-only (bf16 payload) layout through
+the same kernel.
 
 Property tests need hypothesis; everything else runs without it.
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -167,35 +167,47 @@ def test_padded_slots_contribute_exactly_zero():
     np.testing.assert_array_equal(got[:, bn:], got2[:, bn:])
 
 
-# ------------------------------------------------- mode dispatch ----------
+# ------------------------------------------- value-only (bf16) payload ----
 
-def test_kernel_mode_dispatch_through_layers():
-    """cfg.dbpim_mode selects the kernel path through apply_mlp; every
-    mode must reproduce its own reference semantics."""
-    from repro.models.config import ModelConfig
-    from repro.models.layers import apply_mlp, init_mlp, make_matmul
-    from repro.sparsity.sparse_linear import (KERNEL_MODES,
-                                              build_kernel_tables)
+@pytest.mark.parametrize("M,K,N", [(128, 256, 128), (256, 512, 256),
+                                   (128, 128, 384)])
+@pytest.mark.parametrize("sparsity", [0.0, 0.5])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_value_payload_matches_reference(M, K, N, sparsity, dtype):
+    """The value-only layout (bf16 payload, unit scales) through the joint
+    kernel: the stored blocks are exactly bf16(w * tile_mask), and the
+    kernel matches the dense matmul over them."""
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(0, 1, (M, K)), dtype)
+    w = rng.normal(0, 1, (K, N)).astype(np.float32)
+    tile_alive = rng.random((K // 128, N // 128)) > sparsity
+    tile_mask = np.repeat(np.repeat(tile_alive, 128, 0), 128, 1)
+    p = ops.pack_joint_sparse_stacked(w[None], masks=tile_mask[None],
+                                      payload="bf16")
+    layer = ops.slice_joint_stacked(p, 0)
+    assert layer.w_blocks.dtype == jnp.bfloat16
+    want_w = np.asarray(jnp.asarray(w * tile_mask, jnp.bfloat16), np.float32)
+    np.testing.assert_array_equal(ops.unpack_joint_sparse(layer), want_w)
+    got = ops.joint_dense(x, layer)
+    want = ref.joint_packed_ref(x, layer)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol * 8)
 
-    cfg = ModelConfig(name="t", family="dense", n_layers=1, d_model=256,
-                      n_heads=4, n_kv_heads=4, d_ff=384, vocab_size=64,
-                      dtype="float32", dbpim=True,
-                      dbpim_value_sparsity=0.5)
-    p = init_mlp(cfg, jax.random.PRNGKey(0), 256, 384)
-    named = {k: np.asarray(v, np.float32) for k, v in p.items()}
-    x = jnp.asarray(np.random.default_rng(6).normal(0, 1, (2, 64, 256)),
-                    jnp.float32)
-    dense = apply_mlp(p, x, cfg)
-    for mode in KERNEL_MODES:
-        mcfg = cfg.scaled(dbpim_mode=mode)
-        tables = build_kernel_tables(named, mcfg)
-        y = apply_mlp(p, x, mcfg, dense_fn=make_matmul(mcfg, tables))
-        assert y.shape == dense.shape and y.dtype == dense.dtype
-        if mode == "dense":
-            np.testing.assert_array_equal(np.asarray(y), np.asarray(dense))
-        else:                      # compressed: close but not identical
-            assert float(jnp.max(jnp.abs(y - dense))) > 0.0
-            assert np.isfinite(np.asarray(y, np.float32)).all()
+
+def test_value_payload_stores_only_alive_blocks():
+    """At 75 % dead tiles (one K-block of four per column survives) the
+    bf16 payload stores one block per column: MAXB == 1."""
+    rng = np.random.default_rng(1)
+    w = rng.normal(0, 1, (512, 256)).astype(np.float32)
+    tile_alive = np.zeros((4, 2), bool)
+    tile_alive[0, :] = True
+    tile_mask = np.repeat(np.repeat(tile_alive, 128, 0), 128, 1)
+    p = ops.pack_joint_sparse_stacked(w[None], masks=tile_mask[None],
+                                      payload="bf16")
+    assert p.maxb == 1
+    assert p.w_blocks.dtype == jnp.bfloat16
 
 
 def test_registry_selects_joint_mode():

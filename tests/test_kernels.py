@@ -1,12 +1,8 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, interpret mode.
-
-Also property tests: the DBMU bit-serial datapath must equal the integer
-matmul EXACTLY for any FTA-compliant weights (hardware equivalence of the
-whole compression pipeline).
+"""The DBMU bit-serial datapath must equal the integer matmul EXACTLY for
+any FTA-compliant weights (hardware equivalence of the whole compression
+pipeline). The joint kernel's tests are in test_joint_sparse.py.
 """
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -16,78 +12,8 @@ try:  # optional: only the seeded property test below needs hypothesis
 except ImportError:
     HAVE_HYPOTHESIS = False
 
-from repro.core import dyadic, fta, pruning
+from repro.core import dyadic, fta
 from repro.kernels import ops, ref
-from repro.kernels.block_sparse_matmul import block_sparse_matmul
-from repro.kernels.fta_int8_matmul import fta_int8_matmul
-
-
-# ------------------------------------------------------ block-sparse -------
-
-@pytest.mark.parametrize("M,K,N", [(128, 256, 128), (256, 512, 256),
-                                   (128, 128, 384)])
-@pytest.mark.parametrize("sparsity", [0.0, 0.5])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_block_sparse_matmul(M, K, N, sparsity, dtype):
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(0, 1, (M, K)), dtype)
-    w = rng.normal(0, 1, (K, N)).astype(np.float32)
-    mask = np.asarray(pruning.block_prune_mask(w, sparsity, alpha=8))
-    # block-tile mask: zero whole (BK, BN) tiles for kernel-level sparsity
-    kt, nt = K // 128, N // 128
-    tile_alive = rng.random((kt, nt)) > sparsity
-    tile_mask = np.repeat(np.repeat(tile_alive, 128, 0), 128, 1)
-    w_blocks, idx = ops.pack_block_sparse(w * tile_mask,
-                                          np.ones_like(w, np.int32))
-    got = block_sparse_matmul(x, w_blocks.astype(dtype), idx)
-    want = ref.block_sparse_matmul_ref(x, jnp.asarray(w, dtype),
-                                       jnp.asarray(tile_mask))
-    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=tol, atol=tol * 8)
-
-
-def test_block_sparse_traffic_scales_with_sparsity():
-    rng = np.random.default_rng(1)
-    w = rng.normal(0, 1, (512, 256)).astype(np.float32)
-    kt = 512 // 128
-    tile_alive = np.zeros((kt, 2), bool)
-    tile_alive[0, :] = True                      # 75% block sparsity
-    tile_mask = np.repeat(np.repeat(tile_alive, 128, 0), 128, 1)
-    w_blocks, idx = ops.pack_block_sparse(w * tile_mask,
-                                          np.ones_like(w, np.int32))
-    assert w_blocks.shape[1] == 1                # stores only alive blocks
-
-
-# ---------------------------------------------------------- int8 FTA -------
-
-@pytest.mark.parametrize("M,K,N", [(128, 512, 128), (256, 1024, 256)])
-def test_fta_int8_matmul(M, K, N):
-    rng = np.random.default_rng(2)
-    x = jnp.asarray(rng.normal(0, 1, (M, K)), jnp.bfloat16)
-    w_q = jnp.asarray(rng.integers(-127, 128, (K, N)), jnp.int8)
-    scales = jnp.asarray(rng.uniform(0.005, 0.02, (1, N)), jnp.float32)
-    got = fta_int8_matmul(x, w_q, scales)
-    want = ref.fta_int8_matmul_ref(x, w_q, scales)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=3e-2, atol=0.5)
-
-
-def test_fta_matmul_exact_on_fta_grid():
-    """FTA weights are exactly representable: int8 path == float path."""
-    rng = np.random.default_rng(3)
-    w = jnp.asarray(rng.normal(0, 0.05, (512, 128)), jnp.float32)
-    mask = jnp.ones((512, 128), jnp.int32)
-    q, scale, packed, phi = ops.fta_pack(w, mask)
-    x = jnp.asarray(rng.normal(0, 1, (128, 512)), jnp.float32)
-    got = ops.fta_dense(x, q, jnp.full((1, 128), scale))
-    w_fta = q.astype(jnp.float32) * scale
-    want = (x @ w_fta).astype(jnp.bfloat16)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=2e-2, atol=0.3)
 
 
 # ------------------------------------------------------------- DBMU --------

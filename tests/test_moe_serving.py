@@ -117,12 +117,12 @@ def test_moe_family_gates():
     mixtral = get_config("mixtral-8x7b", reduced=True)
     arctic = get_config("arctic-480b", reduced=True)
     jamba = get_config("jamba-v0.1-52b", reduced=True)
-    assert mixtral.supports_stacked_tables
-    assert arctic.supports_stacked_tables
-    assert jamba.supports_stacked_tables
-    assert not mixtral.supports_chunked_prefill
-    assert arctic.supports_chunked_prefill
-    assert jamba.supports_chunked_prefill
+    assert mixtral.serving_capabilities().stacked_tables
+    assert arctic.serving_capabilities().stacked_tables
+    assert jamba.serving_capabilities().stacked_tables
+    assert not mixtral.serving_capabilities().chunked_prefill
+    assert arctic.serving_capabilities().chunked_prefill
+    assert jamba.serving_capabilities().chunked_prefill
 
 
 # ------------------------------------- forward / decode vs reference ------

@@ -9,8 +9,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.launch.mesh import make_test_mesh
-from repro.launch.steps import (build_prefill_chunk_step,
-                                build_slot_decode_step)
+from repro.launch.steps import build_step
 from repro.models import decode_chunk, init_cache, init_params
 from repro.models.ssm import PARALLEL_PREFILL_ATOL
 from repro.sparsity.sparse_linear import build_stacked_tables
@@ -147,11 +146,11 @@ def test_parallel_prefill_mixed_ragged_slots():
 # ------------------------------------------------- config + cost tags ----
 
 def test_supports_parallel_prefill_predicate():
-    assert _cfg(None).supports_parallel_prefill
-    assert not get_config("tinyllama-1.1b",
-                          reduced=True).supports_parallel_prefill
-    assert not get_config("mixtral-8x7b",
-                          reduced=True).supports_parallel_prefill
+    assert _cfg(None).serving_capabilities().parallel_prefill
+    assert not get_config(
+        "tinyllama-1.1b", reduced=True).serving_capabilities().parallel_prefill
+    assert not get_config(
+        "mixtral-8x7b", reduced=True).serving_capabilities().parallel_prefill
 
 
 def test_get_config_prefill_exact_kwarg():
@@ -166,14 +165,14 @@ def test_step_builders_tag_call_kinds():
     chunks always exact, decode steps "decode"."""
     mesh = make_test_mesh()
     ssm = _cfg(None)
-    fn, _ = build_prefill_chunk_step(ssm, mesh)
+    fn, _ = build_step(ssm, mesh, "prefill_chunk")
     assert fn.call_kind == "prefill_parallel"
-    fn, _ = build_prefill_chunk_step(ssm.scaled(prefill_exact=True), mesh)
+    fn, _ = build_step(ssm.scaled(prefill_exact=True), mesh, "prefill_chunk")
     assert fn.call_kind == "prefill_chunk_exact"
     attn = get_config("tinyllama-1.1b", reduced=True)
-    fn, _ = build_prefill_chunk_step(attn, mesh)
+    fn, _ = build_step(attn, mesh, "prefill_chunk")
     assert fn.call_kind == "prefill_chunk_exact"
-    fn, _ = build_slot_decode_step(ssm, mesh)
+    fn, _ = build_step(ssm, mesh, "decode")
     assert fn.call_kind == "decode"
 
 
@@ -199,9 +198,9 @@ def test_parallel_chunk_reads_projections_once():
     toks = jnp.zeros((B, C), jnp.int32)
     nv = jnp.full((B,), C, jnp.int32)
     slots = jnp.arange(B, dtype=jnp.int32)
-    par_fn, _ = build_prefill_chunk_step(cfg, mesh)
-    ex_fn, _ = build_prefill_chunk_step(cfg.scaled(prefill_exact=True),
-                                        mesh)
+    par_fn, _ = build_step(cfg, mesh, "prefill_chunk")
+    ex_fn, _ = build_step(cfg.scaled(prefill_exact=True), mesh,
+                          "prefill_chunk")
     kinds = analyze_call_kinds({
         par_fn.call_kind: (par_fn, (params, tables, cache, toks, nv, slots)),
         ex_fn.call_kind: (ex_fn, (params, tables, cache, toks, nv, slots))},

@@ -14,8 +14,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.launch.mesh import make_test_mesh
-from repro.launch.steps import (build_prefill_chunk_step, build_serve_step,
-                                build_slot_decode_step, build_step)
+from repro.launch.steps import build_step
 from repro.models import (decode_chunk, decode_step, forward, init_cache,
                           init_params)
 from repro.models.segments import (decoder_layout, packable_projections,
@@ -69,8 +68,8 @@ def test_decoder_layouts_per_family():
 
 
 def test_serving_capabilities_and_deprecated_shims():
-    """serving_capabilities() is the single source of truth; the old
-    boolean cfg properties are shims over it. Every family packs stacked
+    """serving_capabilities() is the single source of truth for what the
+    serving stack supports per config. Every family packs stacked
     tables; only sliding windows gate chunked prefill; parallel prefill
     means an SSM segment exists."""
     for arch, chunked, par in [("tinyllama-1.1b", True, False),
@@ -86,10 +85,6 @@ def test_serving_capabilities_and_deprecated_shims():
         assert caps.parallel_prefill is par
         assert caps.prefill_modes == (("chunked", "full") if chunked
                                       else ("full",))
-        # shims agree with the capability object
-        assert cfg.supports_stacked_tables == caps.stacked_tables
-        assert cfg.supports_chunked_prefill == caps.chunked_prefill
-        assert cfg.supports_parallel_prefill == caps.parallel_prefill
     # packable projections carry exact segment-qualified paths
     wh = serving_capabilities(get_config("whisper-base", reduced=True))
     assert "blocks/xattn/wq" in wh.packable
@@ -314,9 +309,7 @@ def test_build_step_tags_and_validation():
     jamba = get_config("jamba-v0.1-52b", reduced=True)
     whisper = get_config("whisper-base", reduced=True)
 
-    serve_fn, _ = build_step(llama, mesh, "serve")
     decode_fn, _ = build_step(llama, mesh, "decode")
-    assert serve_fn.call_kind == "decode"
     assert decode_fn.call_kind == "decode"
     chunk_j, _ = build_step(jamba, mesh, "prefill_chunk")
     assert chunk_j.call_kind == "prefill_parallel"
@@ -326,16 +319,7 @@ def test_build_step_tags_and_validation():
     chunk_w, _ = build_step(whisper, mesh, "prefill_chunk")
     assert chunk_w.call_kind == "prefill_chunk_exact"
 
-    # the legacy builders are thin wrappers over the same entry point
-    assert build_serve_step(llama, mesh)[0].call_kind == "decode"
-    assert build_slot_decode_step(llama, mesh)[0].call_kind == "decode"
-    assert build_prefill_chunk_step(jamba, mesh)[0].call_kind == \
-        "prefill_parallel"
-
     with pytest.raises(ValueError, match="call_kind"):
         build_step(llama, mesh, "train")
-    serve_i8, _ = build_step(llama, mesh, "serve", int8_weights=True)
-    with pytest.raises(ValueError, match="mutually"):
-        serve_i8(None, object(), None, None)
-    with pytest.raises(ValueError, match="serve"):
-        build_step(llama, mesh, "decode", int8_weights=True)
+    with pytest.raises(ValueError, match="call_kind"):
+        build_step(llama, mesh, "serve")

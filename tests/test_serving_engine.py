@@ -119,7 +119,7 @@ def test_chunk_window_clamped_at_cache_end_bit_identical():
 
 def test_chunked_prefill_rejects_unsupported_families():
     cfg = get_config("mixtral-8x7b", reduced=True)          # MoE + window
-    assert not cfg.supports_chunked_prefill
+    assert not cfg.serving_capabilities().chunked_prefill
     params = init_params(cfg, jax.random.PRNGKey(0))
     cache = init_cache(cfg, 1, 8)
     with pytest.raises(ValueError):

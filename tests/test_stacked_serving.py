@@ -162,16 +162,41 @@ def test_small_m_row_tile_selection():
 
 # ----------------------------------------------- value-only (bf16) --------
 
+@pytest.mark.parametrize("mode", ["dense", "value", "bit", "joint"])
+def test_stacked_tables_by_mode(mode):
+    """What each dbpim_mode packs: "dense" nothing; "value" a bf16 payload
+    with unit scales; "bit" an INT8 payload keeping every K-block; "joint"
+    an INT8 payload keeping the balanced rule's count per column."""
+    vs = 0.5
+    cfg = get_config("tinyllama-1.1b", reduced=True, dbpim_mode=mode).scaled(
+        dtype="float32", dbpim_value_sparsity=vs)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tables = build_stacked_tables(params, cfg, bk=32, bn=32)
+    if mode == "dense":
+        assert tables is None
+        return
+    for name, t in tables.arrays.items():
+        kt = tables.static[name][2] // 32
+        maxb = t["w_blocks"].shape[-3]
+        if mode == "value":
+            assert t["w_blocks"].dtype == jnp.bfloat16
+            assert np.asarray(t["scales"] == 1.0).all()
+        else:
+            assert t["w_blocks"].dtype == jnp.int8
+        if mode == "bit":
+            assert maxb == kt
+        if mode == "joint":
+            assert maxb == kt - min(int(round(vs * kt)), kt - 1)
+
+
+@pytest.mark.parametrize("mode", ["value", "bit"])
 @pytest.mark.parametrize("arch", ARCHS)
-def test_value_mode_packs_bf16_payload_and_serves(arch):
-    """dbpim_mode="value" builds bf16-PAYLOAD stacked tables (compacted
-    blocks hold the raw pruned weights, unit scales — value level only,
-    no bit-level grid) and serves forward + decode through the scan to
-    the same tolerance contract as joint."""
-    cfg, params, tables = _setup(arch, mode="value")
-    for t in tables.arrays.values():
-        assert t["w_blocks"].dtype == jnp.bfloat16
-        assert np.asarray(t["scales"] == 1.0).all()
+def test_value_mode_packs_bf16_payload_and_serves(arch, mode):
+    """The single-level modes serve forward + decode through the scan to
+    the same tolerance contract as joint: "value" (compacted blocks hold
+    the raw pruned bf16 weights, unit scales — no bit-level grid) and
+    "bit" (INT8/FTA payload, no value pruning)."""
+    cfg, params, tables = _setup(arch, mode=mode)
     recon = reconstruct_stacked_params(params, tables, cfg)
     toks = jnp.asarray(np.random.default_rng(6).integers(
         1, cfg.vocab_size, (2, 16)), jnp.int32)
@@ -273,16 +298,6 @@ def test_mismatched_tables_raise_instead_of_misserving():
     with pytest.raises(ValueError, match="segmented pack"):
         forward(params_t, jnp.ones((1, 8), jnp.int32), cfg_t,
                 tables=tables.segments["blocks"])
-
-
-def test_serve_step_rejects_conflicting_weight_formats():
-    from repro.launch.mesh import make_test_mesh
-    from repro.launch.steps import build_serve_step
-    cfg, params, tables = _setup("tinyllama-1.1b")
-    step, _ = build_serve_step(cfg, make_test_mesh(), int8_weights=True)
-    with pytest.raises(ValueError):
-        step(params, tables, init_cache(cfg, 1, 8),
-             jnp.ones((1, 1), jnp.int32))
 
 
 # ------------------------------------------------ interpret default -------

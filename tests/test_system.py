@@ -9,7 +9,7 @@ import pytest
 from repro.configs import get_config
 from repro.data import SyntheticLMDataset
 from repro.launch.mesh import make_test_mesh
-from repro.launch.steps import build_serve_step, build_train_step
+from repro.launch.steps import build_train_step
 from repro.models import decode_step, init_cache, init_params, loss_fn
 from repro.optim import adamw_init
 from repro.runtime import sharding as shr
