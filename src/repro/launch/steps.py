@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.models import decode_chunk, decode_step, loss_fn, merge_slots
+from repro.models import decode_chunk, decode_step, loss_fn
 from repro.models.config import ModelConfig
 from repro.optim import AdamWState, adamw_update, cosine_with_warmup
 from repro.runtime import sharding as shr
@@ -147,11 +147,12 @@ def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
       * "decode" — the serving engine's slot decode step,
         ``(params, tables, cache, token, active)``: inactive slots (free,
         draining, or mid-prefill while their neighbors decode) compute
-        alongside the batch but their K/V writes are dropped in-step
-        (decode_step's write_mask) and their position and SSM state
-        advances discarded (models.decode.merge_slots) — continuous
-        batching with ZERO per-request recompilation. Positions come from
-        cache["pos"], a (B,) vector of per-slot depths. Tag "decode".
+        alongside the batch, but decode_step's write_mask leaves their
+        K/V rows, SSM conv/state and position as they were, in-step —
+        the step returns decode_step's cache as it is, and nothing
+        selects the whole cache afterwards. Continuous batching with ZERO
+        per-request recompilation. Positions come from cache["pos"], a
+        (B,) vector of per-slot depths. Tag "decode".
       * "prefill_chunk" — chunked cache-filling prefill,
         ``(params, tables, cache, tokens, n_valid, slots)``: C prompt
         tokens per row in ONE fixed-shape device call
@@ -186,10 +187,8 @@ def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
                          f"{SERVE_CALL_KINDS}")
     if call_kind == "decode" and paged:
         def step_fn(params, tables, cache, token, active, ptab):
-            logits, new_cache = decode_step(params, cache, token, cfg,
-                                            tables=tables,
-                                            ptab=ptab, write_mask=active)
-            return logits, merge_slots(new_cache, cache, active, cfg)
+            return decode_step(params, cache, token, cfg, tables=tables,
+                               ptab=ptab, write_mask=active)
         step_fn.call_kind = "decode"
 
         def shardings(params, tables, cache, token, active, ptab):
@@ -204,10 +203,8 @@ def build_step(cfg: ModelConfig, mesh: Mesh, call_kind: str, *,
 
     elif call_kind == "decode":
         def step_fn(params, tables, cache, token, active):
-            logits, new_cache = decode_step(params, cache, token, cfg,
-                                            tables=tables,
-                                            write_mask=active)
-            return logits, merge_slots(new_cache, cache, active, cfg)
+            return decode_step(params, cache, token, cfg, tables=tables,
+                               write_mask=active)
         step_fn.call_kind = "decode"
 
         def shardings(params, tables, cache, token, active):

@@ -1,14 +1,17 @@
-"""Serving path: cache init, prefill, and single-token decode for every
+"""Serving path: cache init, chunked prefill, and single-token decode for every
 family (dense/MoE/VLM, SSM, hybrid, enc-dec).
 
 The decoder is a list of per-kind segments (models.segments): decode
 walks it, scanning each segment's stacked layer params (and packed-table
 slices) as scan xs — the HLO stays O(segments) in depth, and every
 composition of attention / SSM / MoE / cross-attention sublayers flows
-through the same four bodies. A contiguous K/V stack rides the scan as
-carry and each layer writes its own rows into it in place; paged pools
-and SSM states ride as per-layer xs/ys. Caches are static-shape; SWA
-archs allocate only the window (ring buffer).
+through the same four bodies. In the decode step a contiguous K/V stack
+and an SSM segment's conv/state stacks ride the scan as carry: each
+layer writes its own rows into them in place, masked per slot, so an
+inactive slot's cache and position stay as they were without any
+select of the whole cache. Paged pools ride as per-layer xs/ys, as do
+the SSM states of a prefill chunk. Caches are static-shape; SWA archs
+allocate only the window (ring buffer).
 """
 
 from __future__ import annotations
@@ -24,7 +27,7 @@ from . import ssm as ssm_mod
 from .config import ModelConfig
 from .layers import (apply_mlp, apply_norm, embed_tokens, logits_from_hidden)
 from .segments import decoder_layout
-from .transformer import _block_tail, _sinusoidal, encode, segment_tables
+from .transformer import _block_tail, _sinusoidal, segment_tables
 
 
 # ---------------------------------------------------------------------------
@@ -37,10 +40,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                page_size: Optional[int] = None) -> Dict:
     """Per-segment caches: attention segments hold stacked (L_seg, B, A,
     Hkv, hd) k/v, SSM segments stacked (L_seg, B, ...) conv/state — the
-    batch axis is 1 EVERYWHERE (the old hybrid layout nested SSM slices
-    as (periods, P-1, B, ...), which forced family-switched axis math in
-    merge_slots). Single-segment stacks keep the historical "attn"/"ssm"
-    cache keys; hybrid stacks key by segment name.
+    batch axis is 1 EVERYWHERE, so per-slot writes and resets index every
+    leaf the same way. Single-segment stacks keep the historical
+    "attn"/"ssm" cache keys; hybrid stacks key by segment name.
 
     PAGED mode (n_pages + page_size set): attention segments hold a
     pooled {"pk","pv"} (L_seg, n_pages, page_size, Hkv, hd) instead —
@@ -121,6 +123,36 @@ def _attn_scan(layer, x, p_stack, c, txs, mk):
     return x, {"k": ks, "v": vs}
 
 
+def _ssm_scan(layer, x, p_stack, c, txs, mk, write_mask):
+    """Scan an SSM segment's layers in the decode step:
+    ``layer(h, p, conv, state, mm)`` -> (h, conv, state) for one layer's
+    (B, ...) slices. Returns (x, the segment's new conv/state leaves).
+
+    The conv and state stacks ride the scan as CARRY, as contiguous K/V
+    do in _attn_scan: each layer reads its own slice once, keeps the old
+    conv/state of slots where write_mask (B,) is False, and writes the
+    layer back at its index — the stacks are updated where they lie and
+    never restacked or selected whole. None writes every slot."""
+    def step(carry, inp):
+        h, convs, states = carry
+        li, p, slices = inp
+        conv = jax.lax.dynamic_index_in_dim(convs, li, keepdims=False)
+        state = jax.lax.dynamic_index_in_dim(states, li, keepdims=False)
+        h, new_conv, new_state = layer(h, p, conv, state, mk(slices))
+        if write_mask is not None:
+            new_conv = jnp.where(write_mask[:, None, None], new_conv, conv)
+            new_state = jnp.where(write_mask[:, None, None, None],
+                                  new_state, state)
+        convs = jax.lax.dynamic_update_index_in_dim(convs, new_conv, li, 0)
+        states = jax.lax.dynamic_update_index_in_dim(states, new_state, li,
+                                                     0)
+        return (h, convs, states), None
+    n = c["state"].shape[0]
+    (x, convs, states), _ = jax.lax.scan(
+        step, (x, c["conv"], c["state"]), (jnp.arange(n), p_stack, txs))
+    return x, {"conv": convs, "state": states}
+
+
 # ---------------------------------------------------------------------------
 # Decode step
 # ---------------------------------------------------------------------------
@@ -137,10 +169,11 @@ def decode_step(params, cache, token, cfg: ModelConfig, tables=None,
     slice; enc-dec: cross-attention packs next to self-attention; hybrid
     segments pack independently). None keeps the plain matmuls.
 
-    write_mask (B,) bool drops the K/V writes of slots where it is False,
-    in-step: their attention cache rows stay bitwise as they were, so
-    merge_slots has no K/V to select (SSM and "pos" leaves still merge
-    there). None writes every slot.
+    write_mask (B,) bool leaves the slots where it is False as they were,
+    in-step: their K/V rows, SSM conv/state and position stay bitwise
+    unchanged (the position comes out as a (B,) vector), so one
+    fixed-shape step serves a batch where only some slots decode. None
+    writes every slot.
 
     ptab (B, max_pages) int32 switches attention segments to the PAGED
     cache (pooled {"pk","pv"} leaves): KV writes route through the page
@@ -156,6 +189,11 @@ def decode_step(params, cache, token, cfg: ModelConfig, tables=None,
         posv = attn_mod._per_slot_pos(pos, B)
         x = x + _sinusoidal_at(posv[:, None], cfg.d_model).astype(x.dtype)
     enc_out = cache.get("enc_out")
+    if write_mask is None:
+        new_pos = pos + 1
+    else:
+        posv = attn_mod._per_slot_pos(pos, B)
+        new_pos = jnp.where(write_mask, posv + 1, posv)
     new_cache = dict(cache)
 
     for seg in segs:
@@ -174,22 +212,19 @@ def decode_step(params, cache, token, cfg: ModelConfig, tables=None,
                 return h, ck, cv
             x, nc = _attn_scan(layer, x, params[seg.name], c, txs, mk)
             if "pos" in c:
-                nc["pos"] = pos + 1
-            new_cache[seg.cache] = nc
+                nc["pos"] = new_pos
         else:
-            def step(h, inp, seg=seg, mk=mk):
-                p, conv, state, slices = inp
-                mm = mk(slices)
+            def layer(h, p, conv, state, mm, seg=seg):
                 hn = apply_norm(p["norm1"], h, cfg)
                 y, conv, state = ssm_mod.decode_ssm(
                     p["ssm"], hn, conv, state, cfg, dense_fn=mm)
                 h = _block_tail(seg, p, h + y, cfg, mm, enc_out)
-                return h, (conv, state)
-            x, (convs, states) = jax.lax.scan(
-                step, x, (params[seg.name], c["conv"], c["state"], txs))
-            new_cache[seg.cache] = {"conv": convs, "state": states}
+                return h, conv, state
+            x, nc = _ssm_scan(layer, x, params[seg.name], c, txs, mk,
+                              write_mask)
+        new_cache[seg.cache] = nc
 
-    new_cache["pos"] = pos + 1
+    new_cache["pos"] = new_pos
     x = apply_norm(params["final_norm"], x, cfg)
     return logits_from_hidden(params["embed"], x, cfg), new_cache
 
@@ -348,43 +383,6 @@ def decode_chunk(params, cache, tokens, n_valid, cfg: ModelConfig,
 # Per-slot cache surgery (the serving engine's slot scheduler)
 # ---------------------------------------------------------------------------
 
-def _select_batch(mask, new, old, axis: int):
-    shape = [1] * new.ndim
-    shape[axis] = mask.shape[0]
-    return jnp.where(mask.reshape(shape), new, old)
-
-
-def merge_slots(new_cache, old_cache, keep_mask, cfg: ModelConfig):
-    """Per-slot cache select: slots where keep_mask (B,) is True take the
-    updated cache, the rest keep their previous contents and position.
-
-    This is what lets ONE fixed-shape decode step serve a batch where
-    only some slots are actively decoding (others are mid-prefill, free,
-    or draining): the step computes updates for every slot, and the merge
-    discards the updates of inactive ones. Positions come out as (B,)
-    vectors regardless of input shape.
-
-    Only "pos" and the SSM conv/state leaves ((L_seg, B, ...), batch on
-    axis 1) are selected. Attention K/V — contiguous "k"/"v" and paged
-    "pk"/"pv" alike — pass through updated: the step gated their writes
-    per slot in place (decode_attention's write_mask, prefill's n_valid),
-    so an inactive slot's rows were never touched, and a select over the
-    whole cache would only copy it. Encoder output (enc-dec) is shared
-    across the batch and passes through unchanged."""
-    B = keep_mask.shape[0]
-
-    def visit(path, new, old):
-        key = str(getattr(path[-1], "key", path[-1]))
-        if key == "pos":
-            return jnp.where(keep_mask, attn_mod._per_slot_pos(new, B),
-                             attn_mod._per_slot_pos(old, B))
-        if key in ("conv", "state"):
-            return _select_batch(keep_mask, new, old, axis=1)
-        return new
-
-    return jax.tree_util.tree_map_with_path(visit, new_cache, old_cache)
-
-
 def _zero_slots(leaf, slot_mask):
     """Zero leaf[:, b] (batch on axis 1) for each b where slot_mask is
     set: a dynamic-update-slice of zeros per masked slot, skipped for
@@ -410,14 +408,14 @@ def reset_slots(cache, slot_mask, cfg: ModelConfig, ptab=None):
     output (enc-dec) is shared and not per-request; callers that rotate
     enc-dec requests must swap it themselves.
 
-    Contiguous k/v are zeroed in place, one slot at a time and only
-    where the mask is set (_zero_slots), so an admission writes its own
-    slots and never reads or selects the whole cache.
+    Contiguous k/v and SSM conv/state are zeroed in place, one slot at a
+    time and only where the mask is set (_zero_slots), so an admission
+    writes its own slots and never reads or selects the whole cache.
     Paged caches additionally take ``ptab`` (B, max_pages): the reset is
     PAGE-TABLE SURGERY — only the pages the masked slots' table rows
     point at are zeroed (fixed-shape scatter; -1 rows route to the drop
     sentinel), so admitting one request never touches another slot's
-    pages. SSM/"pos" leaves are per-slot and reset by select."""
+    pages. "pos" is reset by select (a (B,) vector)."""
     B = slot_mask.shape[0]
     if ptab is not None:
         sel = slot_mask[:, None] & (ptab >= 0)               # (B, MP)
@@ -426,10 +424,7 @@ def reset_slots(cache, slot_mask, cfg: ModelConfig, ptab=None):
         key = str(getattr(path[-1], "key", path[-1]))
         if key == "pos":
             return jnp.where(slot_mask, 0, attn_mod._per_slot_pos(leaf, B))
-        if key in ("conv", "state"):
-            return _select_batch(slot_mask, jnp.zeros_like(leaf), leaf,
-                                 axis=1)
-        if key in ("k", "v"):
+        if key in ("k", "v", "conv", "state"):
             return _zero_slots(leaf, slot_mask)
         if key in ("pk", "pv") and ptab is not None:
             pids = jnp.where(sel, ptab, leaf.shape[1]).reshape(-1)
@@ -439,14 +434,3 @@ def reset_slots(cache, slot_mask, cfg: ModelConfig, ptab=None):
 
     return jax.tree_util.tree_map_with_path(visit, cache)
 
-
-def prefill(params, tokens, cfg: ModelConfig,
-            frames: Optional[jnp.ndarray] = None, tables=None):
-    """Prefill returns last-position logits. (The dry-run lowers the full
-    forward; serving fills caches through the engine — chunked
-    `decode_chunk` steps, or stepwise decode for families without chunked
-    support. See serving.prefill.)"""
-    from .transformer import forward
-    enc_out = encode(params, frames, cfg) if cfg.is_encdec else None
-    return forward(params, tokens, cfg, enc_out=enc_out, last_only=True,
-                   tables=tables)

@@ -10,9 +10,9 @@ with all scheduling host-side and all math in a few compiled
 executables, fixed-shape so NO recompilation ever happens per request:
 
   * decode step   (B, 1) tokens + (B,) active mask
-    (launch.steps.build_step("decode") — inactive slots' K/V writes are
-    dropped in the step, their position and SSM state updates discarded
-    by models.decode.merge_slots);
+    (launch.steps.build_step("decode") — inactive slots' K/V rows, SSM
+    conv/state and position are left as they were inside the step, each
+    layer writing only the active slots' cache in place);
   * prefill chunk (R, C) tokens + (R,) n_valid + (R,) slots, one
     executable per row bucket R in 1, 2, 4, ..., B
     (serving.prefill.chunk_buckets / build_chunk_step — only in
@@ -354,8 +354,9 @@ class ServeEngine:
                 cfg, n_slots, max_len, enc_out=enc_out,
                 n_pages=self.n_pages if self.paged else None,
                 page_size=self.page_size if self.paged else None)
-            # per-slot positions from the start (merge_slots vectorizes
-            # them anyway; starting scalar would recompile after tick 0)
+            # per-slot positions from the start (the masked decode step
+            # vectorizes them anyway; starting scalar would recompile
+            # after tick 0)
             cache["pos"] = jnp.zeros((n_slots,), jnp.int32)
             if "attn" in cache and "pos" in cache["attn"]:
                 cache["attn"]["pos"] = jnp.zeros((n_slots,), jnp.int32)

@@ -284,7 +284,7 @@ def test_hybrid_engine_refill_slots_match_fresh_slots():
     """The refill-slot regression on the hybrid cache layout: an engine
     whose 2 slots are reset and refilled mid-trace (4 requests) must
     generate exactly what a 4-slot engine (every request on a fresh slot)
-    generates — reset_slots/merge_slots walk the per-segment seg00..
+    generates — reset_slots and the masked decode step walk the seg00..
     caches with uniform batch axis 1, no family-switched axis math."""
     from repro.serving import ServeEngine, WorkloadSpec, make_trace
     cfg = get_config("jamba-v0.1-52b", reduced=True,

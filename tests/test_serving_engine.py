@@ -459,6 +459,51 @@ def test_reset_slots_zeroes_exactly_the_masked_slots(arch):
                 np.testing.assert_array_equal(new[:, b], old[:, b])
 
 
+@pytest.mark.parametrize("arch", ("mamba2-1.3b", "jamba-v0.1-52b"))
+def test_masked_decode_step_advances_only_active_ssm_state(arch):
+    """Through the serving decode step, a mixed active mask gives the
+    active slots the logits, conv window and SSM state of a step in which
+    every slot is active, and leaves the inactive slots' conv, state and
+    position bitwise as they were. reset_slots then zeroes exactly the
+    masked slots' conv and state and leaves the others bitwise equal."""
+    cfg = _cfg(arch)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    step, cache = _filled(cfg, params, 4, 16, 5, seed=9)
+    before = jax.tree_util.tree_map(np.asarray, cache)
+    tok = jnp.asarray([[3], [4], [5], [6]], jnp.int32)
+    active = np.array([True, False, True, False])
+    ssm = [k for k, c in before.items()
+           if isinstance(c, dict) and "state" in c]
+    assert ssm
+    logits_all, every = step(params, None, jax.tree_util.tree_map(
+        jnp.asarray, before), tok, jnp.ones((4,), bool))
+    logits, after = step(params, None, cache, tok, jnp.asarray(active))
+    np.testing.assert_array_equal(np.asarray(logits)[active],
+                                  np.asarray(logits_all)[active])
+    np.testing.assert_array_equal(
+        np.asarray(after["pos"]), before["pos"] + active)
+    for key in ssm:
+        for leaf in ("conv", "state"):
+            new = np.asarray(after[key][leaf])
+            np.testing.assert_array_equal(
+                new[:, active], np.asarray(every[key][leaf])[:, active])
+            np.testing.assert_array_equal(new[:, ~active],
+                                          before[key][leaf][:, ~active])
+            assert not np.array_equal(new[:, active],
+                                      before[key][leaf][:, active])
+
+    kept = jax.tree_util.tree_map(np.asarray, after)
+    mask = np.array([False, True, True, False])
+    reset = jax.jit(lambda c, m: reset_slots(c, m, cfg))(after,
+                                                        jnp.asarray(mask))
+    for key in ssm:
+        for leaf in ("conv", "state"):
+            new = np.asarray(reset[key][leaf])
+            assert not new[:, mask].any(), (key, leaf)
+            np.testing.assert_array_equal(new[:, ~mask],
+                                          kept[key][leaf][:, ~mask])
+
+
 def test_decode_spans_count_slots_written():
     """Each decode call span carries slots_written: the slots whose K/V
     rows the step wrote, which are exactly its active participants."""

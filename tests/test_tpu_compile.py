@@ -263,3 +263,31 @@ def test_two_row_chunk_reads_its_slots_kv_rows_by_slice(one_chip,
     assert kv_gathers == []
     slot = 1024 * cfg.n_kv_heads * cfg.hd
     assert _whole_kv_ops(text, {16 * slot, cfg.n_layers * 16 * slot}) == []
+
+
+def test_decode_step_writes_the_ssm_state_in_place(one_chip, monkeypatch):
+    """The decode step at published mamba2-1.3b widths, 4 layers, 16
+    slots x 1024, dense: each layer reads its own slice of the donated
+    conv and f32 state stacks and writes it back where it lies, the
+    inactive slots kept inside the layer. No copy, select or
+    dynamic-update-slice moves the whole 4-layer state (the whole-state
+    select after the step and the restack of the scan's per-layer
+    outputs that this replaced), and the step's scratch holds less than
+    one layer's state."""
+    monkeypatch.setenv(INTERPRET_ENV, "0")
+    cfg = get_config("mamba2-1.3b", dbpim_mode="dense").scaled(n_layers=4)
+    n_slots, max_len = 16, 1024
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: init_cache(cfg, n_slots, max_len))
+    cache["pos"] = jax.ShapeDtypeStruct((n_slots,), jnp.int32)
+    step, _ = build_step(cfg, None, "decode")
+    args = _on(one_chip, (params, None, cache,
+                          jax.ShapeDtypeStruct((n_slots, 1), jnp.int32),
+                          jax.ShapeDtypeStruct((n_slots,), jnp.bool_)))
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(*args).compile()
+    state = cache["ssm"]["state"]
+    layer = int(np.prod(state.shape[1:]))
+    assert _whole_kv_ops(compiled.as_text(), {cfg.n_layers * layer},
+                         dtype="f32") == []
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 4 * layer, temp          # less than a layer's f32 state
